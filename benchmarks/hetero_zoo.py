@@ -1,6 +1,9 @@
 """Heterogeneous model-zoo benchmark: per-architecture cohort costs in a
 mixed federation.
 
+CPU only: it starts one child process per device count, each with
+forced host devices, and a TPU belongs to one process at a time.
+
 Builds ONE mixed federation (4 families round-robined over the clients:
 ``mlp-s, resnet, transformer, ssm``) and measures, at N ∈ {64, 256}
 clients × devices ∈ {1, 8}:

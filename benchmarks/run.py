@@ -2,7 +2,8 @@
 benches. Prints ``name,us_per_call,derived`` CSV lines (one per bench).
 
 Each bench runs in its OWN subprocess: a long federation sweep accumulates
-jit executables faster than this container's RAM likes.
+jit executables faster than the host's RAM likes. CPU only: a TPU
+belongs to one process at a time.
 
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run --only table3

@@ -1,6 +1,9 @@
 """Client-axis sharding benchmark: cohort step + server graph build vs
 device count.
 
+CPU only: it starts one child process per device count, each with
+forced host devices, and a TPU belongs to one process at a time.
+
 Measures, at N ∈ {256, 1k, 4k} clients:
 
   * step    — one device-sharded ``cohort_step`` over a single stacked

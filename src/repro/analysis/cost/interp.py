@@ -35,9 +35,10 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from jax import core as jcore
+import jax.extend.core as jcore
 
-from repro.analysis.jaxprlib import _as_open, _opaque_subs, _transparent_sub
+from repro.analysis.jaxprlib import (DropVar, _as_open, _opaque_subs,
+                                     _transparent_sub)
 
 # --------------------------------------------------------------------------
 # per-primitive FLOP model
@@ -119,7 +120,7 @@ def eqn_flops(eqn) -> float:
     """The per-primitive FLOP model (see module docstring)."""
     name = eqn.primitive.name
     out_elems = sum(_numel(v.aval) for v in eqn.outvars
-                    if not isinstance(v, jcore.DropVar))
+                    if not isinstance(v, DropVar))
     in_elems = sum(_numel(v.aval) for v in eqn.invars)
     if name == "dot_general":
         (lhs_c, _), _ = eqn.params["dimension_numbers"]
@@ -210,7 +211,7 @@ def flatten(closed) -> Program:
                          for iv, ov in zip(sub.invars, eqn.invars)}
                 walk(sub, inner, mult)
                 for ov, sv in zip(eqn.outvars, sub.outvars):
-                    if not isinstance(ov, jcore.DropVar):
+                    if not isinstance(ov, DropVar):
                         env[ov] = buf_of(sv, inner)
                 continue
             name = eqn.primitive.name
@@ -226,14 +227,14 @@ def flatten(closed) -> Program:
             out_bufs: List[int] = []
             alloc: List[bool] = []
             inplace = (name in _INPLACE or name in _ALIAS_ONLY) and bool(
-                eqn.invars) and not isinstance(eqn.outvars[0], jcore.DropVar)
+                eqn.invars) and not isinstance(eqn.outvars[0], DropVar)
             if inplace:
                 # output 0 must match operand 0's width to alias it
                 o0 = eqn.outvars[0].aval
                 i0 = eqn.invars[0].aval
                 inplace = aval_nbytes(o0) == aval_nbytes(i0)
             for i, ov in enumerate(eqn.outvars):
-                if isinstance(ov, jcore.DropVar):
+                if isinstance(ov, DropVar):
                     out_bufs.append(new_buf(ov.aval, "eqn"))
                     alloc.append(True)
                     continue

@@ -37,7 +37,7 @@ FEATURES = 7
 class TracedEntry:
     """One audited entry point: its closed jaxpr + audit metadata."""
     name: str
-    jaxpr: object                      # jax.core.ClosedJaxpr
+    jaxpr: object                      # jax.extend.core.ClosedJaxpr
     # inside the wire-codec boundary: precision drops are the point
     codec_boundary: bool = False
     # (padded_dim, real_dim) when the entry runs on a ghost-padded stack

@@ -1,7 +1,7 @@
 """Jaxpr-walking machinery for the ``jaxpr`` rule family.
 
 Three analyses over a ``ClosedJaxpr`` (all recursion-aware — entry points
-jit their bodies, so the interesting equations sit inside nested ``pjit``
+jit their bodies, so the interesting equations sit inside nested ``jit``
 calls):
 
   * ``key_consumption`` / ``key_reuse_events`` — global value numbering
@@ -9,7 +9,7 @@ calls):
     draw plus a split/fold_in) means overlapping random streams.
   * ``output_dependencies`` — per-OUTPUT set of input positions each
     output depends on, with PRECISE propagation through transparent call
-    primitives (pjit/remat/custom_jvp). Precision matters: a
+    primitives (jit/remat/custom_jvp). Precision matters: a
     conservative union-through-calls would claim every output depends on
     every input and the masked-update auditor could never catch a mutant.
   * ``find_downcasts`` / ``random_draw_shapes`` — flat scans for
@@ -30,8 +30,9 @@ import itertools
 from typing import Dict, Iterator, List, Optional, Set, Tuple
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
-from jax import core as jcore
+from jax._src.core import DropVar  # the one jaxpr class jax.extend omits
 
 # primitives that CONSUME key randomness (drawing values) vs DERIVE fresh
 # keys. fold_in/split are listed as consumers too: reusing one key for a
@@ -42,9 +43,7 @@ DERIVE_PRIMS = frozenset({"random_split", "random_fold_in"})
 # call primitives whose sub-jaxpr invars/outvars map POSITIONALLY to the
 # equation's invars/outvars — safe to recurse through precisely
 _TRANSPARENT_CALLS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
-    "checkpoint", "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
+    "jit", "closed_call", "remat2", "custom_jvp_call", "custom_vjp_call",
 })
 
 
@@ -147,13 +146,13 @@ def key_consumption(closed) -> List[KeyEvent]:
                 walk(sub, inner)
                 for ov, sv in zip(eqn.outvars, sub.outvars):
                     if not isinstance(sv, jcore.Literal) and \
-                            not isinstance(ov, jcore.DropVar):
+                            not isinstance(ov, DropVar):
                         env[ov] = inner.get(sv, next(counter))
                 continue
             for j in _opaque_subs(eqn):
                 walk(j, {})
             for ov in eqn.outvars:
-                if not isinstance(ov, jcore.DropVar):
+                if not isinstance(ov, DropVar):
                     env[ov] = next(counter)
 
     walk(_as_open(closed), {})
@@ -205,14 +204,14 @@ def _jaxpr_out_deps(jaxpr: jcore.Jaxpr,
         if sub is not None:
             sub_deps = _jaxpr_out_deps(sub, memo)
             for ov, sd in zip(eqn.outvars, sub_deps):
-                if not isinstance(ov, jcore.DropVar):
+                if not isinstance(ov, DropVar):
                     deps[ov] = set().union(*(in_deps[p] for p in sd)) \
                         if sd else set()
         else:
             # opaque (incl. scan/while/cond): every output <- every input
             union: Set[int] = set().union(*in_deps) if in_deps else set()
             for ov in eqn.outvars:
-                if not isinstance(ov, jcore.DropVar):
+                if not isinstance(ov, DropVar):
                     deps[ov] = union
     out = [var_deps(v) for v in jaxpr.outvars]
     memo[id(jaxpr)] = out

@@ -6,7 +6,10 @@ repository holds; when messengers arrive int8-quantized (``wire.Int8``)
 the naive route decodes the whole stack to fp32 — an (N,R,C) HBM
 materialization 4x the wire form. This kernel dequantizes per-tile in
 VMEM instead: HBM holds the uint8 codes plus O(N·R) fp32 row statistics,
-and each grid step reconstructs only its (block, BR, C) tiles.
+and each grid step reconstructs only its (block, C, BR) tiles. The
+wrapper lays the codes out as (N, C, R), one uint8 copy of the wire form,
+so R fills the 128-wide lanes and the (R, C) contraction runs as C
+lane-dense matmuls.
 
 Math: with deq = q·scale + zp, the normalized log-prob is
 logp = deq − logsumexp(deq) = q·scale − lse(q·scale) − the per-row zp is
@@ -26,9 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.backend import resolve_interpret
+from repro.kernels.pairwise_kl import _ceil_mult
 
-DEFAULT_BN = 16
-DEFAULT_BM = 16
+DEFAULT_BN = 128
+DEFAULT_BM = 128
 DEFAULT_BR = 128
 
 _LSE_PAD = 1e30     # padded rows: p = exp(deq - LSE_PAD) == 0
@@ -38,7 +42,7 @@ _STATS_CHUNK = 256  # row-stats pass: bounds the fp32 dequant to
 
 def _kernel(qa_ref, sa_ref, la_ref, qb_ref, sb_ref, lb_ref, out_ref, *,
             n_r: int, inv_r: float):
-    """qa (BN,BR,C) uint8 codes [i,r]; sa/la (BN,BR) scale/lse [i,r];
+    """qa (BN,C,BR) uint8 codes [i,r]; sa/la (BN,1,BR) scale/lse [i,r];
     qb/sb/lb the [j,r] tiles; out (BN,BM) fp32 accumulator."""
     r = pl.program_id(2)
 
@@ -46,17 +50,19 @@ def _kernel(qa_ref, sa_ref, la_ref, qb_ref, sb_ref, lb_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    lpa = (qa_ref[...].astype(jnp.float32)
-           * sa_ref[...].astype(jnp.float32)[..., None]
-           - la_ref[...].astype(jnp.float32)[..., None])   # (BN,BR,C)
+    # Mosaic has no uint8 -> f32 cast; widen through int32
+    lpa = (qa_ref[...].astype(jnp.int32).astype(jnp.float32)
+           * sa_ref[...] - la_ref[...])                     # (BN,C,BR)
     pa = jnp.exp(lpa)
-    lpb = (qb_ref[...].astype(jnp.float32)
-           * sb_ref[...].astype(jnp.float32)[..., None]
-           - lb_ref[...].astype(jnp.float32)[..., None])   # (BM,BR,C)
-    rowterm = jnp.sum(pa * lpa, axis=(1, 2))[:, None]      # (BN,1)
-    cross = jax.lax.dot_general(
-        pa, lpb, (((1, 2), (1, 2)), ((), ())),
-        preferred_element_type=jnp.float32)                # (BN,BM)
+    lpb = (qb_ref[...].astype(jnp.int32).astype(jnp.float32)
+           * sb_ref[...] - lb_ref[...])                     # (BM,C,BR)
+    rowterm = jnp.sum(jnp.sum(pa * lpa, axis=1), axis=1,
+                      keepdims=True)                        # (BN,1)
+    # the (C, R) contraction as C lane-dense (BN,BR)x(BM,BR)^T matmuls
+    cross = sum(jax.lax.dot_general(
+        pa[:, c, :], lpb[:, c, :], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+        for c in range(pa.shape[1]))                        # (BN,BM)
     out_ref[...] += rowterm - cross
 
     @pl.when(r == n_r - 1)
@@ -79,14 +85,15 @@ def int8_row_stats(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 
 
 def _pad_operand(q, scale, lse, rows_pad, r_pad):
-    """Pad one wire-form operand along its row/ref axes. Padded rows get
+    """Lay one wire-form operand out as (rows, C, R) codes and (rows, 1,
+    R) row statistics, padded along its row/ref axes. Padded rows get
     lse = _LSE_PAD => p = 0 and the (finite) -_LSE_PAD log-prob is
     annihilated by it; padded rows are sliced off the output."""
-    q_p = jnp.pad(q, ((0, rows_pad), (0, r_pad), (0, 0)))
+    q_p = jnp.pad(jnp.swapaxes(q, 1, 2), ((0, rows_pad), (0, 0), (0, r_pad)))
     s_p = jnp.pad(scale.astype(jnp.float32), ((0, rows_pad), (0, r_pad)))
     l_p = jnp.pad(lse.astype(jnp.float32), ((0, rows_pad), (0, r_pad)),
                   constant_values=_LSE_PAD)
-    return q_p, s_p, l_p
+    return q_p, s_p[:, None, :], l_p[:, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "br", "interpret"))
@@ -96,8 +103,8 @@ def _call_pair(qa, sa, la, qb, sb, lb, bn, bm, br, interpret):
     arrays for both sides."""
     u, r, c = qa.shape
     m = qb.shape[0]
-    bn = min(bn, u)
-    bm = min(bm, m)
+    bn = min(bn, _ceil_mult(u))
+    bm = min(bm, _ceil_mult(m))
     br = min(br, r)
     u_pad = -u % bn
     m_pad = -m % bm
@@ -110,12 +117,12 @@ def _call_pair(qa, sa, la, qb, sb, lb, bn, bm, br, interpret):
         functools.partial(_kernel, n_r=gr, inv_r=1.0 / r),
         grid=(gn, gm, gr),
         in_specs=[
-            pl.BlockSpec((bn, br, c), lambda i, j, r: (i, r, 0)),  # q  [i]
-            pl.BlockSpec((bn, br), lambda i, j, r: (i, r)),        # s  [i]
-            pl.BlockSpec((bn, br), lambda i, j, r: (i, r)),        # lse[i]
-            pl.BlockSpec((bm, br, c), lambda i, j, r: (j, r, 0)),  # q  [j]
-            pl.BlockSpec((bm, br), lambda i, j, r: (j, r)),        # s  [j]
-            pl.BlockSpec((bm, br), lambda i, j, r: (j, r)),        # lse[j]
+            pl.BlockSpec((bn, c, br), lambda i, j, r: (i, 0, r)),  # q  [i]
+            pl.BlockSpec((bn, 1, br), lambda i, j, r: (i, 0, r)),  # s  [i]
+            pl.BlockSpec((bn, 1, br), lambda i, j, r: (i, 0, r)),  # lse[i]
+            pl.BlockSpec((bm, c, br), lambda i, j, r: (j, 0, r)),  # q  [j]
+            pl.BlockSpec((bm, 1, br), lambda i, j, r: (j, 0, r)),  # s  [j]
+            pl.BlockSpec((bm, 1, br), lambda i, j, r: (j, 0, r)),  # lse[j]
         ],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((u + u_pad, m + m_pad), jnp.float32),

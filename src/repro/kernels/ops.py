@@ -5,16 +5,18 @@
   * "interpret"  — same kernel body, Python interpreter (CPU validation),
   * "jnp"        — the pure-jnp oracle from ref.py.
 
-On this CPU container the default is "interpret" for small inputs in tests
-and "jnp" for the federation runtime (fastest on CPU); on a real TPU the
-default flips to "pallas". The numerical contract is identical.
+The default (``backend=None``) is "pallas" on a TPU and "jnp" elsewhere;
+the CPU paths exist for tests, and on the chip nothing gives way to them.
+The numerical contract is identical, up to the TPU's bf16 matmul passes.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.kernels import dequant_kl as _dk
 from repro.kernels import neighbor_mean as _nm
@@ -27,6 +29,34 @@ from repro.kernels.backend import (  # noqa: F401  (public re-exports)
     resolve_interpret,
     set_default_backend,
 )
+
+
+def operand_mesh(*args):
+    """The multi-device mesh the first mesh-placed operand lives on, or
+    None (single-device arrays, and tracers, which carry no placement)."""
+    for a in args:
+        s = getattr(a, "sharding", None)
+        if isinstance(s, NamedSharding) and s.mesh.size > 1:
+            return s.mesh
+    return None
+
+
+def replicated(kernel, mesh):
+    """``kernel`` run whole on every device of ``mesh``. Mosaic kernels
+    cannot be partitioned automatically, and a ``pallas_call``'s output
+    carries no varying-manual-axes type, hence ``check_vma=False``."""
+    return jax.shard_map(kernel, mesh=mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)
+
+
+def _pallas(kernel, *args, **kwargs):
+    """Call a Pallas kernel on operands wherever they live: arrays spread
+    over the client mesh (the sharded server's repository, graph and
+    targets) run it replicated on that mesh, no partitioner involved."""
+    kernel = functools.partial(kernel, **kwargs)
+    mesh = operand_mesh(*args)
+    return kernel(*args) if mesh is None else replicated(kernel,
+                                                         mesh)(*args)
 
 
 # Above this many rows the square divergence rebuild streams row-block
@@ -53,7 +83,8 @@ def pairwise_kl(logp: jnp.ndarray, backend: Optional[str] = None,
     backend = backend or default_backend()
     if backend == "jnp":
         return _ref.pairwise_kl_ref(logp)
-    return _pk.pairwise_kl(logp, interpret=(backend == "interpret"), **blocks)
+    return _pallas(_pk.pairwise_kl, logp,
+                   interpret=(backend == "interpret"), **blocks)
 
 
 # strips are hot-path (delta rounds, chunked rebuilds): jit the oracle so
@@ -70,8 +101,8 @@ def pairwise_kl_pair(logp_a: jnp.ndarray, logp_b: jnp.ndarray,
     backend = backend or default_backend()
     if backend == "jnp":
         return _pair_ref_jit(logp_a, logp_b)
-    return _pk.pairwise_kl_pair(logp_a, logp_b,
-                                interpret=(backend == "interpret"), **blocks)
+    return _pallas(_pk.pairwise_kl_pair, logp_a, logp_b,
+                   interpret=(backend == "interpret"), **blocks)
 
 
 # the oracle materializes the dense fp32 decode; jit so the dequant and
@@ -90,8 +121,8 @@ def int8_pairwise_kl(q: jnp.ndarray, scale: jnp.ndarray, zp: jnp.ndarray,
     backend = backend or default_backend()
     if backend == "jnp":
         return _int8_ref_jit(q, scale, zp)
-    return _dk.int8_pairwise_kl(q, scale, zp,
-                                interpret=(backend == "interpret"), **blocks)
+    return _pallas(_dk.int8_pairwise_kl, q, scale, zp,
+                   interpret=(backend == "interpret"), **blocks)
 
 
 # jitted for the same reason as the square form: the double dequant +
@@ -113,9 +144,8 @@ def int8_pairwise_kl_pair(qa: jnp.ndarray, sa: jnp.ndarray,
     backend = backend or default_backend()
     if backend == "jnp":
         return _int8_pair_ref_jit(qa, sa, zpa, qb, sb, zpb)
-    return _dk.int8_pairwise_kl_pair(qa, sa, zpa, qb, sb, zpb,
-                                     interpret=(backend == "interpret"),
-                                     **blocks)
+    return _pallas(_dk.int8_pairwise_kl_pair, qa, sa, zpa, qb, sb, zpb,
+                   interpret=(backend == "interpret"), **blocks)
 
 
 def soft_ce(logits: jnp.ndarray, labels: jnp.ndarray,
@@ -124,8 +154,8 @@ def soft_ce(logits: jnp.ndarray, labels: jnp.ndarray,
     backend = backend or default_backend()
     if backend == "jnp":
         return _ref.soft_ce_ref(logits, labels)
-    return _sc.soft_ce(logits, labels, interpret=(backend == "interpret"),
-                       **blocks)
+    return _pallas(_sc.soft_ce, logits, labels,
+                   interpret=(backend == "interpret"), **blocks)
 
 
 def neighbor_mean(w: jnp.ndarray, probs: jnp.ndarray,
@@ -134,5 +164,5 @@ def neighbor_mean(w: jnp.ndarray, probs: jnp.ndarray,
     backend = backend or default_backend()
     if backend == "jnp":
         return _ref.neighbor_mean_ref(w, probs)
-    return _nm.neighbor_mean(w, probs, interpret=(backend == "interpret"),
-                             **blocks)
+    return _pallas(_nm.neighbor_mean, w, probs,
+                   interpret=(backend == "interpret"), **blocks)

@@ -33,6 +33,7 @@ import json
 import time
 from typing import Optional, Union
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (ArrivalProcess, AsyncFederationEngine,
                         BurstyArrivals, EveryKUploads, FederationConfig,
                         FederationEngine, HeterogeneousCadence, Protocol,
@@ -175,6 +176,7 @@ def main() -> None:
             as_codec(getattr(args, which))
         except (KeyError, ValueError) as e:
             ap.error(f"--{which}: {e}")
+    enable_compile_cache()
 
     ds = DATASETS[args.dataset](samples_per_client=args.samples_per_client,
                                 ref_size=args.ref_size)

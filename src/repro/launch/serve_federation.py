@@ -27,6 +27,7 @@ import json
 import time
 
 import repro.serve  # registers query arrivals + batch policies
+from repro.compile_cache import enable_compile_cache
 from repro.core import (AsyncFederationEngine, FederationConfig, Protocol,
                         get_arrivals, registered_arrivals,
                         registered_policies, registered_triggers)
@@ -137,6 +138,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.until <= 0:
         ap.error("--until must be > 0")
+    enable_compile_cache()
 
     ds = DATASETS[args.dataset](samples_per_client=args.samples_per_client,
                                 ref_size=args.ref_size)
