@@ -152,7 +152,7 @@ def _concrete_pool(d):
     bucket = graph_mod._pool_bucket(cand, d["k"])
     if bucket is None:         # q=0 probe — cannot happen with DEFAULT_DIMS
         raise ValueError("probe candidate pool is empty")
-    return bucket
+    return tuple(jnp.asarray(a) for a in bucket)
 
 
 def _build_graph(d):

@@ -64,6 +64,7 @@ from repro.core.schedules import (ArrivalProcess, Schedule, StagedJoin,
 from repro.core.server import ServerState, init_server
 from repro.data.partition import ClientSplit, pack_cohort
 from repro.data.synthetic import FederatedDataset
+from repro.obs import span
 from repro.optim import Optimizer, sgd
 
 
@@ -375,23 +376,26 @@ class FederationEngine:
         """One federation round, in place: a full-federation wake for the
         schedule's availability mask, then (every ``interval`` rounds) an
         immediate zero-latency upload that fires the server round."""
-        fed = self.fed
-        t = float(rnd)
-        self.clock.advance(t)
-        avail_np = np.asarray(self.schedule.available(rnd, fed.n_clients),
-                              bool)
+        with span("repro.round", round=rnd):
+            fed = self.fed
+            t = float(rnd)
+            self.clock.advance(t)
+            avail_np = np.asarray(self.schedule.available(rnd,
+                                                          fed.n_clients),
+                                  bool)
 
-        # --- local steps (line 12) ---
-        use_ref = self.policy.uses_reference and rnd > 0
-        self.clients.local_round(avail_np, use_ref)
+            # --- local steps (line 12) ---
+            use_ref = self.policy.uses_reference and rnd > 0
+            self.clients.local_round(avail_np, use_ref)
 
-        # --- communication step (lines 5-10) ---
-        if self.policy.uses_reference and rnd % self.policy.interval == 0:
-            msg = self.clients.collect_messengers(avail_np)
-            self.bus.deliver(t, msg, avail_np)
-        else:
-            self.bus.observe(t, avail_np)
-        self._publish(t)   # fresh params become the serving snapshot
+            # --- communication step (lines 5-10) ---
+            if (self.policy.uses_reference
+                    and rnd % self.policy.interval == 0):
+                msg = self.clients.collect_messengers(avail_np)
+                self.bus.deliver(t, msg, avail_np)
+            else:
+                self.bus.observe(t, avail_np)
+            self._publish(t)   # fresh params become the serving snapshot
 
     # -- evaluation --------------------------------------------------------
     def evaluate(self, splits: Sequence[ClientSplit],

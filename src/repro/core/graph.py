@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quality import BIG
+from repro.obs import host_read, span
 
 
 class CollaborationGraph(NamedTuple):
@@ -78,15 +79,15 @@ def _topk_weights(sub: jnp.ndarray, pool: jnp.ndarray, k: int):
 
 
 def _pool_bucket(candidates, k: int):
-    """Candidate mask -> (padded pool indices, validity) or None if the
-    pool is empty. Power-of-two padding keeps jit compiles per-bucket."""
-    pool = np.nonzero(np.asarray(candidates, bool))[0].astype(np.int32)
+    """Candidate mask -> host (padded pool indices, validity) or None if
+    the pool is empty. Power-of-two padding keeps jit compiles
+    per-bucket."""
+    pool = np.nonzero(host_read(candidates, "select.pool", bool))[0]
     if pool.size == 0 or k == 0:
         return None
     bucket = max(1 << (pool.size - 1).bit_length(), k)
-    pool_valid = np.arange(bucket) < pool.size
-    return (jnp.asarray(np.pad(pool, (0, bucket - pool.size))),
-            jnp.asarray(pool_valid))
+    return (np.pad(pool.astype(np.int32), (0, bucket - pool.size)),
+            np.arange(bucket) < pool.size)
 
 
 def _select_dense(similarity: jnp.ndarray, candidates: jnp.ndarray, k: int):
@@ -128,7 +129,10 @@ def select_neighbors(similarity: jnp.ndarray, candidates: jnp.ndarray,
             neighbors=jnp.zeros((n, k), jnp.int32),
             weights=jnp.zeros((n, n), jnp.float32),
             similarity=similarity, candidates=candidates)
-    nbrs, w = _select_pool(similarity, *bucket, k)
+    pool, valid = bucket
+    with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
+        nbrs, w = _select_pool(similarity, jnp.asarray(pool),
+                               jnp.asarray(valid), k)
     return CollaborationGraph(neighbors=nbrs, weights=w,
                               similarity=similarity, candidates=candidates)
 
@@ -156,7 +160,10 @@ def select_neighbors_from_div(divergence: jnp.ndarray, candidates: jnp.ndarray,
             weights=jnp.zeros((n, n), jnp.float32),
             similarity=similarity_matrix(divergence), candidates=candidates,
             divergence=divergence)
-    sim, nbrs, w = _select_pool_div(divergence, *bucket, k)
+    pool, valid = bucket
+    with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
+        sim, nbrs, w = _select_pool_div(divergence, jnp.asarray(pool),
+                                        jnp.asarray(valid), k)
     return CollaborationGraph(neighbors=nbrs, weights=w, similarity=sim,
                               candidates=candidates, divergence=divergence)
 

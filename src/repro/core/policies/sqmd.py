@@ -11,6 +11,7 @@ from repro.core import graph as graph_mod
 from repro.core import quality as quality_mod
 from repro.core import similarity as sim_mod
 from repro.core.policies.base import ServerPolicy, register_policy
+from repro.obs import host_read
 
 
 @register_policy("sqmd")
@@ -71,16 +72,16 @@ class SQMDPolicy(ServerPolicy):
         if uploaded.dtype != bool:
             raise TypeError(f"uploaded must be a boolean mask, got dtype "
                             f"{uploaded.dtype}")
-        active = np.asarray(state.active, bool)
+        active = host_read(state.active, "ivf.active", bool)
         # first fire must also ingest rows that joined before the index
         # existed; re-uploads refresh their wire form + lists
         ingest = (uploaded | ~idx.active_rows()) & active
         rows = np.nonzero(ingest)[0]
         if rows.size:
-            idx.update(rows, np.asarray(state.repo_logp)[rows])
+            idx.update(rows, host_read(state.repo_logp, "ivf.repo")[rows])
         idx.sync_active(active)
-        cand = np.asarray(quality_mod.candidate_mask(
-            quality, state.active, self.protocol.q), bool)
+        cand = host_read(quality_mod.candidate_mask(
+            quality, state.active, self.protocol.q), "ivf.pool", bool)
         n = active.shape[0]
         k = max(1, min(self.protocol.k, n - 1))
         nbrs, ndiv = idx.select(cand, k)

@@ -42,6 +42,7 @@ from repro.core.client import (cohort_messenger_upload, cohort_step,
 from repro.core.server import (policy_round, staleness_summary,
                                upload_messengers)
 from repro.data.pipeline import cohort_batch, cohort_batch_padded
+from repro.obs import host_read, span
 
 # --------------------------------------------------------------------------
 # Clock / Event
@@ -290,46 +291,55 @@ class ClientRuntime:
     def local_round(self, mask_np: np.ndarray, use_ref: bool) -> None:
         """One local round for the masked clients, in place."""
         fed, cfg = self.fed, self.config
-        n, r, c = fed.server.repo_logp.shape
-        if fed.targets is None:
-            fed.targets = jnp.full((n, r, c), 1.0 / c, jnp.float32)
-        self.ever_woken |= mask_np
-        avail = jnp.asarray(mask_np)
-        for _ in range(cfg.local_steps):
-            for coh in fed.cohorts:
-                # cohorts are independently placed: each runs on its own
-                # (sub)mesh's pinned jit; per-family optimizers override
-                # the federation-wide default when the zoo set them
-                step = (cohort_step if coh.sharding is None
-                        else sharded_cohort_step(coh.sharding.mesh))
-                opt = coh.optimizer or fed.optimizer
-                fed.rng, sub = jax.random.split(fed.rng)
-                if coh.n_pad == 0:
-                    batch = cohort_batch(sub, coh.data, cfg.batch_size)
-                    rows = jnp.asarray(coh.client_ids)
-                    on = avail[rows]
-                else:
-                    batch = cohort_batch_padded(sub, coh.data,
-                                                cfg.batch_size,
-                                                coh.n_clients)
-                    rows = jnp.asarray(coh.padded_ids)
-                    # ghost rows alias the last real client's global id;
-                    # force them out of the trainable mask regardless
-                    on = avail[rows] & (jnp.arange(coh.n_rows)
-                                        < coh.n_clients)
-                tgt = fed.targets[rows]
-                if (self.mesh is not None and coh.sharding is not None
-                        and coh.sharding.mesh.devices.size
-                        < self.mesh.devices.size):
-                    # tiny bucket on a device subset: the target rows may
-                    # be committed to the FULL device set (the server
-                    # emits mesh-wide); re-place them on the bucket's
-                    # submesh so the pinned jit sees one device set
-                    tgt = jax.device_put(tgt, coh.sharding)
-                coh.params, coh.opt_state, _ = step(
-                    coh.apply_fn, opt, coh.params, coh.opt_state,
-                    batch["x"], batch["y"], fed.ref_x, tgt,
-                    on, self.policy.rho, use_ref)
+        with span("repro.local_round"):
+            n, r, c = fed.server.repo_logp.shape
+            if fed.targets is None:
+                fed.targets = jnp.full((n, r, c), 1.0 / c, jnp.float32)
+            self.ever_woken |= mask_np
+            avail = jnp.asarray(mask_np)
+            for _ in range(cfg.local_steps):
+                for coh in fed.cohorts:
+                    with span("repro.cohort_step", family=coh.family_name,
+                              clients=coh.n_clients):
+                        self._step_cohort(coh, avail, use_ref)
+
+    def _step_cohort(self, coh, avail: jnp.ndarray, use_ref: bool) -> None:
+        """One cohort's step: draw its batch, gather its availability and
+        targets, and dispatch the jitted step."""
+        fed, cfg = self.fed, self.config
+        # cohorts are independently placed: each runs on its own
+        # (sub)mesh's pinned jit; per-family optimizers override the
+        # federation-wide default when the zoo set them
+        step = (cohort_step if coh.sharding is None
+                else sharded_cohort_step(coh.sharding.mesh))
+        opt = coh.optimizer or fed.optimizer
+        with span("repro.cohort_batch", family=coh.family_name):
+            fed.rng, sub = jax.random.split(fed.rng)
+            if coh.n_pad == 0:
+                batch = cohort_batch(sub, coh.data, cfg.batch_size)
+                rows = jnp.asarray(coh.client_ids)
+                on = avail[rows]
+            else:
+                batch = cohort_batch_padded(sub, coh.data, cfg.batch_size,
+                                            coh.n_clients)
+                rows = jnp.asarray(coh.padded_ids)
+                # ghost rows alias the last real client's global id;
+                # force them out of the trainable mask regardless
+                on = avail[rows] & (jnp.arange(coh.n_rows)
+                                    < coh.n_clients)
+            tgt = fed.targets[rows]
+            if (self.mesh is not None and coh.sharding is not None
+                    and coh.sharding.mesh.devices.size
+                    < self.mesh.devices.size):
+                # tiny bucket on a device subset: the target rows may be
+                # committed to the FULL device set (the server emits
+                # mesh-wide); re-place them on the bucket's submesh so
+                # the pinned jit sees one device set
+                tgt = jax.device_put(tgt, coh.sharding)
+        coh.params, coh.opt_state, _ = step(
+            coh.apply_fn, opt, coh.params, coh.opt_state,
+            batch["x"], batch["y"], fed.ref_x, tgt,
+            on, self.policy.rho, use_ref)
 
     def collect_messengers(self,
                            mask_np: Optional[np.ndarray] = None
@@ -340,34 +350,40 @@ class ClientRuntime:
         fed = self.fed
         n, r, c = fed.server.repo_logp.shape
         parts, rows = [], []
-        for coh in fed.cohorts:
-            if mask_np is not None and not mask_np[coh.client_ids].any():
-                continue
-            up = (cohort_messenger_upload if coh.sharding is None
-                  else sharded_messenger_upload(coh.sharding.mesh))
-            part = up(coh.apply_fn, coh.params, fed.ref_x,
-                      codec=self.uplink)
-            if coh.n_pad:
-                # ghost rows never upload: slice the payload back to the
-                # real clients before it enters the N-stack
-                part = wire.gather(part, np.arange(coh.n_clients))
-            if (self.mesh is not None and coh.sharding is not None
-                    and coh.sharding.mesh.devices.size
-                    < self.mesh.devices.size):
-                # tiny-bucket payloads live on a device subset; replicate
-                # them over the full mesh so the N-stack scatter sees one
-                # device set across all cohorts
-                from jax.sharding import NamedSharding, PartitionSpec
-                rep = NamedSharding(self.mesh, PartitionSpec())
-                part = wire.Payload(
-                    part.codec, part.domain, part.shape,
-                    {k: jax.device_put(a, rep)
-                     for k, a in part.arrays.items()})
-            parts.append(part)
-            rows.append(coh.client_ids)
-        if not parts:
-            return self.uplink.encode(jnp.zeros((n, r, c), jnp.float32))
-        return wire.assemble(parts, rows, n)
+        with span("repro.collect_messengers"):
+            for coh in fed.cohorts:
+                if mask_np is not None and not mask_np[coh.client_ids].any():
+                    continue
+                with span("repro.upload", family=coh.family_name):
+                    parts.append(self._upload(coh))
+                rows.append(coh.client_ids)
+            if not parts:
+                return self.uplink.encode(jnp.zeros((n, r, c), jnp.float32))
+            with span("repro.assemble"):
+                return wire.assemble(parts, rows, n)
+
+    def _upload(self, coh) -> wire.Payload:
+        """One cohort's wire-encoded messengers, real clients only."""
+        up = (cohort_messenger_upload if coh.sharding is None
+              else sharded_messenger_upload(coh.sharding.mesh))
+        part = up(coh.apply_fn, coh.params, self.fed.ref_x,
+                  codec=self.uplink)
+        if coh.n_pad:
+            # ghost rows never upload: slice the payload back to the
+            # real clients before it enters the N-stack
+            part = wire.gather(part, np.arange(coh.n_clients))
+        if (self.mesh is not None and coh.sharding is not None
+                and coh.sharding.mesh.devices.size
+                < self.mesh.devices.size):
+            # tiny-bucket payloads live on a device subset; replicate
+            # them over the full mesh so the N-stack scatter sees one
+            # device set across all cohorts
+            from jax.sharding import NamedSharding, PartitionSpec
+            rep = NamedSharding(self.mesh, PartitionSpec())
+            part = wire.Payload(
+                part.codec, part.domain, part.shape,
+                {k: jax.device_put(a, rep) for k, a in part.arrays.items()})
+        return part
 
 
 # --------------------------------------------------------------------------
@@ -381,9 +397,9 @@ class ServerBus:
     ``deliver`` merges the masked rows into the repository via
     ``upload_messengers`` — rows of clients not in the mask keep their
     stale value (merged, never dropped) — then asks the trigger whether to
-    run ``policy_round``. ``tick`` is the wall-interval hook. Staleness of
-    every repository row (virtual age of its newest merge) is summarized
-    at each fire and at eval time.
+    run ``policy_round``. ``tick`` is the wall-interval hook.
+    ``staleness`` summarizes every repository row's virtual age since its
+    newest merge, when asked (the engines ask at eval time).
 
     ``delta=True`` hands each fire the accumulated fresh-uploader mask so
     the policy can take its incremental O(u·N) graph update
@@ -436,7 +452,6 @@ class ServerBus:
         self.bytes_up = np.zeros(n)    # cumulative uplink wire bytes
         self.bytes_down = np.zeros(n)  # cumulative downlink wire bytes
         self.last_graph = None
-        self.last_staleness: Optional[dict] = None
 
     @property
     def uplink(self) -> wire.Codec:
@@ -466,23 +481,24 @@ class ServerBus:
         refreshed). The trigger is consulted even for an empty batch, so
         an every-upload (sync) communication round with no available
         client still fires its policy round."""
-        if not isinstance(msg, wire.Payload):
-            msg = self.uplink.encode(jnp.asarray(msg))
         sent = np.asarray(uploaded, bool)
-        self.bytes_up[sent] += wire.bytes_per_messenger(msg)
         pt = t if produced_at is None else produced_at
         up = sent & (pt >= self.last_upload_t)
-        fed = self.fed
-        fed.server = upload_messengers(fed.server, msg, jnp.asarray(up))
-        self.last_upload_t = np.where(up, pt, self.last_upload_t)
         k = int(up.sum())
-        self.n_uploads += k
-        self.uploads_since_fire += k
-        self.fresh_since_fire |= up
-        if self.trigger.should_fire(t, self):
-            self.fire(t)
-            return True
-        return False
+        with span("repro.deliver", rows=k, fire=self.n_triggers):
+            if not isinstance(msg, wire.Payload):
+                msg = self.uplink.encode(jnp.asarray(msg))
+            self.bytes_up[sent] += wire.bytes_per_messenger(msg)
+            fed = self.fed
+            fed.server = upload_messengers(fed.server, msg, jnp.asarray(up))
+            self.last_upload_t = np.where(up, pt, self.last_upload_t)
+            self.n_uploads += k
+            self.uploads_since_fire += k
+            self.fresh_since_fire |= up
+            if self.trigger.should_fire(t, self):
+                self.fire(t)
+                return True
+            return False
 
     def tick(self, t: float) -> bool:
         """Wall tick: fire if the trigger wants to and new uploads exist
@@ -499,26 +515,30 @@ class ServerBus:
         DECODED payload, and its bytes are charged to the policy's
         receiver set (K^n payloads per client)."""
         fed = self.fed
-        uploaded = self.fresh_since_fire.copy() if self.delta else None
-        fed.server, targets, self.last_graph = policy_round(
-            fed.server, self.policy, fed.ref_y, backend=self.backend,
-            uploaded=uploaded)
-        payload = self.downlink.encode(targets, domain="prob")
-        decoded = wire.decode(payload)
-        recv = np.asarray(self.policy.receivers(fed.server,
-                                                self.last_graph), bool)
-        if not recv.all():
-            # nothing is sent to excluded rows, so nothing must arrive: a
-            # lossy decode would otherwise turn their zero target rows
-            # into spurious near-uniform distributions they train toward
-            decoded = jnp.where(jnp.asarray(recv)[:, None, None],
-                                decoded, 0.0)
-        fed.targets = decoded
-        self.bytes_down[recv] += wire.bytes_per_messenger(payload)
-        self.n_triggers += 1
-        self.last_staleness = self.staleness(t)
-        self.uploads_since_fire = 0
-        self.fresh_since_fire[:] = False
+        with span("repro.fire", fire=self.n_triggers, delta=self.delta,
+                  rows=int(self.fresh_since_fire.sum())):
+            uploaded = self.fresh_since_fire.copy() if self.delta else None
+            fed.server, targets, self.last_graph = policy_round(
+                fed.server, self.policy, fed.ref_y, backend=self.backend,
+                uploaded=uploaded)
+            with span("repro.downlink"):
+                payload = self.downlink.encode(targets, domain="prob")
+                decoded = wire.decode(payload)
+            recv = host_read(self.policy.receivers(fed.server,
+                                                   self.last_graph),
+                             "fire.receivers", bool)
+            if not recv.all():
+                # nothing is sent to excluded rows, so nothing must
+                # arrive: a lossy decode would otherwise turn their zero
+                # target rows into spurious near-uniform distributions
+                # they train toward
+                decoded = jnp.where(jnp.asarray(recv)[:, None, None],
+                                    decoded, 0.0)
+            fed.targets = decoded
+            self.bytes_down[recv] += wire.bytes_per_messenger(payload)
+            self.n_triggers += 1
+            self.uploads_since_fire = 0
+            self.fresh_since_fire[:] = False
 
     def observe(self, t: float, mask_np: np.ndarray) -> None:
         """Non-communication round: mark the masked clients active and

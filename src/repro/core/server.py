@@ -25,6 +25,7 @@ import numpy as np
 from repro.core import quality as quality_mod
 from repro.core import wire
 from repro.core.protocols import Protocol
+from repro.obs import host_read, span
 
 
 class ServerState(NamedTuple):
@@ -67,7 +68,7 @@ def upload_messengers(state: ServerState,
     this round keep their STALE repository row — the paper's
     asynchronous semantics."""
     if isinstance(messengers_logp, wire.Payload):
-        up_np = np.asarray(uploaded, bool)
+        up_np = host_read(uploaded, "deliver.mask", bool)
         rows = np.nonzero(up_np)[0]
         if (len(messengers_logp.shape) == 3
                 and messengers_logp.shape[0] == up_np.size
@@ -135,12 +136,18 @@ def policy_round(state: ServerState, policy, ref_labels: jnp.ndarray,
     its incremental O(u·N) graph-update path (``build_graph_delta``)
     instead of the O(N²) full rebuild. ``uploaded=None`` (the default, and
     the legacy ``server_round`` contract) always rebuilds from scratch."""
-    g = policy.grade(state, ref_labels, backend=backend)
+    with span("repro.grade"):
+        g = policy.grade(state, ref_labels, backend=backend)
     if uploaded is None:
-        graph = policy.build_graph(state, g, backend=backend)
+        with span("repro.build_graph", path="full"):
+            graph = policy.build_graph(state, g, backend=backend)
     else:
-        graph = policy.build_graph_delta(state, g, uploaded, backend=backend)
-    targets = policy.emit_targets(state, graph, backend=backend)
+        path = "ivf" if policy.selection == "ivf" else "delta"
+        with span("repro.build_graph", path=path):
+            graph = policy.build_graph_delta(state, g, uploaded,
+                                             backend=backend)
+    with span("repro.emit_targets"):
+        targets = policy.emit_targets(state, graph, backend=backend)
     return policy.update_state(state, g, graph), targets, graph
 
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.obs import host_read, span
 
 EPS = 1e-8
 
@@ -181,18 +182,20 @@ def update_divergence_cache(cache: jnp.ndarray, messengers_logp: jnp.ndarray,
         return cache
     if rows.size >= messengers_logp.shape[0]:
         return divergence_matrix(messengers_logp, backend=backend)
-    rows = jnp.asarray(_bucket_rows(rows))
-    backend = backend or ops.default_backend()
-    if backend == "jnp":
-        n, r, c = messengers_logp.shape
-        lp = messengers_logp.astype(jnp.float32).reshape(n, r * c)
-        return _delta_update(cache, lp, rows, r)
-    fresh = messengers_logp[rows]
-    row_strip = ops.pairwise_kl_pair(fresh, messengers_logp,
-                                     backend=backend)       # (u, N)
-    col_strip = ops.pairwise_kl_pair(messengers_logp, fresh,
-                                     backend=backend)       # (N, u)
-    return _scatter_strips(cache, rows, row_strip, col_strip)
+    bucket = _bucket_rows(rows)
+    with span("repro.div_update", rows=rows.size, bucket=bucket.size):
+        rows = jnp.asarray(bucket)
+        backend = backend or ops.default_backend()
+        if backend == "jnp":
+            n, r, c = messengers_logp.shape
+            lp = messengers_logp.astype(jnp.float32).reshape(n, r * c)
+            return _delta_update(cache, lp, rows, r)
+        fresh = messengers_logp[rows]
+        row_strip = ops.pairwise_kl_pair(fresh, messengers_logp,
+                                         backend=backend)       # (u, N)
+        col_strip = ops.pairwise_kl_pair(messengers_logp, fresh,
+                                         backend=backend)       # (N, u)
+        return _scatter_strips(cache, rows, row_strip, col_strip)
 
 
 @jax.jit
@@ -373,9 +376,10 @@ class NeighborIndex:
     def _centroid_div(self, rows: np.ndarray) -> np.ndarray:
         """(u, ncent) exact Eq.2 divergence row -> centroid (the
         assignment/probing metric — same metric as the lists hold)."""
-        return np.asarray(ops.pairwise_kl_pair(
+        return host_read(ops.pairwise_kl_pair(
             jnp.asarray(self._recon_logp(rows)),
-            jnp.asarray(self._centroids), backend=self.backend))
+            jnp.asarray(self._centroids), backend=self.backend),
+            "ivf.centroid_div")
 
     def _effective_probe(self) -> int:
         ncent = self.n_centroids
@@ -389,12 +393,12 @@ class NeighborIndex:
         """Exact (|a|,|b|) KL strip straight off the stored wire form."""
         zp_a = np.zeros_like(self._scale[rows_a])
         zp_b = np.zeros_like(self._scale[rows_b])
-        return np.asarray(ops.int8_pairwise_kl_pair(
+        return host_read(ops.int8_pairwise_kl_pair(
             jnp.asarray(self._codes[rows_a]),
             jnp.asarray(self._scale[rows_a]), jnp.asarray(zp_a),
             jnp.asarray(self._codes[rows_b]),
             jnp.asarray(self._scale[rows_b]), jnp.asarray(zp_b),
-            backend=self.backend))
+            backend=self.backend), "ivf.strip")
 
     def _search(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """rows (u,) -> (candidates (m,), fwd strip (u,m)).
@@ -471,9 +475,9 @@ class NeighborIndex:
         rows pass through ``update``."""
         rows = np.asarray(rows, np.int64)
         q, s, l = _encode_wire_rows(jnp.asarray(logp))
-        self._codes[rows] = np.asarray(q)
-        self._scale[rows] = np.asarray(s)
-        self._lse[rows] = np.asarray(l)
+        self._codes[rows] = host_read(q, "ivf.ingest")
+        self._scale[rows] = host_read(s, "ivf.ingest")
+        self._lse[rows] = host_read(l, "ivf.ingest")
         self._active[rows] = True
 
     def update(self, rows, logp) -> int:

@@ -127,6 +127,7 @@ def _call_pair(qa, sa, la, qb, sb, lb, bn, bm, br, interpret):
         out_specs=pl.BlockSpec((bn, bm), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((u + u_pad, m + m_pad), jnp.float32),
         interpret=interpret,
+        name="int8_pairwise_kl",
     )(qa_p, sa_p, la_p, qb_p, sb_p, lb_p)
     return out[:u, :m]
 

@@ -67,5 +67,6 @@ def neighbor_mean(w: jnp.ndarray, probs: jnp.ndarray, bn: int = DEFAULT_BN,
         out_specs=pl.BlockSpec((bn, bk), lambda i, k, j: (i, k)),
         out_shape=jax.ShapeDtypeStruct((n + n_pad, rc + k_pad), jnp.float32),
         interpret=interpret,
+        name="neighbor_mean",
     )(w_p, s_p)
     return out[:n, :rc].reshape(n, r, c)

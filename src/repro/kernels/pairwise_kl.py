@@ -90,6 +90,7 @@ def _pair_call(lp_a: jnp.ndarray, lp_b: jnp.ndarray, r: int, bn: int,
         out_specs=pl.BlockSpec((bn, bm), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((u + n_pad, m + m_pad), jnp.float32),
         interpret=interpret,
+        name="pairwise_kl",
     )(p_p, la_p, lb_p)
     return out[:u, :m]
 

@@ -77,5 +77,6 @@ def soft_ce(logits: jnp.ndarray, labels: jnp.ndarray, bn: int = DEFAULT_BN,
         out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + n_pad, 1), jnp.float32),
         interpret=interpret,
+        name="soft_ce",
     )(z, y)
     return out[:n, 0]

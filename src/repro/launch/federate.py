@@ -20,6 +20,11 @@ Messenger wire formats (bandwidth accounting lands in the summary):
   PYTHONPATH=src python -m repro.launch.federate --uplink int8 \
       --downlink topk:4 --rounds 40
 
+A profiler trace of the run, with the program's repro.* spans (README,
+"Tracing"), readable in TensorBoard or Perfetto:
+
+  PYTHONPATH=src python -m repro.launch.federate --rounds 5 --profile runs/prof
+
 Multi-device client sharding (cohort steps + server divergence rows shard
 over a 1-D client mesh; fake host devices for CPU testing):
 
@@ -29,9 +34,12 @@ over a 1-D client mesh; fake host devices for CPU testing):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 from typing import Optional, Union
+
+import jax
 
 from repro.compile_cache import enable_compile_cache
 from repro.core import (ArrivalProcess, AsyncFederationEngine,
@@ -165,6 +173,10 @@ def main() -> None:
     ap.add_argument("--label-noise", type=float, default=0.3)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt")
+    ap.add_argument("--profile", metavar="DIR",
+                    help="record a JAX profiler trace of the run into DIR "
+                         "(the repro.* spans: round, cohort step, upload, "
+                         "deliver, fire, host syncs; see README, Tracing)")
     args = ap.parse_args()
     if args.rounds < 1:
         ap.error("--rounds must be >= 1")
@@ -203,26 +215,30 @@ def main() -> None:
                               selection=args.selection,
                               verbose=True)
     t0 = time.time()
-    if args.clock == "event":
-        arrivals = make_arrivals(args, ds.n_clients, args.rounds)
-        trigger = make_trigger(args)
-        print(f"policy={args.policy} clock=event arrivals={arrivals!r} "
-              f"trigger={trigger!r} dataset={args.dataset} "
-              f"clients={ds.n_clients} config={config}")
-        engine = AsyncFederationEngine.build(
-            ds, splits, zoo, assignment, protocol, arrivals=arrivals,
-            trigger=trigger, config=config, seed=args.seed + 1)
-        hist = engine.fit(splits, until=args.until)
-    else:
-        schedule = make_schedule(args, ds.n_clients, args.rounds)
-        print(f"policy={args.policy} schedule={schedule or 'always-on'} "
-              f"dataset={args.dataset} clients={ds.n_clients} "
-              f"config={config}")
-        engine = FederationEngine.build(ds, splits, zoo, assignment,
-                                        protocol, config=config,
-                                        schedule=schedule,
-                                        seed=args.seed + 1)
-        hist = engine.fit(splits)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        if args.clock == "event":
+            arrivals = make_arrivals(args, ds.n_clients, args.rounds)
+            trigger = make_trigger(args)
+            print(f"policy={args.policy} clock=event "
+                  f"arrivals={arrivals!r} "
+                  f"trigger={trigger!r} dataset={args.dataset} "
+                  f"clients={ds.n_clients} config={config}")
+            engine = AsyncFederationEngine.build(
+                ds, splits, zoo, assignment, protocol, arrivals=arrivals,
+                trigger=trigger, config=config, seed=args.seed + 1)
+            hist = engine.fit(splits, until=args.until)
+        else:
+            schedule = make_schedule(args, ds.n_clients, args.rounds)
+            print(f"policy={args.policy} "
+                  f"schedule={schedule or 'always-on'} "
+                  f"dataset={args.dataset} clients={ds.n_clients} "
+                  f"config={config}")
+            engine = FederationEngine.build(ds, splits, zoo, assignment,
+                                            protocol, config=config,
+                                            schedule=schedule,
+                                            seed=args.seed + 1)
+            hist = engine.fit(splits)
     prec, rec = precision_recall(engine.fed, splits, ds.n_classes)
     summary = {
         "policy": args.policy, "dataset": args.dataset,
