@@ -2,7 +2,7 @@
 
 Times the jnp runtime path on CPU and reports the analytic TPU roofline of
 the Pallas path (the kernels are MXU matmuls; see DESIGN.md §4):
-  pairwise_kl: 2·N²·R·C flops; neighbor_mean: 2·N²·R·C; soft_ce: ~5·N·R·C.
+  pairwise_kl: 2·N²·R·C flops; neighbor_mean: 2·N·K·R·C; soft_ce: ~5·N·R·C.
 """
 from __future__ import annotations
 
@@ -43,12 +43,15 @@ def run(verbose=True):
         logits = jax.random.normal(key, (n, r, c)) * 2
         logp = jax.nn.log_softmax(logits, -1)
         labels = jax.random.randint(jax.random.key(1), (r,), 0, c)
-        w = jnp.full((n, n), 1.0 / n)
+        k = min(8, n - 1)
+        nbrs = jax.random.randint(jax.random.key(2), (n, k), 0, n)
+        w = jnp.full((n, k), 1.0 / k)
         probs = jnp.exp(logp)
 
         t_kl = _time(lambda a: ops.pairwise_kl(a, backend="jnp"), logp)
         t_ce = _time(lambda a: ops.soft_ce(a, labels, backend="jnp"), logp)
-        t_nm = _time(lambda a: ops.neighbor_mean(w, a, backend="jnp"), probs)
+        t_nm = _time(lambda a: ops.neighbor_mean(nbrs, w, a, backend="jnp"),
+                     probs)
         kl_flops = 2.0 * n * n * r * c
         tpu_us = kl_flops / PEAK * 1e6
         rows.append({

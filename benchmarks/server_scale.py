@@ -96,9 +96,9 @@ def bench_one(n: int, r: int, c: int, uploads: int, backend: str,
     # (b) the whole graph build (divergence + Def.4/5 pool selection) —
     #     what one server trigger actually costs end to end
     t_full_g = _time(lambda: pol.build_graph(state, quality,
-                                             backend=backend).weights)
+                                             backend=backend).edge_weights)
     t_delta_g = _time(lambda: pol.build_graph_delta(
-        state, quality, mask, backend=backend).weights)
+        state, quality, mask, backend=backend).edge_weights)
     row = {
         "n_clients": n, "ref_size": r, "n_classes": c, "uploads": uploads,
         "backend": backend,

@@ -6,7 +6,7 @@
 One process, no children. The phases, one printed line each:
 
   1. device      — a TPU is present and the kernels dispatch to Pallas;
-  2. kernels     — the six Pallas kernels, compiled, against their jnp
+  2. kernels     — the seven Pallas kernels, compiled, against their jnp
                    oracles at the Sleep-Cassette widths (N=32, R=240, C=3);
   3. sync        — 3 rounds of the sync engine on ``sc_like`` with the
                    mlp-s/resnet/transformer/ssm zoo and the SQMD policy;
@@ -41,7 +41,7 @@ import numpy as np  # noqa: E402
 
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import (AsyncFederationEngine, FederationConfig,  # noqa: E402
-                        FederationEngine, Protocol)
+                        FederationEngine, Protocol, selection_matrix)
 from repro.core.wire import Int8  # noqa: E402
 from repro.data import make_splits, sc_like  # noqa: E402
 from repro.kernels import dequant_kl, neighbor_mean, ops, pairwise_kl, ref  # noqa: E402
@@ -128,11 +128,13 @@ def phase_kernels(setup, u: int = 4) -> None:
     it gets fp32 rounding over its R-term sum."""
     ds = setup[0]
     n, r, c = ds.n_clients, len(ds.ref_y), ds.n_classes
-    k1, k2, k3 = jax.random.split(jax.random.key(SEED), 3)
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(SEED), 4)
     logp = jax.nn.log_softmax(jax.random.normal(k1, (n, r, c)) * 2.0, -1)
     labels = jax.random.randint(k2, (r,), 0, c)
     w = jax.random.uniform(k3, (n, n))
     w = w / w.sum(1, keepdims=True)
+    nbrs = jax.random.randint(k4, (n, 8), 0, n)
+    edges = w[:, :8] / w[:, :8].sum(1, keepdims=True)
     probs = jnp.exp(logp)
     wire = Int8().encode(logp).arrays
     q, s, z = wire["q"], wire["scale"], wire["zp"]
@@ -153,10 +155,15 @@ def phase_kernels(setup, u: int = 4) -> None:
     want = _highest(ref.soft_ce_ref, logp, labels)
     lines.append(_report("soft_ce", got, want,
                          FP32_SLACK * np.maximum(np.abs(want), 1.0)))
-    got = neighbor_mean.neighbor_mean(w, probs, interpret=False)
+    got = neighbor_mean.neighbor_mean(nbrs, edges, probs, interpret=False)
+    want = _highest(ref.neighbor_mean_sparse_ref, nbrs, edges, probs)
+    # an fp32 sum of K nonnegative terms: fp32 rounding of its magnitude
+    lines.append(_report("neighbor_mean", got, want,
+                         FP32_SLACK * np.maximum(want, 1.0)))
+    got = neighbor_mean.neighbor_mean_dense(w, probs, interpret=False)
     want = _highest(ref.neighbor_mean_ref, w, probs)
     # w and probs are nonnegative, so the oracle is its own magnitude
-    lines.append(_report("neighbor_mean", got, want,
+    lines.append(_report("neighbor_mean_dense", got, want,
                          BF16_PASS * want + FP32_SLACK))
     got = dequant_kl.int8_pairwise_kl(q, s, z, interpret=False)
     lines.append(_report("int8_pairwise_kl", got,
@@ -196,7 +203,7 @@ def _summary(eng, wall: float) -> dict:
     check(np.isfinite(h.mean_acc).all() and np.isfinite(acc).all(),
           "non-finite accuracy")
     check(eng.last_graph is not None, "the server never built a graph")
-    edges = int(np.asarray(eng.last_graph.weights > 0).sum())
+    edges = int(np.asarray(selection_matrix(eng.last_graph) > 0).sum())
     check(edges > 0, "the last graph has no edges")
     check(h.bytes_up[-1] > 0, "no messenger bytes went up")
     return {"acc": round(h.mean_acc[-1], 4), "edges": edges,

@@ -9,7 +9,8 @@ graph the server last built.
 """
 import numpy as np
 
-from repro.core import FederationConfig, FederationEngine, graph_stats, sqmd
+from repro.core import (FederationConfig, FederationEngine, graph_stats,
+                        selection_matrix, sqmd)
 from repro.data import make_splits, pad_like
 from repro.models.mlp import hetero_mlp_zoo
 
@@ -43,7 +44,7 @@ def main():
     print("collaboration graph:", graph_stats(engine.last_graph))
 
     # how well did similarity recover the ground-truth clusters?
-    w = np.asarray(engine.server.weights)
+    w = np.asarray(selection_matrix(engine.last_graph))
     cl = ds.client_cluster
     hit = [np.mean(cl[np.where(w[i] > 0)[0]] == cl[i])
            for i in range(ds.n_clients)]

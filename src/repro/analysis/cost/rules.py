@@ -90,7 +90,9 @@ _POLICY_KERNELS: Dict[str, Dict[str, float]] = {
     "pairwise_kl_pair": {"intensity_floor": 1.5},
     "int8_pairwise_kl": {"intensity_floor": 15.0},
     "soft_ce": {"intensity_floor": 1.0},
-    "neighbor_mean": {"intensity_floor": 5.0},
+    # the K-sparse Eq. 5 is a gather and an elementwise sum: ~1 flop/byte
+    "neighbor_mean": {"intensity_floor": 0.5},
+    "neighbor_mean_dense": {"intensity_floor": 5.0},
 }
 
 # allow: sequence-adapter intermediates that LOOK like blowups at the
@@ -275,7 +277,7 @@ def kernel_probes() -> Dict[str, tuple]:
     from repro.kernels import ref
 
     d = entries_mod.DEFAULT_DIMS
-    n, r, c, u = d["n"], d["r"], d["c"], d["q"]
+    n, r, c, u, k = d["n"], d["r"], d["c"], d["q"], d["k"]
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     return {
         "pairwise_kl": (ref.pairwise_kl_ref, (f32(n, r, c),)),
@@ -286,8 +288,11 @@ def kernel_probes() -> Dict[str, tuple]:
                               f32(n, r), f32(n, r))),
         "soft_ce": (ref.soft_ce_ref,
                     (f32(n, r, c), jax.ShapeDtypeStruct((r,), jnp.int32))),
-        "neighbor_mean": (ref.neighbor_mean_ref,
-                          (f32(n, n), f32(n, r, c))),
+        "neighbor_mean": (ref.neighbor_mean_sparse_ref,
+                          (jax.ShapeDtypeStruct((n, k), jnp.int32),
+                           f32(n, k), f32(n, r, c))),
+        "neighbor_mean_dense": (ref.neighbor_mean_ref,
+                                (f32(n, n), f32(n, r, c))),
     }
 
 

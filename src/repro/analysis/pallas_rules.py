@@ -139,6 +139,7 @@ def run_kernel_probes() -> List[PallasCallRecord]:
     logp_b = logp[: 5 + bump]
     labels = jax.random.randint(jax.random.key(14), (r,), 0, c)
     w = jnp.ones((n, n), jnp.float32) / n
+    nbrs = jax.random.randint(jax.random.key(16), (n, 3), 0, n)
     q = jax.random.randint(jax.random.key(15), (n, r, c),
                            0, 256).astype(jnp.uint8)
     scale = jnp.full((n, r), 0.05, jnp.float32)
@@ -150,7 +151,8 @@ def run_kernel_probes() -> List[PallasCallRecord]:
         ops.pairwise_kl_pair(logp_b, logp, backend="interpret")
         ops.int8_pairwise_kl(q, scale, zp, backend="interpret")
         ops.soft_ce(logp, labels, backend="interpret")
-        ops.neighbor_mean(w, jnp.exp(logp), backend="interpret")
+        ops.neighbor_mean_dense(w, jnp.exp(logp), backend="interpret")
+        ops.neighbor_mean(nbrs, w[:, :3], jnp.exp(logp), backend="interpret")
     if not records:
         raise RuntimeError(
             "pallas_call interception recorded nothing — kernel probes "

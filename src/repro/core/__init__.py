@@ -4,7 +4,8 @@ from repro.core.engine import (AsyncFederationEngine, Federation,
                                FederationConfig, FederationEngine, History,
                                evaluate, precision_recall)
 from repro.core.graph import (CollaborationGraph, ddist_graph, fedmd_graph,
-                              graph_stats, select_neighbors)
+                              graph_stats, select_neighbors,
+                              selection_matrix)
 from repro.core.messenger import cohort_messengers, make_messenger
 from repro.core.policies import (DDistPolicy, FedMDPolicy, ISGDPolicy,
                                  SQMDPolicy, ServerPolicy, as_policy,
@@ -44,7 +45,8 @@ __all__ = [
     "HeterogeneousCadence", "BurstyArrivals", "as_arrivals", "get_arrivals",
     "register_arrivals", "registered_arrivals", "staleness_summary",
     "CollaborationGraph", "ddist_graph", "fedmd_graph", "graph_stats",
-    "select_neighbors", "cohort_messengers", "make_messenger",
+    "select_neighbors", "selection_matrix", "cohort_messengers",
+    "make_messenger",
     "Codec", "Payload", "as_codec", "bytes_per_messenger", "decode",
     "encode", "get_codec", "payload_bytes", "register_codec",
     "registered_codecs",

@@ -3,9 +3,11 @@
 G = (A, E, C): nodes are clients, the fp32 weight matrix C holds c_nm, and
 each round the server re-derives every client's neighbor set K^n — the K
 most-similar members of the quality pool Q (excluding the client itself).
-This module also produces the row-stochastic selection matrix W used by the
-neighbor_mean kernel (w_nm = 1/K on chosen edges), which IS the adjacency of
-the collaboration graph.
+SQMD's graph is carried K-sparse: ``neighbors`` (N,K) and ``edge_weights``
+(N,K), 1/count on each realized edge and 0 on unrealized slots, which is
+all Eq. 5 reads (``ops.neighbor_mean``). Graphs that are dense by nature
+(FedMD, D-Dist) carry the row-stochastic (N,N) ``weights`` instead;
+``selection_matrix`` gives either as the dense W off the hot path.
 """
 from __future__ import annotations
 
@@ -22,12 +24,43 @@ from repro.obs import host_read, span
 
 class CollaborationGraph(NamedTuple):
     neighbors: jnp.ndarray       # (N, K) int32 neighbor indices
-    weights: jnp.ndarray         # (N, N) fp32 row-stochastic selection matrix
+    weights: Optional[jnp.ndarray]  # (N, N) fp32 row-stochastic selection
+    # matrix of a dense graph; None on K-sparse graphs
     similarity: jnp.ndarray      # (N, N) fp32 c_nm (the C matrix of Def. 5)
     candidates: jnp.ndarray      # (N,) bool — the Q pool
     divergence: Optional[jnp.ndarray] = None  # (N,N) fp32 Eq.2 matrix this
     # graph was built from; policies that compute it surface it here so
     # update_state can persist it as ServerState.div_cache (delta path)
+    edge_weights: Optional[jnp.ndarray] = None  # (N, K) fp32 weight of
+    # each neighbor slot (0 on unrealized ones): set on K-sparse graphs
+
+
+def k_sparse(neighbors, edge_weights, similarity, candidates,
+             divergence=None) -> CollaborationGraph:
+    """A graph carried as its (N,K) neighbors and edge weights alone."""
+    return CollaborationGraph(neighbors=neighbors, weights=None,
+                              similarity=similarity, candidates=candidates,
+                              divergence=divergence,
+                              edge_weights=edge_weights)
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _scatter_edges(neighbors, edge_weights, n: int):
+    k = neighbors.shape[1]
+    rows = jnp.repeat(jnp.arange(n), k)
+    return jnp.zeros((n, n), jnp.float32).at[
+        rows, neighbors.reshape(-1)].add(
+            edge_weights.astype(jnp.float32).reshape(-1))
+
+
+def selection_matrix(g: CollaborationGraph) -> jnp.ndarray:
+    """The dense (N,N) row-stochastic W of any graph, for readers off the
+    hot path (stats, tests): ``weights`` where the graph carries it, else
+    scattered from its neighbors and edge weights."""
+    if g.weights is not None:
+        return g.weights
+    return _scatter_edges(g.neighbors, g.edge_weights,
+                          g.neighbors.shape[0])
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -58,24 +91,23 @@ def _select_pool_div(div: jnp.ndarray, pool: jnp.ndarray,
     i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     sim = c * (i != j).astype(c.dtype)
-    nbrs, w = _select_pool(sim, pool, pool_valid, k)
-    return sim, nbrs, w
+    nbrs, vals = _select_pool(sim, pool, pool_valid, k)
+    return sim, nbrs, vals
 
 
 def _topk_weights(sub: jnp.ndarray, pool: jnp.ndarray, k: int):
-    """(N,B) masked pool scores -> ((N,K) neighbors, (N,N) weights)."""
-    n = sub.shape[0]
+    """(N,B) masked pool scores -> ((N,K) neighbors, (N,K) edge weights):
+    1/count on each row's realized edges, 0 on the rest."""
     top_vals, top_sub = jax.lax.top_k(sub, k)               # (N, K)
     nbrs = pool[top_sub].astype(jnp.int32)
     valid = top_vals > -BIG / 2                             # realized edges
-    # row-normalize BEFORE the scatter: per-row 1/count on the realized
-    # edges costs O(N·K), versus sum+divide passes over the (N,N) matrix
     count = jnp.sum(valid.astype(jnp.float32), axis=1, keepdims=True)
-    vals = valid.astype(jnp.float32) / jnp.maximum(count, 1.0)
-    w = jnp.zeros((n, n), jnp.float32)
-    rows = jnp.repeat(jnp.arange(n), k)
-    w = w.at[rows, nbrs.reshape(-1)].add(vals.reshape(-1))
-    return nbrs, w
+    return nbrs, valid.astype(jnp.float32) / jnp.maximum(count, 1.0)
+
+
+def _empty(n: int, k: int):
+    """No candidates: K unrealized slots per row, all of weight 0."""
+    return jnp.zeros((n, k), jnp.int32), jnp.zeros((n, k), jnp.float32)
 
 
 def _pool_bucket(candidates, k: int):
@@ -107,8 +139,8 @@ def select_neighbors(similarity: jnp.ndarray, candidates: jnp.ndarray,
 
     Clients outside Q still get K neighbors (paper: 'any client, regardless
     of its quality, is assigned K neighbors'). A client never selects
-    itself. If fewer than K candidates exist, the selection matrix row is
-    renormalized over the realized edges.
+    itself. If fewer than K candidates exist, the row's edge weights are
+    renormalized over the realized edges (unrealized slots weigh 0).
 
     Only the Q candidate columns are ever eligible, so the top-k runs over
     the (N, Q) pool sub-matrix, not all N² scores. The pool index set is
@@ -119,22 +151,16 @@ def select_neighbors(similarity: jnp.ndarray, candidates: jnp.ndarray,
     n = similarity.shape[0]
     k = min(k, n - 1)
     if isinstance(candidates, jax.core.Tracer):
-        nbrs, w = _select_dense(similarity, candidates, k)
-        return CollaborationGraph(neighbors=nbrs, weights=w,
-                                  similarity=similarity,
-                                  candidates=candidates)
+        return k_sparse(*_select_dense(similarity, candidates, k),
+                        similarity, candidates)
     bucket = _pool_bucket(candidates, k)
     if bucket is None:
-        return CollaborationGraph(
-            neighbors=jnp.zeros((n, k), jnp.int32),
-            weights=jnp.zeros((n, n), jnp.float32),
-            similarity=similarity, candidates=candidates)
+        return k_sparse(*_empty(n, k), similarity, candidates)
     pool, valid = bucket
     with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
-        nbrs, w = _select_pool(similarity, jnp.asarray(pool),
-                               jnp.asarray(valid), k)
-    return CollaborationGraph(neighbors=nbrs, weights=w,
-                              similarity=similarity, candidates=candidates)
+        nbrs, vals = _select_pool(similarity, jnp.asarray(pool),
+                                  jnp.asarray(valid), k)
+    return k_sparse(nbrs, vals, similarity, candidates)
 
 
 def select_neighbors_from_div(divergence: jnp.ndarray, candidates: jnp.ndarray,
@@ -148,24 +174,18 @@ def select_neighbors_from_div(divergence: jnp.ndarray, candidates: jnp.ndarray,
     if isinstance(candidates, jax.core.Tracer):
         from repro.core.similarity import similarity_matrix
         sim = similarity_matrix(divergence)
-        nbrs, w = _select_dense(sim, candidates, k)
-        return CollaborationGraph(neighbors=nbrs, weights=w, similarity=sim,
-                                  candidates=candidates,
-                                  divergence=divergence)
+        return k_sparse(*_select_dense(sim, candidates, k), sim,
+                        candidates, divergence)
     bucket = _pool_bucket(candidates, k)
     if bucket is None:
         from repro.core.similarity import similarity_matrix
-        return CollaborationGraph(
-            neighbors=jnp.zeros((n, k), jnp.int32),
-            weights=jnp.zeros((n, n), jnp.float32),
-            similarity=similarity_matrix(divergence), candidates=candidates,
-            divergence=divergence)
+        return k_sparse(*_empty(n, k), similarity_matrix(divergence),
+                        candidates, divergence)
     pool, valid = bucket
     with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
-        sim, nbrs, w = _select_pool_div(divergence, jnp.asarray(pool),
-                                        jnp.asarray(valid), k)
-    return CollaborationGraph(neighbors=nbrs, weights=w, similarity=sim,
-                              candidates=candidates, divergence=divergence)
+        sim, nbrs, vals = _select_pool_div(divergence, jnp.asarray(pool),
+                                           jnp.asarray(valid), k)
+    return k_sparse(nbrs, vals, sim, candidates, divergence)
 
 
 def fedmd_graph(active: jnp.ndarray) -> CollaborationGraph:
@@ -216,7 +236,7 @@ def ddist_graph(key, n: int, k: int, active: Optional[jnp.ndarray] = None
 
 def graph_stats(g: CollaborationGraph) -> dict:
     """Diagnostics for EXPERIMENTS.md: degree distribution, reciprocity."""
-    adj = g.weights > 0
+    adj = selection_matrix(g) > 0
     in_deg = adj.sum(axis=0)
     recip = jnp.logical_and(adj, adj.T).sum() / jnp.maximum(adj.sum(), 1)
     return {

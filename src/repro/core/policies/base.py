@@ -173,9 +173,15 @@ class ServerPolicy(abc.ABC):
 
         The runtime wire-codes this output with the downlink codec
         before it reaches any client (``ServerBus.fire``) — the rows
-        that actually ship are ``receivers``."""
+        that actually ship are ``receivers``. A K-sparse graph (one that
+        carries ``edge_weights``) gathers its K neighbors per client; a
+        dense one multiplies by its (N,N) ``weights``."""
         probs = jnp.exp(state.repo_logp)
-        return ops.neighbor_mean(graph.weights, probs, backend=backend)
+        if graph.edge_weights is not None:
+            return ops.neighbor_mean(graph.neighbors, graph.edge_weights,
+                                     probs, backend=backend)
+        return ops.neighbor_mean_dense(graph.weights, probs,
+                                       backend=backend)
 
     def receivers(self, state, graph) -> jnp.ndarray:
         """(N,) bool — which clients a K^n downlink payload is sent to
@@ -195,6 +201,5 @@ class ServerPolicy(abc.ABC):
         sim = graph.similarity if self.computes_similarity else state.sim
         div = (graph.divergence if graph.divergence is not None
                else state.div_cache)
-        return state._replace(quality=quality, sim=sim,
-                              weights=graph.weights, div_cache=div,
+        return state._replace(quality=quality, sim=sim, div_cache=div,
                               round=state.round + 1)

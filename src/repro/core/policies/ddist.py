@@ -43,4 +43,5 @@ class DDistPolicy(ServerPolicy):
     def receivers(self, state, graph) -> jnp.ndarray:
         """A client whose static edges all point at never-joined peers
         gets an all-zero row — the server skips its downlink payload."""
-        return state.active & (graph.weights.sum(axis=1) > 0)
+        w = graph_mod.selection_matrix(graph)
+        return state.active & (w.sum(axis=1) > 0)

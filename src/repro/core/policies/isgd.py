@@ -20,10 +20,9 @@ class ISGDPolicy(ServerPolicy):
     def build_graph(self, state, quality: jnp.ndarray, *,
                     backend: Optional[str] = None):
         n = state.active.shape[0]
-        return graph_mod.CollaborationGraph(
-            neighbors=jnp.zeros((n, 0), jnp.int32),
-            weights=jnp.zeros_like(state.weights),
-            similarity=state.sim, candidates=state.active)
+        return graph_mod.k_sparse(jnp.zeros((n, 0), jnp.int32),
+                                  jnp.zeros((n, 0), jnp.float32),
+                                  state.sim, state.active)
 
     def receivers(self, state, graph) -> jnp.ndarray:
         """No collaboration, no downlink: zero wire bytes charged."""

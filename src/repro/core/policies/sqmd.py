@@ -64,9 +64,10 @@ class SQMDPolicy(ServerPolicy):
     def _build_graph_ivf(self, state, quality: jnp.ndarray, uploaded, *,
                          backend: Optional[str] = None):
         """Sub-quadratic round: keep per-client top-L neighbor lists in
-        the IVF index and emit a graph whose similarity matrix is sparse
-        (nonzero only at realized edges). ``graph.divergence`` stays None
-        so the dense div_cache is never touched (nor trusted)."""
+        the IVF index and emit a K-sparse graph whose similarity matrix
+        is sparse (nonzero only at realized edges). ``graph.divergence``
+        stays None so the dense div_cache is never touched (nor
+        trusted)."""
         idx = self._index_for(state, backend)
         uploaded = np.asarray(uploaded)
         if uploaded.dtype != bool:
@@ -89,10 +90,7 @@ class SQMDPolicy(ServerPolicy):
         count = valid.sum(axis=1)
         safe = np.where(valid, nbrs, 0)
         rows_ix = np.repeat(np.arange(n), k)
-        w = np.zeros((n, n), np.float32)
         vals = np.where(valid, 1.0 / np.maximum(count, 1)[:, None], 0.0)
-        np.add.at(w, (rows_ix, safe.reshape(-1)),
-                  vals.reshape(-1).astype(np.float32))
         sim = np.zeros((n, n), np.float32)
         sim_vals = np.where(valid,
                             1.0 / np.maximum(ndiv, sim_mod.EPS), 0.0)
@@ -100,7 +98,6 @@ class SQMDPolicy(ServerPolicy):
         # clobber a realized (i, 0) edge — they contribute exactly 0
         np.add.at(sim, (rows_ix, safe.reshape(-1)),
                   sim_vals.reshape(-1).astype(np.float32))
-        return graph_mod.CollaborationGraph(
-            neighbors=jnp.asarray(safe.astype(np.int32)),
-            weights=jnp.asarray(w), similarity=jnp.asarray(sim),
-            candidates=jnp.asarray(cand))
+        return graph_mod.k_sparse(jnp.asarray(safe.astype(np.int32)),
+                                  jnp.asarray(vals.astype(np.float32)),
+                                  jnp.asarray(sim), jnp.asarray(cand))

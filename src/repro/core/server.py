@@ -5,7 +5,6 @@ State (a pytree — jit-able end to end):
   active    (N,)     participation mask (clients that have ever joined)
   quality   (N,)     latest Eq.1 grades
   sim       (N,N)    latest similarity matrix C (Def. 5)
-  weights   (N,N)    current collaboration-graph selection matrix W
   round     ()       round counter
   div_cache (N,N)    cached Eq.2 divergence matrix of the CURRENT
                      repository — the delta path scatters u×N / N×u strips
@@ -13,7 +12,10 @@ State (a pytree — jit-able end to end):
 
 ``server_round`` consumes freshly uploaded messengers, updates the
 repository, re-grades, rebuilds the dynamic graph per the protocol, and
-returns the per-client distillation targets (the K^n payloads).
+returns the per-client distillation targets (the K^n payloads). The
+round's collaboration graph is not state: it is rebuilt every round and
+kept by its caller (``ServerBus.last_graph``); SQMD's is K-sparse, so no
+(N,N) selection matrix exists on the server.
 """
 from __future__ import annotations
 
@@ -33,7 +35,6 @@ class ServerState(NamedTuple):
     active: jnp.ndarray
     quality: jnp.ndarray
     sim: jnp.ndarray
-    weights: jnp.ndarray
     round: jnp.ndarray
     div_cache: jnp.ndarray
 
@@ -48,7 +49,6 @@ def init_server(n_clients: int, ref_size: int, n_classes: int) -> ServerState:
         active=jnp.zeros((n_clients,), bool),
         quality=jnp.full((n_clients,), quality_mod.BIG),
         sim=jnp.zeros((n_clients, n_clients), jnp.float32),
-        weights=jnp.zeros((n_clients, n_clients), jnp.float32),
         round=jnp.zeros((), jnp.int32),
         # the all-uniform repository has KL(p||p) = 0 everywhere, so the
         # zero matrix IS the exact divergence of the initial repository
@@ -146,7 +146,8 @@ def policy_round(state: ServerState, policy, ref_labels: jnp.ndarray,
         with span("repro.build_graph", path=path):
             graph = policy.build_graph_delta(state, g, uploaded,
                                              backend=backend)
-    with span("repro.emit_targets"):
+    path = "dense" if graph.edge_weights is None else "k-sparse"
+    with span("repro.emit_targets", path=path):
         targets = policy.emit_targets(state, graph, backend=backend)
     return policy.update_state(state, g, graph), targets, graph
 

@@ -158,11 +158,28 @@ def soft_ce(logits: jnp.ndarray, labels: jnp.ndarray,
                    interpret=(backend == "interpret"), **blocks)
 
 
-def neighbor_mean(w: jnp.ndarray, probs: jnp.ndarray,
-                  backend: Optional[str] = None, **blocks) -> jnp.ndarray:
-    """Eq.5 targets. w (N,N), probs (N,R,C) -> (N,R,C) fp32."""
+_sparse_ref_jit = jax.jit(_ref.neighbor_mean_sparse_ref)
+
+
+def neighbor_mean(neighbors: jnp.ndarray, edge_weights: jnp.ndarray,
+                  probs: jnp.ndarray, backend: Optional[str] = None,
+                  **blocks) -> jnp.ndarray:
+    """K-sparse Eq.5 targets. neighbors (N,K) ids, edge_weights (N,K),
+    probs (N,R,C) -> (N,R,C) fp32."""
+    backend = backend or default_backend()
+    if backend == "jnp":
+        return _sparse_ref_jit(neighbors, edge_weights, probs)
+    return _pallas(_nm.neighbor_mean, neighbors, edge_weights, probs,
+                   interpret=(backend == "interpret"), **blocks)
+
+
+def neighbor_mean_dense(w: jnp.ndarray, probs: jnp.ndarray,
+                        backend: Optional[str] = None,
+                        **blocks) -> jnp.ndarray:
+    """Eq.5 targets of a dense graph. w (N,N), probs (N,R,C) -> (N,R,C)
+    fp32."""
     backend = backend or default_backend()
     if backend == "jnp":
         return _ref.neighbor_mean_ref(w, probs)
-    return _pallas(_nm.neighbor_mean, w, probs,
+    return _pallas(_nm.neighbor_mean_dense, w, probs,
                    interpret=(backend == "interpret"), **blocks)

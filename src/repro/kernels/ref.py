@@ -106,3 +106,17 @@ def neighbor_mean_ref(w: jnp.ndarray, probs: jnp.ndarray) -> jnp.ndarray:
     pf = probs.astype(jnp.float32).reshape(n, r * c)
     t = w.astype(jnp.float32) @ pf
     return t.reshape(n, r, c)
+
+
+def neighbor_mean_sparse_ref(neighbors: jnp.ndarray, edge_weights: jnp.ndarray,
+                             probs: jnp.ndarray) -> jnp.ndarray:
+    """K-sparse Eq. 5: T[n] = sum_k edge_weights[n,k] * probs[neighbors[n,k]].
+
+    neighbors (N,K) int ids (clamped into [0, N)), edge_weights (N,K);
+    probs (N,R,C) -> targets (N,R,C) fp32, an elementwise fp32 sum.
+    """
+    n, r, c = probs.shape
+    pf = probs.astype(jnp.float32).reshape(n, r * c)
+    rows = pf[jnp.clip(neighbors, 0, n - 1)]                # (N,K,RC)
+    t = jnp.sum(edge_weights.astype(jnp.float32)[..., None] * rows, axis=1)
+    return t.reshape(n, r, c)

@@ -4,9 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (candidate_mask, ddist_graph, fedmd_graph, init_server,
-                        quality_scores, select_neighbors, server_round,
-                        similarity_matrix, divergence_matrix,
+from repro.core import (ServerState, as_policy, candidate_mask, ddist_graph,
+                        fedmd_graph, init_server, policy_round,
+                        quality_scores, select_neighbors, selection_matrix,
+                        server_round, similarity_matrix, divergence_matrix,
                         upload_messengers)
 from repro.core.protocols import ddist, fedmd, isgd, sqmd
 
@@ -60,11 +61,22 @@ def test_server_round_all_inactive_no_nan_downstream():
     n, r, c = 5, 10, 3
     labels = jax.random.randint(jax.random.key(0), (r,), 0, c)
     st = init_server(n, r, c)          # nobody has joined: active all-False
-    st2, targets = server_round(st, sqmd(q=3, k=2), labels, backend="jnp")
+    st2, targets, g = policy_round(st, as_policy(sqmd(q=3, k=2)), labels,
+                                   backend="jnp")
     assert np.isfinite(np.asarray(targets)).all()
     np.testing.assert_allclose(np.asarray(targets), 0.0)
-    np.testing.assert_allclose(np.asarray(st2.weights), 0.0)
+    np.testing.assert_allclose(np.asarray(selection_matrix(g)), 0.0)
     assert np.isfinite(np.asarray(st2.sim)).all()
+
+
+def test_server_state_holds_no_selection_matrix():
+    """The graph is rebuilt every fire and kept by its caller: the state
+    holds the two (N,N) arrays Eq. 2 and Def. 4 need, and no W."""
+    assert "weights" not in ServerState._fields
+    st = init_server(6, 4, 3)
+    square = [f for f in ServerState._fields
+              if getattr(st, f).shape == (6, 6)]
+    assert sorted(square) == ["div_cache", "sim"]
 
 
 def test_quality_ranks_better_model_lower():
@@ -104,7 +116,7 @@ def test_select_neighbors_never_self_and_row_stochastic():
     logp = _logp(9, 20, 3, seed=5)
     sim = similarity_matrix(divergence_matrix(logp, backend="jnp"))
     g = select_neighbors(sim, jnp.ones((9,), bool), k=4)
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     assert np.allclose(np.diag(w), 0.0)
     np.testing.assert_allclose(w.sum(1), 1.0, atol=1e-6)
     assert ((w > 0).sum(1) == 4).all()
@@ -115,7 +127,7 @@ def test_select_neighbors_respects_candidates():
     sim = similarity_matrix(divergence_matrix(logp, backend="jnp"))
     cand = jnp.asarray([True, True, True, False, False, False, False, True])
     g = select_neighbors(sim, cand, k=3)
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     # only candidate columns may carry weight
     assert np.allclose(w[:, ~np.asarray(cand)], 0.0)
     # every client (incl. non-candidates) still gets neighbors
@@ -125,14 +137,14 @@ def test_select_neighbors_respects_candidates():
 def test_fedmd_is_complete_graph_average():
     active = jnp.asarray([True, True, True, False])
     g = fedmd_graph(active)
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     np.testing.assert_allclose(w[:, :3], 1.0 / 3, atol=1e-6)
     np.testing.assert_allclose(w[:, 3], 0.0)
 
 
 def test_ddist_static_graph_properties():
     g = ddist_graph(jax.random.key(7), 10, 4)
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     assert np.allclose(np.diag(w), 0.0)
     np.testing.assert_allclose(w.sum(1), 1.0, atol=1e-6)
 
@@ -167,8 +179,9 @@ def test_async_newcomer_excluded_from_candidates_but_served():
     logp = jnp.concatenate([good, newbie])
     st = init_server(n, r, c)
     st = upload_messengers(st, logp, jnp.ones((n,), bool))
-    st2, targets = server_round(st, sqmd(q=4, k=2), labels, backend="jnp")
-    w = np.asarray(st2.weights)
+    st2, targets, g = policy_round(st, as_policy(sqmd(q=4, k=2)), labels,
+                                   backend="jnp")
+    w = np.asarray(selection_matrix(g))
     assert np.allclose(w[:, -1], 0.0), "newcomer poisoned the graph"
     assert w[-1].sum() > 0.99, "newcomer did not receive neighbors"
 

@@ -16,8 +16,8 @@ import pytest
 
 from repro.core import (FederationConfig, FederationEngine, ServerBus,
                         StagedJoin, divergence_matrix, init_server,
-                        policy_round, sqmd, update_divergence_cache,
-                        upload_messengers)
+                        policy_round, selection_matrix, sqmd,
+                        update_divergence_cache, upload_messengers)
 from repro.core.graph import ddist_graph
 from repro.core.policies import as_policy
 from repro.kernels import ops, ref
@@ -62,15 +62,17 @@ def test_select_neighbors_traceable_under_jit():
     """The pool fast path needs concrete candidates; under an outer jit
     the dense fallback keeps select_neighbors traceable with identical
     results."""
-    from repro.core import select_neighbors, similarity_matrix
+    from repro.core import (select_neighbors, selection_matrix,
+                            similarity_matrix)
     lp = _logp(8, 10, 3, seed=3)
     sim = similarity_matrix(divergence_matrix(lp, backend="jnp"))
     cand = jnp.asarray([True] * 6 + [False] * 2)
     eager = select_neighbors(sim, cand, 3)
-    jitted = jax.jit(lambda s, c: select_neighbors(s, c, 3).weights)(sim,
-                                                                     cand)
+    jitted = jax.jit(lambda s, c: selection_matrix(
+        select_neighbors(s, c, 3)))(sim, cand)
     np.testing.assert_allclose(np.asarray(jitted),
-                               np.asarray(eager.weights), atol=1e-6)
+                               np.asarray(selection_matrix(eager)),
+                               atol=1e-6)
 
 
 def test_interpret_defaults_from_platform():
@@ -139,8 +141,8 @@ def test_policy_round_delta_matches_full_rebuild():
     st_f, tgt_f, g_f = policy_round(st, pol, labels, backend="jnp")
     np.testing.assert_allclose(np.asarray(g_d.divergence),
                                np.asarray(g_f.divergence), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(st_d.weights),
-                               np.asarray(st_f.weights), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(selection_matrix(g_d)),
+                               np.asarray(selection_matrix(g_f)), atol=1e-6)
     np.testing.assert_allclose(np.asarray(tgt_d), np.asarray(tgt_f),
                                atol=1e-5)
     # the delta round persisted its updated cache
@@ -162,6 +164,74 @@ def test_policy_round_mask_is_optional_for_any_policy():
     _, t_full, _ = policy_round(st, pol, labels, backend="jnp")
     np.testing.assert_allclose(np.asarray(t_delta), np.asarray(t_full),
                                atol=1e-7)
+
+
+# --- the K-sparse graph form ----------------------------------------------
+
+def _assert_k_sparse(g, n, k):
+    """SQMD's graph form: no (N,N) W, (N,K) edge weights whose realized
+    rows sum to 1, and a selection matrix equal bit for bit to the dense
+    scatter of those edges."""
+    assert g.weights is None
+    ew = np.asarray(g.edge_weights)
+    nb = np.asarray(g.neighbors)
+    assert ew.shape == nb.shape == (n, k) and ew.dtype == np.float32
+    sums = ew.sum(axis=1)
+    np.testing.assert_allclose(sums[sums > 0], 1.0, atol=1e-6)
+    want = np.zeros((n, n), np.float32)
+    np.add.at(want, (np.repeat(np.arange(n), k), nb.reshape(-1)),
+              ew.reshape(-1))
+    np.testing.assert_array_equal(np.asarray(selection_matrix(g)), want)
+
+
+@pytest.mark.parametrize("build", ("full", "delta", "jit", "empty"))
+def test_sqmd_graphs_are_k_sparse(build):
+    n, r, c, k = 9, 10, 3, 3
+    labels = jax.random.randint(jax.random.key(3), (r,), 0, c)
+    pol = as_policy(sqmd(q=5, k=k))
+    st = init_server(n, r, c)
+    if build != "empty":
+        st = upload_messengers(st, _logp(n, r, c, seed=80),
+                               jnp.ones(n, bool))
+    if build == "jit":
+        from repro.core import select_neighbors, similarity_matrix
+        sim = similarity_matrix(divergence_matrix(st.repo_logp,
+                                                  backend="jnp"))
+        cand = jnp.arange(n) < 5
+        g = jax.jit(lambda s, m: select_neighbors(s, m, k))(sim, cand)
+    else:
+        mask = np.arange(n) < 2 if build == "delta" else None
+        _, _, g = policy_round(st, pol, labels, backend="jnp",
+                               uploaded=mask)
+    _assert_k_sparse(g, n, k)
+    if build == "empty":
+        np.testing.assert_array_equal(np.asarray(g.edge_weights), 0.0)
+
+
+def test_delta_and_full_rebuild_give_equal_k_sparse_targets():
+    n, r, c = 11, 12, 3
+    labels = jax.random.randint(jax.random.key(4), (r,), 0, c)
+    pol = as_policy(sqmd(q=6, k=3))
+    st = upload_messengers(init_server(n, r, c), _logp(n, r, c, seed=81),
+                           jnp.ones(n, bool))
+    st, _, _ = policy_round(st, pol, labels, backend="jnp")
+    mask = np.zeros(n, bool)
+    mask[[1, 7]] = True
+    st = upload_messengers(st, _logp(n, r, c, seed=82), jnp.asarray(mask))
+    _, tgt_d, g_d = policy_round(st, pol, labels, backend="jnp",
+                                 uploaded=mask)
+    _, tgt_f, g_f = policy_round(st, pol, labels, backend="jnp")
+    np.testing.assert_array_equal(np.asarray(g_d.neighbors),
+                                  np.asarray(g_f.neighbors))
+    np.testing.assert_array_equal(np.asarray(g_d.edge_weights),
+                                  np.asarray(g_f.edge_weights))
+    np.testing.assert_allclose(np.asarray(tgt_d), np.asarray(tgt_f),
+                               atol=1e-6)
+    # and both equal Eq. 5 over the dense selection matrix
+    want = ref.neighbor_mean_ref(selection_matrix(g_f),
+                                 jnp.exp(st.repo_logp))
+    np.testing.assert_allclose(np.asarray(tgt_f), np.asarray(want),
+                               atol=1e-6)
 
 
 # --- ServerBus / engine integration ---------------------------------------
@@ -240,6 +310,30 @@ def test_checkpoint_restores_legacy_server_without_div_cache(tmp_path):
         np.asarray(ref.pairwise_kl_ref(fed2.server.repo_logp)), atol=1e-6)
 
 
+def test_checkpoint_restores_legacy_server_with_weights(tmp_path):
+    """Checkpoints written while the selection matrix was server state
+    carry a ``weights`` key: restore drops it and the bus fires on."""
+    from repro.checkpoint.io import restore_pytree, save_pytree
+    from repro.checkpoint import restore_federation, save_federation
+    n, r, c = 5, 8, 3
+    fed = _tiny_fed()
+    fed.server = upload_messengers(fed.server, _logp(n, r, c, seed=71),
+                                   jnp.ones(n, bool))
+    save_federation(str(tmp_path), fed, step=1)
+    path = str(tmp_path / "step_1.msgpack")
+    tree = restore_pytree(path)
+    tree["server"]["weights"] = np.full((n, n), 0.5, np.float32)
+    save_pytree(path, tree)
+    fed2 = _tiny_fed()
+    assert restore_federation(str(tmp_path), fed2) == 1
+    assert "weights" not in fed2.server._fields
+    bus = ServerBus(fed2, as_policy(sqmd(q=n, k=2)), backend="jnp",
+                    delta=True)
+    assert bus.deliver(1.0, _logp(n, r, c, seed=72), np.arange(n) < 2)
+    _assert_k_sparse(bus.last_graph, n, 2)
+    assert np.isfinite(np.asarray(fed2.targets)).all()
+
+
 # --- frozen clients keep optimizer state bit-for-bit ----------------------
 
 def test_frozen_client_matches_never_stepped_bit_for_bit():
@@ -279,7 +373,7 @@ def test_frozen_client_matches_never_stepped_bit_for_bit():
 
 def test_ddist_zero_active_clients_yields_zero_graph_no_nan():
     g = ddist_graph(jax.random.key(0), 6, 4, active=jnp.zeros(6, bool))
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     assert np.isfinite(w).all()
     np.testing.assert_allclose(w, 0.0)
 
@@ -289,7 +383,7 @@ def test_ddist_fewer_candidates_than_k_clamps_per_row():
     candidate — never an inactive neighbor, rows renormalized."""
     active = jnp.asarray([True, True, False, False, False, False])
     g = ddist_graph(jax.random.key(1), 6, 4, active=active)
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     assert np.isfinite(w).all()
     np.testing.assert_allclose(w[:, 2:], 0.0)       # inactive never sampled
     np.testing.assert_allclose(np.diag(w), 0.0)     # never self
@@ -299,7 +393,7 @@ def test_ddist_fewer_candidates_than_k_clamps_per_row():
 
 def test_ddist_full_population_unchanged_properties():
     g = ddist_graph(jax.random.key(7), 10, 4)
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     assert np.allclose(np.diag(w), 0.0)
     np.testing.assert_allclose(w.sum(1), 1.0, atol=1e-6)
     assert ((w > 0).sum(1) == 4).all()

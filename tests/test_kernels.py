@@ -5,10 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.graph import k_sparse, selection_matrix
 from repro.kernels import ops, ref
 from repro.kernels.pairwise_kl import pairwise_kl
 from repro.kernels.soft_ce import soft_ce
-from repro.kernels.neighbor_mean import neighbor_mean
+from repro.kernels.neighbor_mean import neighbor_mean_dense
 
 SHAPES = [(4, 8, 3), (7, 13, 5), (20, 100, 10), (32, 64, 2), (9, 50, 26)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -77,7 +78,7 @@ def test_neighbor_mean_matches_oracle(shape, dtype):
     probs = jnp.exp(_messengers(n, r, c, jnp.float32)).astype(dtype)
     w = jax.random.uniform(jax.random.key(4), (n, n))
     w = w / w.sum(1, keepdims=True)
-    got = neighbor_mean(w, probs, bn=8, bj=8, bk=32, interpret=True)
+    got = neighbor_mean_dense(w, probs, bn=8, bj=8, bk=32, interpret=True)
     want = ref.neighbor_mean_ref(w, probs)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -88,21 +89,68 @@ def test_neighbor_mean_rows_are_distributions():
     n, r, c = 10, 20, 4
     probs = jnp.exp(_messengers(n, r, c, jnp.float32))
     w = jnp.eye(n)  # self-selection -> identity
-    got = np.asarray(neighbor_mean(w, probs, bn=8, bj=8, bk=16,
-                                   interpret=True))
+    got = np.asarray(neighbor_mean_dense(w, probs, bn=8, bj=8, bk=16,
+                                         interpret=True))
     np.testing.assert_allclose(got, np.asarray(probs), atol=1e-5)
     np.testing.assert_allclose(got.sum(-1), 1.0, atol=1e-4)
+
+
+SPARSE_CASES = ("short_rows", "padded_repeat", "k0", "ragged_n")
+
+
+def _sparse_graph(case):
+    """(graph, probs) for one K-sparse Eq. 5 case, ids drawn from a
+    4-member pool as SQMD's are."""
+    n, k = {"short_rows": (12, 4), "padded_repeat": (10, 3),
+            "k0": (9, 0), "ragged_n": (13, 8)}[case]
+    r, c = 24, 5
+    key = jax.random.key(SPARSE_CASES.index(case))
+    kp, kn, kc = jax.random.split(key, 3)
+    probs = jnp.exp(_messengers(n, r, c, jnp.float32, seed=31))
+    nbrs = jax.random.randint(kn, (n, k), 0, n)
+    count = jnp.full((n, 1), k)
+    if case == "short_rows":      # rows realize 0..k edges
+        count = jax.random.randint(kc, (n, 1), 0, k + 1)
+    if case == "padded_repeat":   # unrealized slots repeat the first id
+        count = jnp.full((n, 1), 1)
+        nbrs = jnp.tile(nbrs[:, :1], (1, k))
+    valid = jnp.arange(k)[None, :] < count
+    w = jnp.where(valid, 1.0 / jnp.maximum(count, 1), 0.0)
+    g = k_sparse(nbrs.astype(jnp.int32), w.astype(jnp.float32),
+                 jnp.zeros((n, n)), jnp.ones((n,), bool))
+    return g, probs
+
+
+@pytest.mark.parametrize("case", SPARSE_CASES)
+@pytest.mark.parametrize("backend", ("jnp", "interpret", "pallas"))
+def test_neighbor_mean_sparse_matches_dense_oracle(backend, case):
+    """K-sparse Eq. 5 on every backend equals the dense oracle over the
+    graph's selection matrix; ``pallas`` is the compiled kernel and
+    needs a TPU."""
+    if backend == "pallas" and jax.devices()[0].platform != "tpu":
+        pytest.skip("the compiled kernel needs a TPU")
+    g, probs = _sparse_graph(case)
+    blocks = {} if backend == "jnp" else {"bn": 4}
+    got = ops.neighbor_mean(g.neighbors, g.edge_weights, probs,
+                            backend=backend, **blocks)
+    with jax.default_matmul_precision("highest"):   # fp32 on a TPU too
+        want = ref.neighbor_mean_ref(selection_matrix(g), probs)
+    assert got.shape == probs.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-6)
 
 
 def test_ops_dispatch_backends_agree():
     logp = _messengers(8, 16, 4, jnp.float32)
     labels = jax.random.randint(jax.random.key(5), (16,), 0, 4)
     w = jnp.full((8, 8), 1.0 / 8)
+    nbrs = jax.random.randint(jax.random.key(6), (8, 3), 0, 8)
     from repro.core.wire import Int8
     wire8 = Int8().encode(logp).arrays
     for fn, args in [(ops.pairwise_kl, (logp,)),
                      (ops.soft_ce, (logp, labels)),
-                     (ops.neighbor_mean, (w, jnp.exp(logp))),
+                     (ops.neighbor_mean_dense, (w, jnp.exp(logp))),
+                     (ops.neighbor_mean, (nbrs, w[:, :3], jnp.exp(logp))),
                      (ops.int8_pairwise_kl,
                       (wire8["q"], wire8["scale"], wire8["zp"]))]:
         a = fn(*args, backend="jnp")

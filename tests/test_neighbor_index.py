@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import wire
+from repro.core import selection_matrix, wire
 from repro.core.similarity import NeighborIndex
 from repro.kernels import ops
 
@@ -212,7 +212,7 @@ def test_policy_ivf_graph_shape_and_edges():
     quality = pol.grade(state, jnp.zeros((r,), jnp.int32), backend="jnp")
     uploaded = np.ones(n, bool)
     g = pol.build_graph_delta(state, quality, uploaded, backend="jnp")
-    w = np.asarray(g.weights)
+    w = np.asarray(selection_matrix(g))
     assert w.shape == (n, n)
     sums = w.sum(axis=1)
     np.testing.assert_allclose(sums[sums > 0], 1.0, atol=1e-5)
@@ -254,7 +254,7 @@ def test_engine_ivf_end_to_end_matches_exact_graph_edges():
     # little, so compare against the oracle computed off the wire form
     div = _oracle_divergence(np.asarray(logp), n)
     cand = np.asarray(g_ivf.candidates)
-    w_ivf = np.asarray(g_ivf.weights)
+    w_ivf = np.asarray(selection_matrix(g_ivf))
     for i in range(n):
         got = set(np.nonzero(w_ivf[i])[0])
         want = set(np.argsort(np.where(

@@ -17,8 +17,8 @@ from repro.core import (AlwaysOn, AsyncFederationEngine, BurstyArrivals,
                         StragglerLatency, SyncClock, WallInterval,
                         as_arrivals, as_trigger, get_arrivals, get_trigger,
                         init_server, isgd, precision_recall,
-                        registered_arrivals, registered_triggers, sqmd,
-                        staleness_summary)
+                        registered_arrivals, registered_triggers,
+                        selection_matrix, sqmd, staleness_summary)
 from repro.core.client import Cohort
 from repro.data import make_splits, pad_like
 from repro.models.mlp import hetero_mlp_zoo
@@ -103,8 +103,10 @@ def test_async_shim_matches_sync(setup_small):
         assert h_async.times == h_sync.times
         np.testing.assert_allclose(h_async.mean_acc, h_sync.mean_acc,
                                    rtol=0, atol=1e-9)
-        np.testing.assert_allclose(np.asarray(asyn.server.weights),
-                                   np.asarray(sync.server.weights),
+        np.testing.assert_allclose(np.asarray(selection_matrix(
+                                       asyn.last_graph)),
+                                   np.asarray(selection_matrix(
+                                       sync.last_graph)),
                                    rtol=0, atol=1e-9)
 
 

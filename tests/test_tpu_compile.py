@@ -70,7 +70,12 @@ def _cases(n, r, c, sharding):
             lambda z, y: soft_ce.soft_ce(z, y, interpret=False),
             [spec((n, r, c)), spec((r,), jnp.int32)]),
         "neighbor_mean": (
-            lambda w, p: neighbor_mean.neighbor_mean(w, p, interpret=False),
+            lambda i, w, p: neighbor_mean.neighbor_mean(i, w, p,
+                                                        interpret=False),
+            [spec((n, 8), jnp.int32), spec((n, 8)), spec((n, r, c))]),
+        "neighbor_mean_dense": (
+            lambda w, p: neighbor_mean.neighbor_mean_dense(w, p,
+                                                           interpret=False),
             [spec((n, n)), spec((n, r, c))]),
         "int8_pairwise_kl": (
             lambda q, s, z: dequant_kl.int8_pairwise_kl(q, s, z,
@@ -84,7 +89,7 @@ def _cases(n, r, c, sharding):
 
 
 KERNELS = ("pairwise_kl", "pairwise_kl_pair", "soft_ce", "neighbor_mean",
-           "int8_pairwise_kl", "int8_pairwise_kl_pair")
+           "neighbor_mean_dense", "int8_pairwise_kl", "int8_pairwise_kl_pair")
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
@@ -95,13 +100,29 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, width):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("n,resident", ((16384, True), (65536, False)))
+def test_k_sparse_neighbor_mean_compiles_at_server_size(one_chip, n,
+                                                        resident):
+    """The K-sparse Eq. 5 at the N=16384 server's size keeps the whole
+    (N, R·C) stack in VMEM; a stack past the VMEM ceiling compiles as
+    an XLA gather instead."""
+    r, c, k = 240, 3, 8
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (((n, k), jnp.int32), ((n, k), jnp.float32),
+                         ((n, r, c), jnp.float32))]
+    text = jax.jit(lambda i, w, p: neighbor_mean.neighbor_mean(
+        i, w, p, interpret=False)).lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in text) == resident
+
+
 @pytest.fixture(scope="module")
 def four_chips(topo):
     from repro.sharding import CLIENT_AXIS
     return Mesh(np.asarray(topo.devices), (CLIENT_AXIS,))
 
 
-@pytest.mark.parametrize("kernel", ("soft_ce", "neighbor_mean"))
+@pytest.mark.parametrize("kernel", ("soft_ce", "neighbor_mean",
+                                    "neighbor_mean_dense"))
 def test_replicated_kernel_compiles_on_four_chips(four_chips, kernel):
     """The sharded server grades and emits targets on row-sharded
     operands: ``ops`` runs those kernels replicated over the mesh."""
