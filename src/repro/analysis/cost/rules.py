@@ -104,6 +104,10 @@ _POLICY_BLOWUP = {"ratio": 32.0, "floor_bytes": 4096, "allow": {
     "cohort_step[transformer]": ["dot_general"],
     "cohort_step[rglru]": ["dot_general"],
     "cohort_step[ssm]": ["dot_general", "pad"],
+    # published widths over 1-token probe sequences: each weight's
+    # gradient (n, d, f) dwarfs the inputs, and the SSD pads 1 token to
+    # its chunk of 128
+    "cohort_step[nemotron-h]": ["dot_general", "pad"],
 }}
 _DEFAULT_TOLERANCE = 0.35
 _DEFAULT_HLO_BAND = 3.0

@@ -76,6 +76,23 @@ class Cohort:
             return self.opt_state
         return jax.tree.map(lambda a: a[: self.n_clients], self.opt_state)
 
+    @property
+    def has_experts(self) -> bool:
+        """Whether the family has expert layers (its apply_fn is a
+        ``repro.models.zoo.ExpertFamilyApply``): its cohort step, the
+        donating ``expert_cohort_step``, also returns token-choice
+        counts."""
+        return hasattr(self.apply_fn, "with_stats")
+
+    def step_args(self, rows: int) -> Dict[str, int]:
+        """Args of the ``repro.cohort_step`` span of a step over ``rows``
+        samples a client: for a family with expert layers, the experts
+        each layer holds and the tokens stepped; else none."""
+        if not self.has_experts:
+            return {}
+        return {"experts_held": self.apply_fn.experts_held,
+                "tokens": self.n_clients * rows * self.apply_fn.seq_len}
+
 
 def make_cohort(family_name: str, init_fn, apply_fn, optimizer: Optimizer,
                 client_ids, data, key) -> Cohort:
@@ -95,6 +112,62 @@ def _client_loss(apply_fn, params, x, y, ref_x, targets, rho: float,
     return (1.0 - rho) * loc + rho * ref
 
 
+def _gated_update(optimizer: Optimizer, p, s, grads, on):
+    """(params, opt_state) after one optimizer step, or unchanged where
+    ``on`` is False."""
+    updates, new_s = optimizer.update(grads, s, p)
+    gate = on.astype(jnp.float32)
+    new_p = jax.tree.map(
+        lambda a, u: (a + gate * u.astype(a.dtype)).astype(a.dtype),
+        p, updates)
+    # freeze optimizer state too when inactive: gate EVERY leaf by
+    # broadcasting the scalar mask — a shape-conditional gate would let
+    # mismatched leaves (e.g. scalar step counters) silently advance,
+    # and a woken client would resume with wrong Adam bias correction
+    new_s = jax.tree.map(lambda a, b: jnp.where(on, b, a), s, new_s)
+    return new_p, new_s
+
+
+def _expert_grads(apply_fn, params, x, y, ref_x, targets, rho: float,
+                  use_ref: bool):
+    """(loss, counts, grads) of Eq. 6 (Eq. 3 alone without the reference
+    term) for a family with expert layers: ``counts`` sums its forwards'
+    token-choice counts (``apply_fn.with_stats``). The reference term is
+    differentiated over equal blocks of at most ``apply_fn.ref_block``
+    samples, one after another, and the gradients added up: the same
+    sum, holding one block's activations at a time."""
+
+    def value_and_grad(loss_of):
+        def f(q):
+            counts = []
+
+            def fn(p, xs):
+                logits, c = apply_fn.with_stats(p, xs)
+                counts.append(c)
+                return logits
+            return loss_of(fn, q), sum(counts)
+        (v, c), g = jax.value_and_grad(f, has_aux=True)(params)
+        return v, c, g
+
+    block = apply_fn.ref_block
+    r = ref_x.shape[0]
+    if not (use_ref and block and block < r):
+        return value_and_grad(lambda fn, q: _client_loss(
+            fn, q, x, y, ref_x, targets, rho, use_ref))
+    nb = next(n for n in range(-(-r // block), r + 1) if r % n == 0)
+    acc = value_and_grad(lambda fn, q: (1.0 - rho) * local_loss(fn, q, x, y))
+
+    def one_block(acc, blk):
+        rx, rt = blk
+        part = value_and_grad(lambda fn, q: rho / nb * ref_loss(fn, q, rx, rt))
+        return jax.tree.map(jnp.add, acc, part), None
+
+    acc, _ = jax.lax.scan(one_block, acc,
+                          (ref_x.reshape(nb, r // nb, *ref_x.shape[1:]),
+                           targets.reshape(nb, r // nb, *targets.shape[1:])))
+    return acc
+
+
 def _cohort_step(apply_fn, optimizer: Optimizer, params, opt_state,
                  batch_x, batch_y, ref_x, targets, trainable,
                  rho: float, use_ref: bool):
@@ -103,23 +176,22 @@ def _cohort_step(apply_fn, optimizer: Optimizer, params, opt_state,
 
     batch_x (n_c,B,L), batch_y (n_c,B), targets (n_c,R,C) per-client
     distill targets, trainable (n_c,) bool (inactive clients frozen).
-    Returns (params, opt_state, per-client loss)."""
+    Returns (params, opt_state, per-client loss); for a family with
+    expert layers (``Cohort.has_experts``; gradients by
+    ``_expert_grads``) also each client's token choices per expert layer
+    and held expert (n_c, n_expert_layers, held)."""
+    experts = hasattr(apply_fn, "with_stats")
 
     def one(p, s, x, y, t, on):
+        if experts:
+            loss, counts, grads = _expert_grads(apply_fn, p, x, y, ref_x,
+                                                t, rho, use_ref)
+            return (*_gated_update(optimizer, p, s, grads, on), loss,
+                    counts)
         loss, grads = jax.value_and_grad(
             lambda q: _client_loss(apply_fn, q, x, y, ref_x, t, rho,
                                    use_ref))(p)
-        updates, new_s = optimizer.update(grads, s, p)
-        gate = on.astype(jnp.float32)
-        new_p = jax.tree.map(
-            lambda a, u: (a + gate * u.astype(a.dtype)).astype(a.dtype),
-            p, updates)
-        # freeze optimizer state too when inactive: gate EVERY leaf by
-        # broadcasting the scalar mask — a shape-conditional gate would let
-        # mismatched leaves (e.g. scalar step counters) silently advance,
-        # and a woken client would resume with wrong Adam bias correction
-        new_s = jax.tree.map(lambda a, b: jnp.where(on, b, a), s, new_s)
-        return new_p, new_s, loss
+        return (*_gated_update(optimizer, p, s, grads, on), loss)
 
     return jax.vmap(one)(params, opt_state, batch_x, batch_y, targets,
                          trainable)
@@ -127,6 +199,11 @@ def _cohort_step(apply_fn, optimizer: Optimizer, params, opt_state,
 
 _STEP_STATICS = ("apply_fn", "optimizer", "rho", "use_ref")
 cohort_step = jax.jit(_cohort_step, static_argnames=_STEP_STATICS)
+# the step of a family with expert layers: it updates params and
+# optimizer state in place, since weights and Adam state that fill a
+# chip cannot be held twice
+expert_cohort_step = jax.jit(_cohort_step, static_argnames=_STEP_STATICS,
+                             donate_argnames=("params", "opt_state"))
 
 
 def _cohort_messenger_upload(apply_fn, params, ref_x, codec=None):

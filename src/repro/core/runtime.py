@@ -37,12 +37,12 @@ import numpy as np
 
 from repro.core import wire
 from repro.core.client import (cohort_messenger_upload, cohort_step,
-                               sharded_cohort_step,
+                               expert_cohort_step, sharded_cohort_step,
                                sharded_messenger_upload)
 from repro.core.server import (policy_round, staleness_summary,
                                upload_messengers)
 from repro.data.pipeline import cohort_batch, cohort_batch_padded
-from repro.obs import host_read, span
+from repro.obs import Counters, host_read, span
 
 # --------------------------------------------------------------------------
 # Clock / Event
@@ -263,13 +263,18 @@ class ClientRuntime:
     through the mesh-pinned jits, and ghost rows stay permanently outside
     the trainable mask (bit-exact no-ops — the PR 3 frozen-client
     guarantee). Batch indices are drawn at the REAL cohort size, so the
-    sharded run consumes the identical RNG stream as ``mesh=None``."""
+    sharded run consumes the identical RNG stream as ``mesh=None``.
+
+    ``counters`` sums on the device what the steps count: for a family
+    with expert layers, ``expert_load.<family>`` (n_expert_layers, held)
+    token choices per held expert, over its real clients."""
 
     def __init__(self, federation, policy, config, mesh=None):
         self.fed = federation
         self.policy = policy
         self.config = config
         self.mesh = mesh
+        self.counters = Counters()
         self.ever_woken = np.zeros(federation.n_clients, bool)
         if mesh is not None:
             from repro.sharding import cohort_mesh, place_cohort_stacks
@@ -297,10 +302,11 @@ class ClientRuntime:
                 fed.targets = jnp.full((n, r, c), 1.0 / c, jnp.float32)
             self.ever_woken |= mask_np
             avail = jnp.asarray(mask_np)
+            rows = cfg.batch_size + (len(fed.ref_x) if use_ref else 0)
             for _ in range(cfg.local_steps):
                 for coh in fed.cohorts:
                     with span("repro.cohort_step", family=coh.family_name,
-                              clients=coh.n_clients):
+                              clients=coh.n_clients, **coh.step_args(rows)):
                         self._step_cohort(coh, avail, use_ref)
 
     def _step_cohort(self, coh, avail: jnp.ndarray, use_ref: bool) -> None:
@@ -336,10 +342,15 @@ class ClientRuntime:
                 # mesh-wide); re-place them on the bucket's submesh so
                 # the pinned jit sees one device set
                 tgt = jax.device_put(tgt, coh.sharding)
-        coh.params, coh.opt_state, _ = step(
-            coh.apply_fn, opt, coh.params, coh.opt_state,
-            batch["x"], batch["y"], fed.ref_x, tgt,
-            on, self.policy.rho, use_ref)
+        args = (coh.apply_fn, opt, coh.params, coh.opt_state,
+                batch["x"], batch["y"], fed.ref_x, tgt,
+                on, self.policy.rho, use_ref)
+        if coh.has_experts and coh.sharding is None:
+            coh.params, coh.opt_state, _, counts = expert_cohort_step(*args)
+            self.counters.add(f"expert_load.{coh.family_name}",
+                              jnp.sum(counts[:coh.n_clients], axis=0))
+        else:
+            coh.params, coh.opt_state, _ = step(*args)
 
     def collect_messengers(self,
                            mask_np: Optional[np.ndarray] = None

@@ -176,8 +176,9 @@ def _qkv(p: Params, cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray):
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -209,8 +210,9 @@ def attn_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray, cache: Params,
     v1 = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if cfg.qkv_bias:
         q, k1, v1 = q + p["bq"], k1 + p["bk"], v1 + p["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k1 = apply_rope(k1, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k1 = apply_rope(k1, positions, cfg.rope_theta)
 
     s_cache = cache["k"].shape[1]
     slot = jnp.where(jnp.int32(window) > 0, pos % s_cache,

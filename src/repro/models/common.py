@@ -27,7 +27,9 @@ class ModelConfig:
 
     ``layer_pattern`` is the repeating unit of per-layer mixer types, e.g.
     ``("local","local","local","local","local","global")`` for gemma3's 5:1.
-    Valid mixer types: "global", "local", "mla", "ssd", "rec".
+    Valid layer kinds: "global", "local", "mla", "ssd", "rec", and "moe"
+    (Nemotron-H's expert layer, a single-op block of its own: a pattern
+    that holds it gives no other layer an FFN).
     """
 
     name: str
@@ -43,11 +45,16 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
     sliding_window: int = 0          # window for "local" layers (0 = unused)
+    use_rope: bool = True            # False: no positional encoding (Nemotron-H)
     layer_pattern: Tuple[str, ...] = ("global",)
     # MoE
     n_experts: int = 0
     n_shared_experts: int = 0
     moe_top_k: int = 0
+    # "moe" layers (sigmoid router, relu^2 experts; DeepSeek-V3 routing)
+    shared_d_ff: int = 0             # shared expert width
+    routed_scale: float = 1.0        # routed_scaling_factor
+    experts_held: int = 0            # experts this chip holds (0 = all)
     # MLA (DeepSeek-V2)
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
@@ -57,6 +64,8 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_expand: int = 2
+    ssm_head_dim: int = 0            # >0: d_inner = ssm_heads * ssm_head_dim
+    ssm_groups: int = 1              # B/C groups; also the gated norm's groups
     conv_width: int = 4
     ssm_chunk: int = 256
     # RG-LRU (RecurrentGemma)
@@ -90,8 +99,17 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:
-        """SSD inner width."""
+        """SSD inner width: heads x head width where the head width is
+        given (Mamba-2's ``mamba_num_heads * mamba_head_dim``), else
+        ``ssm_expand * d_model``."""
+        if self.ssm_head_dim:
+            return self.ssm_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.experts_held or self.n_experts
 
     def param_count(self, params: Params) -> int:
         return sum(int(x.size) for x in jax.tree.leaves(params))
@@ -117,12 +135,19 @@ class ModelConfig:
                 per_layer += self.n_heads * vh * d              # o
             elif kind == "ssd":
                 di = self.d_inner
-                per_layer += d * (2 * di + 2 * self.ssm_state
-                                  + self.ssm_heads)
+                per_layer += d * (2 * di + 2 * self.ssm_groups
+                                  * self.ssm_state + self.ssm_heads)
                 per_layer += di * d
             elif kind == "rec":
                 w = self.lru_width or d
                 per_layer += 2 * d * w + w * d + 2 * w
+            elif kind == "moe":
+                per_layer += d * self.n_experts                 # router
+                per_layer += self.moe_top_k * 2 * d * f         # up, down
+                per_layer += 2 * d * self.shared_d_ff
+                continue
+            if "moe" in self.layer_pattern:
+                continue                # single-op blocks: no ffn
             # ffn (except pure ssd layers which have none in mamba2)
             if kind != "ssd" or self.d_ff > 0:
                 if self.is_moe:

@@ -9,6 +9,14 @@ MoE has three execution paths:
     ``jax.lax.ragged_dot`` (MegaBlocks-style). Exact active-FLOPs; used on
     CPU smoke/federation paths and as the correctness oracle.
   * ``moe_decode`` — per-token expert-weight gather for single-token decode.
+
+``held_moe_forward`` is Nemotron-H's expert layer (a layer kind of its own,
+``"moe"``): DeepSeek-V3's sigmoid router with a score-correction bias and
+normalised, scaled top-k weights; relu² experts (up, then down, no gate)
+and a relu² shared expert. The layer holds ``cfg.n_held`` of the
+``cfg.n_experts`` routed experts, from expert ``first`` on (0 in the
+program) — one chip's share under expert parallelism — routes every
+token over all of them, and adds only its held experts' part, dropless.
 """
 from __future__ import annotations
 
@@ -18,6 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ModelConfig, Params, dense_init, swiglu
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 MOE_CAPACITY_FACTOR = 1.25
 
@@ -192,3 +202,90 @@ def moe_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     if path == "dropless":
         return moe_dropless_forward(p, cfg, x)
     raise ValueError(f"unknown moe path {path!r}")
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-H expert layer: DeepSeek-V3 routing, relu² experts, held share
+# ---------------------------------------------------------------------------
+
+def relu2(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.square(jax.nn.relu(x))
+
+
+def init_held_moe(key, cfg: ModelConfig) -> Params:
+    d, f, fs = cfg.d_model, cfg.d_ff, cfg.shared_d_ff
+    held = cfg.n_held
+    dt = cfg.param_dtype
+    ks = jax.random.split(key, 5)
+    return {
+        "router": dense_init(ks[0], (d, cfg.n_experts), jnp.float32),
+        # e_score_correction_bias: it only shifts the choice, so it takes
+        # no gradient and the optimizer leaves it at its value
+        "router_bias": jnp.zeros((cfg.n_experts,), jnp.float32),
+        "w_up": dense_init(ks[1], (held, d, f), dt, fan_in=d),
+        "w_down": dense_init(ks[2], (held, f, d), dt, fan_in=f),
+        "shared_up": dense_init(ks[3], (d, fs), dt),
+        "shared_down": dense_init(ks[4], (fs, d), dt, fan_in=fs),
+    }
+
+
+def sigmoid_route(p: Params, cfg: ModelConfig, xf: jnp.ndarray):
+    """DeepSeek-V3 routing of tokens xf (T, D): scores s = sigmoid(x W_r)
+    (float32, HIGHEST precision, so the choice matches a float32
+    reference except on true ties); the top k of s + bias; weights
+    s_i / sum_chosen s * routed_scale. Returns (ids (T,k), weights (T,k))."""
+    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), p["router"],
+                        precision=HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(p["router_bias"]), cfg.moe_top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    return ids, w
+
+
+# tokens a block of the expert layer: a block's held-expert activations
+# are block * n_held * d_ff floats, so bounding the block bounds the
+# layer's buffers (each block is rematerialised in the backward pass)
+MOE_BLOCK = 4096
+
+
+def _held_block(p: Params, cfg: ModelConfig, xf: jnp.ndarray,
+                real: jnp.ndarray, first: int):
+    """One block of tokens xf (T, D), ``real`` (T,) marking the tokens
+    that are not padding: (y (T, D), counts (n_held,))."""
+    held = cfg.n_held
+    ids, w = sigmoid_route(p, cfg, xf)
+    mine = ((ids - first)[..., None] == jnp.arange(held)) \
+        & real[:, None, None]                                # (T, k, held)
+    gate = jnp.sum(jnp.where(mine, w[..., None], 0.0), axis=1)   # (T, held)
+    counts = jnp.sum(mine, axis=(0, 1), dtype=jnp.int32)
+    h = relu2(jnp.einsum("td,edf->tef", xf, p["w_up"]))
+    routed = jnp.einsum("tef,efd->td", h * gate[..., None].astype(h.dtype),
+                        p["w_down"])
+    shared = relu2(xf @ p["shared_up"]) @ p["shared_down"]
+    return routed + shared, counts
+
+
+def held_moe_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
+                     block: int = MOE_BLOCK, first: int = 0):
+    """x (B,S,D) -> (y (B,S,D), counts (n_held,) int32): the shared expert
+    plus sum over chosen ∩ held experts i of w_i relu²(x U_i) D_i.
+
+    Every held expert runs on every token of a block, weighed by its gate
+    (nought where the token did not choose it): dropless, with the same
+    work whatever the routing, as dense matmuls on the MXU. Tokens go in
+    blocks of at most ``block``. ``counts`` is each held expert's number
+    of token choices. The layer holds experts ``first`` to ``first +
+    n_held - 1``."""
+    b, s, d = x.shape
+    t = b * s
+    nb = -(-t // block)
+    tb = -(-t // nb)
+    xf = jnp.pad(x.reshape(t, d), ((0, nb * tb - t), (0, 0)))
+    real = jnp.arange(nb * tb) < t
+    y, counts = jax.lax.map(
+        jax.checkpoint(lambda a: _held_block(p, cfg, *a, first)),
+        (xf.reshape(nb, tb, d), real.reshape(nb, tb)))
+    y = y.reshape(nb * tb, d)[:t].reshape(b, s, d)
+    return y.astype(x.dtype), jnp.sum(counts, axis=0)

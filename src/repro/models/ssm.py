@@ -3,7 +3,11 @@
 TPU adaptation: the SSD chunked form is used for train/prefill — quadratic
 attention-like compute *within* VMEM-sized chunks (MXU-friendly matmuls) and a
 tiny recurrent state handoff *across* chunks (``lax.scan``). Decode is the
-constant-memory recurrence. Single B/C group (G=1), scalar-per-head A.
+constant-memory recurrence. Scalar-per-head A; ``cfg.ssm_groups`` B/C
+groups, each shared by ``ssm_heads / ssm_groups`` consecutive heads, and
+the gated RMSNorm taken over the same groups of ``d_inner / ssm_groups``
+channels (mamba_ssm's ``RMSNormGated(group_size=d_inner/ngroups,
+norm_before_gate=False)``).
 """
 from __future__ import annotations
 
@@ -26,12 +30,13 @@ def _dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
 def init_ssd(key, cfg: ModelConfig) -> Params:
     d = cfg.d_model
     di, h, p, n = _dims(cfg)
+    gn = cfg.ssm_groups * n
     dt = cfg.param_dtype
-    conv_ch = di + 2 * n                       # conv over [x, B, C]
+    conv_ch = di + 2 * gn                      # conv over [x, B, C]
     ks = jax.random.split(key, 4)
     return {
-        # in_proj -> [z (di), x (di), B (n), C (n), dt (h)]
-        "w_in": dense_init(ks[0], (d, 2 * di + 2 * n + h), dt),
+        # in_proj -> [z (di), x (di), B (G*n), C (G*n), dt (h)]
+        "w_in": dense_init(ks[0], (d, 2 * di + 2 * gn + h), dt),
         "conv_w": dense_init(ks[1], (cfg.conv_width, conv_ch), dt,
                              fan_in=cfg.conv_width),
         "conv_b": jnp.zeros((conv_ch,), dt),
@@ -45,20 +50,31 @@ def init_ssd(key, cfg: ModelConfig) -> Params:
 
 def _split_in(p: Params, cfg: ModelConfig, x: jnp.ndarray):
     di, h, _, n = _dims(cfg)
+    gn = cfg.ssm_groups * n
     proj = jnp.einsum("bsd,de->bse", x, p["w_in"])
     z = proj[..., :di]
-    xin = proj[..., di:2 * di]
-    b_ = proj[..., 2 * di:2 * di + n]
-    c_ = proj[..., 2 * di + n:2 * di + 2 * n]
-    dt_raw = proj[..., 2 * di + 2 * n:]
-    return z, xin, b_, c_, dt_raw
+    xbc = proj[..., di:2 * di + 2 * gn]
+    dt_raw = proj[..., 2 * di + 2 * gn:]
+    return z, xbc, dt_raw
 
 
-def _gated_norm(p: Params, y: jnp.ndarray, z: jnp.ndarray,
-                eps: float) -> jnp.ndarray:
+def _split_xbc(cfg: ModelConfig, xbc: jnp.ndarray):
+    """[x (di), B (G*n), C (G*n)] -> x, B (...,G,n), C (...,G,n)."""
+    di, _, _, n = _dims(cfg)
+    g = cfg.ssm_groups
+    lead = xbc.shape[:-1]
+    return (xbc[..., :di],
+            xbc[..., di:di + g * n].reshape(*lead, g, n),
+            xbc[..., di + g * n:].reshape(*lead, g, n))
+
+
+def _gated_norm(p: Params, y: jnp.ndarray, z: jnp.ndarray, eps: float,
+                groups: int = 1) -> jnp.ndarray:
+    """RMSNorm of y * silu(z) over ``groups`` equal channel groups."""
     yf = (y * jax.nn.silu(z.astype(jnp.float32))).astype(jnp.float32)
-    var = jnp.mean(yf * yf, axis=-1, keepdims=True)
-    return (yf * jax.lax.rsqrt(var + eps)
+    yg = yf.reshape(*yf.shape[:-1], groups, yf.shape[-1] // groups)
+    var = jnp.mean(yg * yg, axis=-1, keepdims=True)
+    return ((yg * jax.lax.rsqrt(var + eps)).reshape(yf.shape)
             * p["norm_scale"].astype(jnp.float32))
 
 
@@ -88,81 +104,82 @@ def ssd_scan(xh: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     """Chunked SSD.
 
     xh (B,S,H,P) head inputs; dt (B,S,H) positive step sizes; a (H,) negative;
-    b_/c_ (B,S,N) single-group SSM in/out projections.
+    b_/c_ (B,S,G,N) SSM in/out projections of G groups, head h reading
+    group h // (H/G).
     Returns (y (B,S,H,P) fp32, final_state (B,H,P,N) fp32).
     """
     bsz, s, h, p = xh.shape
-    n = b_.shape[-1]
+    g, n = b_.shape[-2:]
+    k = h // g                                              # heads per group
     nc = -(-s // chunk)
     pad = nc * chunk - s
     if pad:
         xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        b_ = jnp.pad(b_, ((0, 0), (0, pad), (0, 0)))
-        c_ = jnp.pad(c_, ((0, 0), (0, pad), (0, 0)))
+        b_ = jnp.pad(b_, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        c_ = jnp.pad(c_, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
     q = chunk
-    xc = xh.reshape(bsz, nc, q, h, p).astype(jnp.float32)
-    dtc = dt.reshape(bsz, nc, q, h).astype(jnp.float32)
-    bc = b_.reshape(bsz, nc, q, n).astype(jnp.float32)
-    cc = c_.reshape(bsz, nc, q, n).astype(jnp.float32)
+    xc = xh.reshape(bsz, nc, q, g, k, p).astype(jnp.float32)
+    dtc = dt.reshape(bsz, nc, q, g, k).astype(jnp.float32)
+    bc = b_.reshape(bsz, nc, q, g, n).astype(jnp.float32)
+    cc = c_.reshape(bsz, nc, q, g, n).astype(jnp.float32)
 
-    da = dtc * a                                            # (B,C,Q,H) <= 0
+    da = dtc * a.reshape(g, k)                              # (B,C,Q,G,K) <= 0
     da_cs = jnp.cumsum(da, axis=2)                          # within-chunk
     x_dt = xc * dtc[..., None]                              # dt-discretized input
 
-    # 1) within-chunk (quadratic, MXU): L[b,c,h,i,j] decay, i >= j
-    l_mat = jnp.exp(_segsum(da.transpose(0, 1, 3, 2)))      # (B,C,H,Q,Q)
-    cb = jnp.einsum("bcin,bcjn->bcij", cc, bc)              # (B,C,Q,Q)
-    y_diag = jnp.einsum("bcij,bchij,bcjhp->bcihp", cb, l_mat, x_dt)
+    # 1) within-chunk (quadratic, MXU): L[b,c,g,k,i,j] decay, i >= j
+    l_mat = jnp.exp(_segsum(da.transpose(0, 1, 3, 4, 2)))   # (B,C,G,K,Q,Q)
+    cb = jnp.einsum("bcign,bcjgn->bcgij", cc, bc)           # (B,C,G,Q,Q)
+    y_diag = jnp.einsum("bcgij,bcgkij,bcjgkp->bcigkp", cb, l_mat, x_dt)
 
     # 2) per-chunk end states
-    decay_to_end = jnp.exp(da_cs[:, :, -1:, :] - da_cs)     # (B,C,Q,H)
-    states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn", bc, decay_to_end, x_dt)
+    decay_to_end = jnp.exp(da_cs[:, :, -1:] - da_cs)        # (B,C,Q,G,K)
+    states = jnp.einsum("bcjgn,bcjgk,bcjgkp->bcgkpn", bc, decay_to_end, x_dt)
 
     # 3) cross-chunk recurrence (tiny scan over chunk index)
-    chunk_decay = jnp.exp(jnp.sum(da, axis=2))              # (B,C,H)
+    chunk_decay = jnp.exp(jnp.sum(da, axis=2))              # (B,C,G,K)
 
     def step(carry, inp):
-        st, dec = inp                                       # (B,H,P,N),(B,H)
+        st, dec = inp                                       # (B,G,K,P,N),(B,G,K)
         prev = carry
         new = prev * dec[..., None, None] + st
         return new, prev
 
-    init = (jnp.zeros((bsz, h, p, n), jnp.float32)
-            if init_state is None else init_state.astype(jnp.float32))
+    init = (jnp.zeros((bsz, g, k, p, n), jnp.float32) if init_state is None
+            else init_state.astype(jnp.float32).reshape(bsz, g, k, p, n))
     final_state, prev_states = jax.lax.scan(
-        step, init,
-        (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
-    prev_states = prev_states.transpose(1, 0, 2, 3, 4)      # (B,C,H,P,N)
+        step, init, (jnp.moveaxis(states, 1, 0),
+                     jnp.moveaxis(chunk_decay, 1, 0)))
+    prev_states = jnp.moveaxis(prev_states, 0, 1)           # (B,C,G,K,P,N)
 
     # 4) contribution of previous chunks' state
-    in_decay = jnp.exp(da_cs)                               # (B,C,Q,H)
-    y_off = jnp.einsum("bcin,bchpn,bcih->bcihp", cc, prev_states, in_decay)
+    in_decay = jnp.exp(da_cs)                               # (B,C,Q,G,K)
+    y_off = jnp.einsum("bcign,bcgkpn,bcigk->bcigkp", cc, prev_states,
+                       in_decay)
 
     y = (y_diag + y_off).reshape(bsz, nc * q, h, p)[:, :s]
-    return y, final_state
+    return y, final_state.reshape(bsz, h, p, n)
 
 
 def ssd_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                 return_state: bool = False):
     """Full-sequence Mamba-2 mixer. x (B,S,D) -> (B,S,D)."""
     di, h, ph, n = _dims(cfg)
-    z, xin, b_, c_, dt_raw = _split_in(p, cfg, x)
-    conv_in = jnp.concatenate([xin, b_, c_], axis=-1)
-    conv_out = _causal_conv(p, conv_in)
-    xin, b_, c_ = (conv_out[..., :di], conv_out[..., di:di + n],
-                   conv_out[..., di + n:])
+    z, xbc, dt_raw = _split_in(p, cfg, x)
+    conv_out = _causal_conv(p, xbc)
+    xin, b_, c_ = _split_xbc(cfg, conv_out)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["a_log"])
     xh = xin.reshape(*xin.shape[:2], h, ph)
     y, state = ssd_scan(xh, dt, a, b_, c_, cfg.ssm_chunk)
     y = y + p["d_skip"][:, None] * xh.astype(jnp.float32)
     y = y.reshape(*x.shape[:2], di)
-    y = _gated_norm(p, y, z, cfg.norm_eps)
+    y = _gated_norm(p, y, z, cfg.norm_eps, cfg.ssm_groups)
     out = jnp.einsum("bse,ed->bsd", y.astype(x.dtype), p["w_out"])
     if return_state:
-        conv_tail = conv_in[:, -(cfg.conv_width - 1):, :]
+        conv_tail = xbc[:, -(cfg.conv_width - 1):, :]
         return out, {"state": state, "conv": conv_tail}
     return out
 
@@ -170,24 +187,24 @@ def ssd_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
 def ssd_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray, cache: Params):
     """One-token recurrent step. cache: {'state': (B,H,P,N), 'conv': (B,W-1,C)}."""
     di, h, ph, n = _dims(cfg)
-    z, xin, b_, c_, dt_raw = _split_in(p, cfg, x)           # all (B,1,·)
-    conv_in = jnp.concatenate([xin, b_, c_], axis=-1)       # (B,1,C)
-    conv_out = _causal_conv(p, conv_in, prior=cache["conv"])
-    new_conv = jnp.concatenate([cache["conv"], conv_in], axis=1)[:, 1:, :]
-    xin, b_, c_ = (conv_out[..., :di], conv_out[..., di:di + n],
-                   conv_out[..., di + n:])
+    g = cfg.ssm_groups
+    z, xbc, dt_raw = _split_in(p, cfg, x)                   # all (B,1,·)
+    conv_out = _causal_conv(p, xbc, prior=cache["conv"])
+    new_conv = jnp.concatenate([cache["conv"], xbc], axis=1)[:, 1:, :]
+    xin, b_, c_ = _split_xbc(cfg, conv_out)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])[:, 0]  # (B,H)
     a = -jnp.exp(p["a_log"])
     xh = xin[:, 0].reshape(-1, h, ph).astype(jnp.float32)   # (B,H,P)
-    bv = b_[:, 0].astype(jnp.float32)                       # (B,N)
-    cv = c_[:, 0].astype(jnp.float32)
+    # each head reads its group's B and C
+    bv = jnp.repeat(b_[:, 0].astype(jnp.float32), h // g, axis=1)  # (B,H,N)
+    cv = jnp.repeat(c_[:, 0].astype(jnp.float32), h // g, axis=1)
     decay = jnp.exp(dt * a)                                 # (B,H)
     dx = xh * dt[..., None]                                 # (B,H,P)
     state = (cache["state"] * decay[..., None, None]
-             + jnp.einsum("bhp,bn->bhpn", dx, bv))
-    y = jnp.einsum("bhpn,bn->bhp", state, cv) + p["d_skip"][:, None] * xh
+             + jnp.einsum("bhp,bhn->bhpn", dx, bv))
+    y = jnp.einsum("bhpn,bhn->bhp", state, cv) + p["d_skip"][:, None] * xh
     y = y.reshape(x.shape[0], 1, di)
-    y = _gated_norm(p, y, z, cfg.norm_eps)
+    y = _gated_norm(p, y, z, cfg.norm_eps, g)
     out = jnp.einsum("bse,ed->bsd", y.astype(x.dtype), p["w_out"],
                      preferred_element_type=jnp.float32).astype(x.dtype)
     return out, {"state": state, "conv": new_conv}
@@ -195,7 +212,7 @@ def ssd_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray, cache: Params):
 
 def ssd_init_cache(cfg: ModelConfig, batch: int, dtype) -> Params:
     di, h, ph, n = _dims(cfg)
-    conv_ch = di + 2 * n
+    conv_ch = di + 2 * cfg.ssm_groups * n
     return {
         "state": jnp.zeros((batch, h, ph, n), jnp.float32),
         "conv": jnp.zeros((batch, cfg.conv_width - 1, conv_ch), dtype),

@@ -26,11 +26,18 @@ from repro.models.cache import (full_kv_to_cache, init_cache, mla_kv_to_cache)
 from repro.models.common import (ModelConfig, Params, dense_init, embed_init,
                                  init_rmsnorm, rmsnorm)
 
-MIXER_KINDS = ("global", "local", "mla", "ssd", "rec")
+MIXER_KINDS = ("global", "local", "mla", "ssd", "rec", "moe")
+
+# each layer's ops carry the scope ``<cfg.name>.<kind's scope>``
+_SCOPE = {"global": "attn", "local": "attn", "mla": "attn", "ssd": "mamba",
+          "rec": "rglru", "moe": "moe"}
 
 
 def _has_ffn(cfg: ModelConfig, kind: str) -> bool:
-    return cfg.d_ff > 0
+    """Whether a layer ends in an FFN. A pattern with an expert layer of
+    its own ("moe", Nemotron-H) is made of single-op blocks, none of
+    which carries one."""
+    return cfg.d_ff > 0 and "moe" not in cfg.layer_pattern
 
 
 # ---------------------------------------------------------------------------
@@ -65,6 +72,8 @@ def init_layer(key, cfg: ModelConfig, kind: str) -> Params:
         p["mixer"] = ssm_mod.init_ssd(k1, cfg)
     elif kind == "rec":
         p["mixer"] = rglru_mod.init_rglru(k1, cfg)
+    elif kind == "moe":
+        p["mixer"] = ffn_mod.init_held_moe(k1, cfg)
     else:
         raise ValueError(f"unknown mixer kind {kind!r}")
     if _has_ffn(cfg, kind):
@@ -104,19 +113,28 @@ def _apply_mixer_full(p, cfg, kind, h, positions, want_cache: bool):
 def apply_layer(p: Params, cfg: ModelConfig, kind: str, x: jnp.ndarray,
                 positions: jnp.ndarray, moe_path: str = "gshard",
                 cache_seq: int = 0):
-    """Full-sequence layer. Returns (x, aux_loss, cache_or_None)."""
-    h = rmsnorm(p["norm1"], x, cfg.norm_eps)
-    want_cache = cache_seq > 0
-    y, raw = _apply_mixer_full(p["mixer"], cfg, kind, h, positions, want_cache)
-    x = x + y
-    aux = jnp.zeros((), jnp.float32)
-    if _has_ffn(cfg, kind):
-        h = rmsnorm(p["norm2"], x, cfg.norm_eps)
-        if cfg.is_moe:
-            y, aux = ffn_mod.moe_forward(p["ffn"], cfg, h, path=moe_path)
+    """Full-sequence layer. Returns (x, aux_loss, cache_or_None, load):
+    ``load`` is an expert layer's token-choice count per held expert,
+    None for other kinds."""
+    with jax.named_scope(f"{cfg.name}.{_SCOPE[kind]}"):
+        h = rmsnorm(p["norm1"], x, cfg.norm_eps)
+        want_cache = cache_seq > 0
+        load = None
+        if kind == "moe":
+            y, load = ffn_mod.held_moe_forward(p["mixer"], cfg, h)
+            raw = ("none", {})
         else:
-            y = ffn_mod.dense_ffn(p["ffn"], h)
+            y, raw = _apply_mixer_full(p["mixer"], cfg, kind, h, positions,
+                                       want_cache)
         x = x + y
+        aux = jnp.zeros((), jnp.float32)
+        if _has_ffn(cfg, kind):
+            h = rmsnorm(p["norm2"], x, cfg.norm_eps)
+            if cfg.is_moe:
+                y, aux = ffn_mod.moe_forward(p["ffn"], cfg, h, path=moe_path)
+            else:
+                y = ffn_mod.dense_ffn(p["ffn"], h)
+            x = x + y
     cache = None
     if want_cache:
         if raw[0] == "kv":
@@ -126,7 +144,7 @@ def apply_layer(p: Params, cfg: ModelConfig, kind: str, x: jnp.ndarray,
             cache = mla_kv_to_cache(raw[1], raw[2], cache_seq)
         else:
             cache = raw[1]
-    return x, aux, cache
+    return x, aux, cache, load
 
 
 def apply_layer_decode(p: Params, cfg: ModelConfig, kind: str,
@@ -174,16 +192,24 @@ def init_params(key, cfg: ModelConfig) -> Params:
         p["frontend_proj"] = dense_init(
             keys[2], (frontend_dim(cfg.frontend), cfg.d_model),
             cfg.param_dtype)
+    p.update(init_stack(key, cfg, keys[3:]))
+    return p
+
+
+def init_stack(key, cfg: ModelConfig, pos_keys=None) -> Params:
+    """The layer stack's params: ``groups`` (pos_i -> stacked (G, ...))
+    and the unscanned remainder ``rem``."""
+    if pos_keys is None:
+        pos_keys = jax.random.split(key, len(cfg.layer_pattern))
     groups: Dict[str, Any] = {}
     for i, kind in enumerate(cfg.layer_pattern):
-        ks = jax.random.split(keys[3 + i], max(cfg.n_groups, 1))
+        ks = jax.random.split(pos_keys[i], max(cfg.n_groups, 1))
         groups[f"pos{i}"] = jax.vmap(
             lambda k, kind=kind: init_layer(k, cfg, kind))(ks[:cfg.n_groups])
-    p["groups"] = groups
     rem_key = jax.random.split(key, cfg.n_remainder + 1)
-    p["rem"] = [init_layer(rem_key[i], cfg, cfg.layer_pattern[i])
-                for i in range(cfg.n_remainder)]
-    return p
+    return {"groups": groups,
+            "rem": [init_layer(rem_key[i], cfg, cfg.layer_pattern[i])
+                    for i in range(cfg.n_remainder)]}
 
 
 def abstract_params(cfg: ModelConfig, seed: int = 0) -> Params:
@@ -220,23 +246,77 @@ def lm_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 # forward / prefill / decode
 # ---------------------------------------------------------------------------
 
-def _stack_body(cfg: ModelConfig, positions, moe_path: str, cache_seq: int):
+def _stack_body(cfg: ModelConfig, positions, moe_path: str, cache_seq: int,
+                remat_layers: bool = False):
     pattern = cfg.layer_pattern
+
+    def layer(kind):
+        def f(p, x):
+            return apply_layer(p, cfg, kind, x, positions, moe_path,
+                               cache_seq)
+        return jax.checkpoint(f) if remat_layers else f
 
     def body(carry, gp):
         x, aux = carry
         if _LAYER_PARAM_HOOK is not None:
             gp = _LAYER_PARAM_HOOK(gp)
-        caches = {}
+        caches, loads = {}, {}
         for i, kind in enumerate(pattern):
-            x, a, c = apply_layer(gp[f"pos{i}"], cfg, kind, x, positions,
-                                  moe_path, cache_seq)
+            x, a, c, load = layer(kind)(gp[f"pos{i}"], x)
             aux = aux + a
             if cache_seq > 0:
                 caches[f"pos{i}"] = c
-        return (x, aux), (caches if cache_seq > 0 else None)
+            if load is not None:
+                loads[f"pos{i}"] = load
+        return (x, aux), {"cache": caches if cache_seq > 0 else None,
+                          "load": loads}
 
     return body
+
+
+def run_stack(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+              positions: Optional[jnp.ndarray] = None,
+              moe_path: str = "gshard", remat: bool = False,
+              remat_layers: bool = False):
+    """The layer stack on embedded inputs x (B,S,D). Returns (x, moe aux
+    loss, loads): ``loads`` (n_expert_layers, n_held) int32 is each
+    "moe" layer's token-choice count per held expert, in layer order
+    (None where the pattern has none).
+
+    ``remat=True`` checkpoints each scan group (activation recompute in
+    the backward pass) — required for the big archs' train_step to fit
+    HBM. ``remat_layers=True`` checkpoints each layer instead, so the
+    backward holds one layer's internals at a time: what a stack of one
+    long group (Nemotron-H's period) needs."""
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.int32)
+    aux = jnp.zeros((), jnp.float32)
+    loads = []
+    if cfg.n_groups > 0:
+        body = _stack_body(cfg, positions, moe_path, 0, remat_layers)
+        if remat:
+            body = jax.checkpoint(body)
+        (x, aux), ys = jax.lax.scan(body, (x, aux), params["groups"])
+        group_loads = [ys["load"][f"pos{i}"]
+                       for i, kind in enumerate(cfg.layer_pattern)
+                       if kind == "moe"]
+        if group_loads:                 # (G, n, held) in layer order
+            loads.append(jnp.stack(group_loads, axis=1).reshape(
+                -1, group_loads[0].shape[-1]))
+    for i, p in enumerate(params["rem"]):
+        layer = functools.partial(apply_layer, cfg=cfg,
+                                  kind=cfg.layer_pattern[i],
+                                  positions=positions, moe_path=moe_path,
+                                  cache_seq=0)
+        if remat or remat_layers:
+            layer = jax.checkpoint(lambda p_, x_, f=layer: f(p_, x=x_))
+            x, a, _, load = layer(p, x)
+        else:
+            x, a, _, load = layer(p, x=x)
+        aux = aux + a
+        if load is not None:
+            loads.append(load[None])
+    return x, aux, (jnp.concatenate(loads) if loads else None)
 
 
 def forward(params: Params, cfg: ModelConfig,
@@ -245,33 +325,10 @@ def forward(params: Params, cfg: ModelConfig,
             positions: Optional[jnp.ndarray] = None,
             moe_path: str = "gshard",
             remat: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (logits (B,S,V) fp32, moe_aux_loss scalar).
-
-    ``remat=True`` checkpoints each scan group (activation recompute in the
-    backward pass) — required for the big archs' train_step to fit HBM."""
+    """Returns (logits (B,S,V) fp32, moe_aux_loss scalar); ``remat`` as
+    in ``run_stack``."""
     x = embed_inputs(params, cfg, tokens, embeds)
-    s = x.shape[1]
-    if positions is None:
-        positions = jnp.arange(s, dtype=jnp.int32)
-    aux0 = jnp.zeros((), jnp.float32)
-    if cfg.n_groups > 0:
-        body = _stack_body(cfg, positions, moe_path, 0)
-        if remat:
-            body = jax.checkpoint(body)
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["groups"])
-    else:
-        aux = aux0
-    for i, p in enumerate(params["rem"]):
-        layer = functools.partial(apply_layer, cfg=cfg,
-                                  kind=cfg.layer_pattern[i],
-                                  positions=positions, moe_path=moe_path,
-                                  cache_seq=0)
-        if remat:
-            layer = jax.checkpoint(lambda p_, x_, f=layer: f(p_, x=x_))
-            x, a, _ = layer(p, x)
-        else:
-            x, a, _ = layer(p, x=x)
-        aux = aux + a
+    x, aux, _ = run_stack(params, cfg, x, positions, moe_path, remat)
     return lm_logits(params, cfg, x), aux
 
 
@@ -290,11 +347,12 @@ def prefill(params: Params, cfg: ModelConfig,
     group_caches = {}
     if cfg.n_groups > 0:
         body = _stack_body(cfg, positions, moe_path, cache_seq)
-        (x, _), group_caches = jax.lax.scan(body, (x, aux0), params["groups"])
+        (x, _), ys = jax.lax.scan(body, (x, aux0), params["groups"])
+        group_caches = ys["cache"]
     rem_caches: List[Params] = []
     for i, p in enumerate(params["rem"]):
-        x, _, c = apply_layer(p, cfg, cfg.layer_pattern[i], x, positions,
-                              moe_path, cache_seq)
+        x, _, c, _ = apply_layer(p, cfg, cfg.layer_pattern[i], x, positions,
+                                 moe_path, cache_seq)
         rem_caches.append(c)
     cache = {"groups": group_caches, "rem": rem_caches}
     return lm_logits(params, cfg, x), cache

@@ -22,7 +22,9 @@ feature vectors through a shared patch adapter: the ``in_dim`` features
 are zero-padded to ``S * patch``, reshaped to ``(B, S, patch)`` tokens,
 linearly embedded to ``d_model``, mixed, mean-pooled, and classified.
 The ResNet-1D family reads the raw series directly (``apply_resnet1d``
-adds the channel axis itself). The MLP tiers are byte-for-byte the
+adds the channel axis itself). ``nemotron-h`` is Nemotron-H's hybrid
+stack (Mamba-2, expert and GQA layers, ``models/transformer.py``) at the
+published widths of Nemotron-3-Nano, over tokens of 25 samples. The MLP tiers are byte-for-byte the
 ``hetero_mlp_zoo`` configs, so MLP-only federations built through the
 registry reproduce the pinned trajectories bit-identically.
 """
@@ -31,11 +33,15 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from repro.models import transformer as tfm
 from repro.models.attention import attn_forward, init_attention
-from repro.models.common import ModelConfig, dense_init
+from repro.models.common import (ModelConfig, dense_init, init_rmsnorm,
+                                 rmsnorm)
 from repro.models.mlp import MLPConfig, mlp_family
 from repro.models.resnet import ResNet1DConfig, resnet1d_family
 from repro.models.rglru import init_rglru, rglru_forward
@@ -245,13 +251,14 @@ def _n_patch(in_dim: int) -> int:
     return -(-in_dim // _SEQ_LEN)
 
 
-def _to_tokens(x: jnp.ndarray, n_patch: int) -> jnp.ndarray:
+def _to_tokens(x: jnp.ndarray, n_patch: int,
+               seq: int = _SEQ_LEN) -> jnp.ndarray:
     """(B, L) flat features -> (B, S, patch), zero-padded tail."""
     x = x.reshape(x.shape[0], -1)
-    pad = _SEQ_LEN * n_patch - x.shape[1]
+    pad = seq * n_patch - x.shape[1]
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
-    return x.reshape(x.shape[0], _SEQ_LEN, n_patch)
+    return x.reshape(x.shape[0], seq, n_patch)
 
 
 def _seq_family(cfg: ModelConfig, mixer_init, mixer_fn,
@@ -309,3 +316,115 @@ def _build_rglru(in_dim: int, n_classes: int) -> FamilyFns:
                       n_heads=1, n_kv_heads=1, d_ff=0, vocab_size=0,
                       lru_width=16, conv_width=2, param_dtype=jnp.float32)
     return _seq_family(cfg, init_rglru, rglru_forward, in_dim, n_classes)
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-H: Mamba-2, expert and GQA layers over patch tokens
+# ---------------------------------------------------------------------------
+
+NEMOTRON_PATCH = 25   # samples a token: 250 ms of a 100 Hz series
+# reference samples a gradient block: at the published widths the
+# backward over 40 sequences of 120 tokens holds about 1.6 GB
+NEMOTRON_REF_BLOCK = 40
+
+_HYBRID_KINDS = {"M": "ssd", "E": "moe", "*": "global"}
+
+
+def hybrid_pattern(pattern: str) -> Tuple[str, ...]:
+    """``hybrid_override_pattern`` letters -> layer kinds."""
+    return tuple(_HYBRID_KINDS[c] for c in pattern)
+
+
+# Nemotron-3-Nano-30B-A3B (huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-
+# 30B-A3B-BF16, config.json) at its published widths: its first seven
+# layers, one whole period, and this chip's share of each expert layer,
+# experts 0-7 of 128 (16 chips splitting each expert layer).
+NEMOTRON_H = ModelConfig(
+    "nemotron_h", "hybrid", n_layers=7, d_model=2688, n_heads=32,
+    n_kv_heads=2, head_dim=128, d_ff=1856, vocab_size=0,
+    layer_pattern=hybrid_pattern("MEMEM*E"), use_rope=False,
+    n_experts=128, moe_top_k=6, n_shared_experts=1, shared_d_ff=3712,
+    routed_scale=2.5, experts_held=8, ssm_state=128, ssm_heads=64,
+    ssm_head_dim=64, ssm_groups=8, conv_width=4, ssm_chunk=128,
+    norm_eps=1e-5, param_dtype=jnp.float32,
+    source="https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16")
+
+
+class ExpertFamilyApply:
+    """The ``apply_fn`` of a family with expert layers. Called as
+    ``(p, x) -> logits`` like every family's; ``with_stats(p, x)`` also
+    gives each expert layer's token-choice count per held expert,
+    (n_expert_layers, experts_held). The cohort step
+    (``repro.core.client.expert_cohort_step``) returns those counts
+    beside the loss, donates the cohort's params and optimizer state and
+    differentiates the reference term in blocks of ``ref_block``
+    samples; its span carries ``experts_held`` and the tokens stepped
+    (``seq_len`` a sample)."""
+
+    def __init__(self, with_stats: Callable, experts_held: int,
+                 seq_len: int, ref_block: int = 0):
+        self.with_stats = with_stats
+        self.experts_held = experts_held
+        self.seq_len = seq_len
+        self.ref_block = ref_block
+
+    def __call__(self, p, x):
+        return self.with_stats(p, x)[0]
+
+
+def _mamba2_a_dt(key, cfg: ModelConfig, mixer):
+    """Mamba-2's init of A and dt: A ~ U[1, 16]; dt log-uniform in
+    [1e-3, 1e-1] (floor 1e-4), stored as softplus' inverse."""
+    k_a, k_dt = jax.random.split(key)
+    shape = mixer["a_log"].shape
+    a = jax.random.uniform(k_a, shape, jnp.float32, 1.0, 16.0)
+    dt = jnp.exp(jax.random.uniform(k_dt, shape, jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    dt = jnp.maximum(dt, 1e-4)
+    return dict(mixer, a_log=jnp.log(a), dt_bias=dt + jnp.log(-jnp.expm1(-dt)))
+
+
+def nemotron_h_family(cfg: ModelConfig, in_dim: int, n_classes: int,
+                      patch: int = NEMOTRON_PATCH,
+                      ref_block: int = NEMOTRON_REF_BLOCK) -> FamilyFns:
+    """Patch tokens of ``patch`` samples, linearly embedded; the
+    Nemotron-H stack with per-layer rematerialisation; the final RMSNorm;
+    mean pool and a class head in place of the LM head. The cohort step
+    differentiates its reference term in blocks of ``ref_block``
+    samples."""
+    seq = -(-in_dim // patch)
+    d = cfg.d_model
+
+    def init_fn(key):
+        k_embed, k_stack, k_head, k_ssm = jax.random.split(key, 4)
+        stack = tfm.init_stack(k_stack, cfg)
+        for i, kind in enumerate(cfg.layer_pattern):
+            if kind == "ssd":
+                pos = stack["groups"][f"pos{i}"]
+                pos["mixer"] = _mamba2_a_dt(jax.random.fold_in(k_ssm, i),
+                                            cfg, pos["mixer"])
+        return {
+            "embed_w": dense_init(k_embed, (patch, d), jnp.float32,
+                                  fan_in=patch),
+            "embed_b": jnp.zeros((d,), jnp.float32),
+            "stack": stack,
+            "final_norm": init_rmsnorm(d, jnp.float32),
+            "head_w": dense_init(k_head, (d, n_classes), jnp.float32,
+                                 fan_in=d),
+            "head_b": jnp.zeros((n_classes,), jnp.float32),
+        }
+
+    def with_stats(p, x):
+        h = _to_tokens(x, patch, seq) @ p["embed_w"] + p["embed_b"]
+        h, _, loads = tfm.run_stack(p["stack"], cfg, h, remat_layers=True)
+        h = jnp.mean(rmsnorm(p["final_norm"], h, cfg.norm_eps), axis=1)
+        return h @ p["head_w"] + p["head_b"], loads
+
+    return init_fn, ExpertFamilyApply(with_stats, cfg.n_held, seq,
+                                      ref_block)
+
+
+@register_family("nemotron-h", optimizer=lambda: adam(3e-3),
+                 tier="hospital server")
+def _build_nemotron_h(in_dim: int, n_classes: int) -> FamilyFns:
+    return nemotron_h_family(NEMOTRON_H, in_dim, n_classes)

@@ -115,6 +115,38 @@ def test_k_sparse_neighbor_mean_compiles_at_server_size(one_chip, n,
     assert ("tpu_custom_call" in text) == resident
 
 
+def test_nemotron_h_cohort_step_fits_one_chip(one_chip):
+    """The ``nemotron-h`` cohort step at its published widths (one client:
+    16 local and 240 reference series of 3000 samples, Adam state,
+    params and state donated) compiles for a v5e within 15 GB of its
+    16, so the chip run cannot fail for memory."""
+    from repro.core import client
+    from repro.models.zoo import build_zoo
+    n, b, r, length, c = 1, 16, 240, 3000, 3
+    zoo = build_zoo("nemotron-h", length, c)
+    init_fn, apply_fn = zoo["nemotron-h"]
+    opt = zoo.optimizers["nemotron-h"]
+
+    def stacks():
+        params = jax.vmap(init_fn)(jax.random.split(jax.random.key(0), n))
+        return params, jax.vmap(opt.init)(params)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, state = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                                 jax.eval_shape(stacks))
+    compiled = client.expert_cohort_step.lower(
+        apply_fn, opt, params, state, spec((n, b, length)),
+        spec((n, b), jnp.int32), spec((r, length)), spec((n, r, c)),
+        spec((n,), jnp.bool_), rho=0.8, use_ref=True).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > 4e9          # params and Adam donated
+    assert total < 15e9, total
+
+
 @pytest.fixture(scope="module")
 def four_chips(topo):
     from repro.sharding import CLIENT_AXIS
