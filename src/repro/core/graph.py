@@ -7,7 +7,10 @@ SQMD's graph is carried K-sparse: ``neighbors`` (N,K) and ``edge_weights``
 (N,K), 1/count on each realized edge and 0 on unrealized slots, which is
 all Eq. 5 reads (``ops.neighbor_mean``). Graphs that are dense by nature
 (FedMD, D-Dist) carry the row-stochastic (N,N) ``weights`` instead;
-``selection_matrix`` gives either as the dense W off the hot path.
+``selection_matrix`` gives either as the dense W off the hot path. SQMD's
+graph carries no (N,N) similarity either: its selection reads only the Q
+pool columns of the divergence, and ``similarity_of`` derives the whole C
+from ``divergence`` for readers off the hot path.
 """
 from __future__ import annotations
 
@@ -26,7 +29,8 @@ class CollaborationGraph(NamedTuple):
     neighbors: jnp.ndarray       # (N, K) int32 neighbor indices
     weights: Optional[jnp.ndarray]  # (N, N) fp32 row-stochastic selection
     # matrix of a dense graph; None on K-sparse graphs
-    similarity: jnp.ndarray      # (N, N) fp32 c_nm (the C matrix of Def. 5)
+    similarity: Optional[jnp.ndarray]  # (N, N) fp32 c_nm (the C matrix
+    # of Def. 5); None where it derives from ``divergence`` (SQMD)
     candidates: jnp.ndarray      # (N,) bool — the Q pool
     divergence: Optional[jnp.ndarray] = None  # (N,N) fp32 Eq.2 matrix this
     # graph was built from; policies that compute it surface it here so
@@ -63,36 +67,59 @@ def selection_matrix(g: CollaborationGraph) -> jnp.ndarray:
                           g.neighbors.shape[0])
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _select_pool(similarity: jnp.ndarray, pool: jnp.ndarray,
-                 pool_valid: jnp.ndarray, k: int):
-    """Top-k over the candidate POOL columns only: O(N·Q·log k) instead of
-    O(N²·log k) — at 10k clients the pool is what bounds the cost."""
-    n = similarity.shape[0]
-    sub = similarity[:, pool]                               # (N, B)
-    rowidx = jnp.arange(n, dtype=pool.dtype)[:, None]
-    # padded slots and self-edges are unrealizable
+def similarity_of(g: CollaborationGraph) -> jnp.ndarray:
+    """The (N,N) Def. 4 similarity C of any graph, for readers off the hot
+    path (stats, tests, policies that keep it): ``similarity`` where the
+    graph carries it, else derived from its ``divergence``."""
+    if g.similarity is not None:
+        return g.similarity
+    from repro.core.similarity import similarity_matrix
+    return similarity_matrix(g.divergence)
+
+
+def _pool_topk(sub: jnp.ndarray, pool: jnp.ndarray, pool_valid: jnp.ndarray,
+               k: int):
+    """(N,B) pool-column scores -> the top-k selection: padded slots and
+    self-edges are unrealizable."""
+    rowidx = jnp.arange(sub.shape[0], dtype=pool.dtype)[:, None]
     sub = jnp.where(pool_valid[None, :] & (pool[None, :] != rowidx),
                     sub, -BIG)
     return _topk_weights(sub, pool, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+def _select_pool(similarity: jnp.ndarray, pool: jnp.ndarray,
+                 pool_valid: jnp.ndarray, k: int):
+    """Top-k over the candidate POOL columns only: O(N·Q·log k) instead of
+    O(N²·log k) — at 10k clients the pool is what bounds the cost."""
+    return _pool_topk(similarity[:, pool], pool, pool_valid, k)
+
+
+def _pool_columns(div: jnp.ndarray, pool: jnp.ndarray) -> jnp.ndarray:
+    """The (N,B) pool columns ``div[:, pool]``, as ``div`` times the (B,N)
+    one-hot of ``pool`` at HIGHEST precision. The matmul reads ``div`` in
+    its stored row-major layout (a column ``take`` transposes the whole
+    matrix on the TPU first) and keeps a row split over a client mesh
+    with no collective. Each column is one term x·1 among zeros, which
+    HIGHEST's float32-exact passes give back bit for bit for finite x (a
+    zero may lose its sign, which Def. 4's EPS floor does not see); Eq. 2
+    of finite log-probabilities is finite."""
+    onehot = jax.nn.one_hot(pool, div.shape[1], dtype=div.dtype)
+    return jax.lax.dot_general(div, onehot, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
 def _select_pool_div(div: jnp.ndarray, pool: jnp.ndarray,
                      pool_valid: jnp.ndarray, k: int):
-    """Fused Def.4+5 from the divergence matrix: one compiled call emits
-    the similarity matrix AND the pool top-k selection — the elementwise
-    similarity transform rides the same pass instead of materializing an
-    extra (N,N) intermediate between two dispatches (the nested
-    _select_pool jit inlines here)."""
+    """Def. 4 + the pool top-k from the (N,N) divergence matrix, on its B
+    pool columns alone: no (N,N) value is written, copied or transposed.
+    The scores are ``similarity_matrix(div)[:, pool]`` bit for bit (the
+    same elementwise ops; the pool mask covers the zeroed diagonal), in
+    pool order, so ``top_k`` breaks ties as over the whole matrix."""
     from repro.core.similarity import EPS
-    n = div.shape[0]
-    c = 1.0 / jnp.maximum(div, EPS)
-    i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    sim = c * (i != j).astype(c.dtype)
-    nbrs, vals = _select_pool(sim, pool, pool_valid, k)
-    return sim, nbrs, vals
+    cols = _pool_columns(div, pool)
+    return _pool_topk(1.0 / jnp.maximum(cols, EPS), pool, pool_valid, k)
 
 
 def _topk_weights(sub: jnp.ndarray, pool: jnp.ndarray, k: int):
@@ -111,10 +138,12 @@ def _empty(n: int, k: int):
 
 
 def _pool_bucket(candidates, k: int):
-    """Candidate mask -> host (padded pool indices, validity) or None if
-    the pool is empty. Power-of-two padding keeps jit compiles
-    per-bucket."""
-    pool = np.nonzero(host_read(candidates, "select.pool", bool))[0]
+    """Candidate mask (a host array, or a device array read here) -> host
+    (padded pool indices, validity) or None if the pool is empty.
+    Power-of-two padding keeps jit compiles per-bucket."""
+    if not isinstance(candidates, np.ndarray):
+        candidates = host_read(candidates, "select.pool", bool)
+    pool = np.nonzero(candidates)[0]
     if pool.size == 0 or k == 0:
         return None
     bucket = max(1 << (pool.size - 1).bit_length(), k)
@@ -163,29 +192,30 @@ def select_neighbors(similarity: jnp.ndarray, candidates: jnp.ndarray,
     return k_sparse(nbrs, vals, similarity, candidates)
 
 
-def select_neighbors_from_div(divergence: jnp.ndarray, candidates: jnp.ndarray,
+def select_neighbors_from_div(divergence: jnp.ndarray, candidates,
                               k: int) -> CollaborationGraph:
-    """``select_neighbors`` fused with the Def.4 similarity transform:
-    takes the (N,N) divergence matrix, emits the graph with both
-    ``similarity`` and ``divergence`` populated in a single compiled
-    call — the hot path for SQMD server rounds at large N."""
+    """``select_neighbors`` on the Def. 4 similarity of the (N,N)
+    divergence matrix, computed on the pool columns alone — the hot path
+    for SQMD server rounds at large N. ``candidates`` may be a host mask,
+    read before the divergence was queued (a device mask is read here).
+    The graph carries ``divergence`` and no ``similarity``
+    (``similarity_of`` derives it)."""
     n = divergence.shape[0]
     k = min(k, n - 1)
     if isinstance(candidates, jax.core.Tracer):
         from repro.core.similarity import similarity_matrix
         sim = similarity_matrix(divergence)
-        return k_sparse(*_select_dense(sim, candidates, k), sim,
+        return k_sparse(*_select_dense(sim, candidates, k), None,
                         candidates, divergence)
     bucket = _pool_bucket(candidates, k)
     if bucket is None:
-        from repro.core.similarity import similarity_matrix
-        return k_sparse(*_empty(n, k), similarity_matrix(divergence),
-                        candidates, divergence)
+        return k_sparse(*_empty(n, k), None, candidates, divergence)
     pool, valid = bucket
-    with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
-        sim, nbrs, vals = _select_pool_div(divergence, jnp.asarray(pool),
-                                           jnp.asarray(valid), k)
-    return k_sparse(nbrs, vals, sim, candidates, divergence)
+    with span("repro.select", pool=int(valid.sum()), bucket=valid.size,
+              form="onehot"):
+        nbrs, vals = _select_pool_div(divergence, jnp.asarray(pool),
+                                      jnp.asarray(valid), k)
+    return k_sparse(nbrs, vals, None, candidates, divergence)
 
 
 def fedmd_graph(active: jnp.ndarray) -> CollaborationGraph:

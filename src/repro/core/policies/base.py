@@ -103,7 +103,8 @@ class ServerPolicy(abc.ABC):
 
     name: str = "?"                 # bound by @register_policy
     uses_reference: bool = True     # False => no messengers, no server round
-    computes_similarity: bool = False  # True => graph.similarity -> state.sim
+    computes_similarity: bool = False  # True => graph.similarity, where
+    # the graph carries one, -> state.sim
     # Client device mesh (repro.sharding.make_client_mesh), attached by the
     # ServerBus when the engine runs device-sharded: policies whose graph
     # build scales with the population (SQMD's O(N²·R·C) divergence) shard
@@ -194,11 +195,14 @@ class ServerPolicy(abc.ABC):
     # -- state fold-in -----------------------------------------------------
     def update_state(self, state, quality: jnp.ndarray, graph):
         """Fold this round's results into the ServerState. Policies that do
-        not compute similarity keep the previous ``sim`` matrix; a graph
-        carrying the divergence it was built from refreshes ``div_cache``
-        (both the full rebuild and the delta scatter produce it, so the
-        cache always matches the current repository)."""
-        sim = graph.similarity if self.computes_similarity else state.sim
+        not compute similarity, and graphs that carry none (SQMD's, whose
+        similarity derives from its divergence: ``graph.similarity_of``),
+        keep the previous ``sim`` matrix; a graph carrying the divergence
+        it was built from refreshes ``div_cache`` (both the full rebuild
+        and the delta scatter produce it, so the cache always matches the
+        current repository)."""
+        sim = (graph.similarity if self.computes_similarity
+               and graph.similarity is not None else state.sim)
         div = (graph.divergence if graph.divergence is not None
                else state.div_cache)
         return state._replace(quality=quality, sim=sim, div_cache=div,

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,9 +29,10 @@ class SQMDPolicy(ServerPolicy):
                     backend: Optional[str] = None):
         # self.mesh (bus-attached) shards the O(N²·R·C) rebuild row-wise
         # over the client mesh; None is the single-device oracle
+        cand = self._pool(state, quality)
         div = sim_mod.divergence_matrix(state.repo_logp, backend=backend,
                                         mesh=self.mesh)
-        return self._select(state, quality, div)
+        return graph_mod.select_neighbors_from_div(div, cand, self.protocol.k)
 
     def build_graph_delta(self, state, quality: jnp.ndarray, uploaded, *,
                           backend: Optional[str] = None):
@@ -41,16 +43,23 @@ class SQMDPolicy(ServerPolicy):
         if self.selection == "ivf":
             return self._build_graph_ivf(state, quality, uploaded,
                                          backend=backend)
+        cand = self._pool(state, quality)
         div = sim_mod.update_divergence_cache(state.div_cache,
                                               state.repo_logp, uploaded,
                                               backend=backend)
-        return self._select(state, quality, div)
+        return graph_mod.select_neighbors_from_div(div, cand, self.protocol.k)
 
-    def _select(self, state, quality: jnp.ndarray, div: jnp.ndarray):
+    def _pool(self, state, quality: jnp.ndarray):
+        """The Def. 3 pool as a host mask, read before the divergence work
+        is queued: the device runs programs in order, so the read then
+        waits for the grades alone and not for the strips and scatter,
+        which run while the host goes on. Under a trace it stays a tracer
+        (the graph then takes the dense fallback)."""
         cand = quality_mod.candidate_mask(quality, state.active,
                                           self.protocol.q)
-        return graph_mod.select_neighbors_from_div(div, cand,
-                                                   self.protocol.k)
+        if isinstance(cand, jax.core.Tracer):
+            return cand
+        return host_read(cand, "select.pool", bool)
 
     # -- approximate (IVF) path -------------------------------------------
     def _index_for(self, state,

@@ -4,7 +4,9 @@ State (a pytree — jit-able end to end):
   repo_logp (N,R,C)  messenger repository S (stale rows allowed: asynchrony)
   active    (N,)     participation mask (clients that have ever joined)
   quality   (N,)     latest Eq.1 grades
-  sim       (N,N)    latest similarity matrix C (Def. 5)
+  sim       (N,N)    latest similarity matrix C (Def. 5) of a policy whose
+                     graph carries one; SQMD's derives from div_cache
+                     (``graph.similarity_of``) and leaves it as it was
   round     ()       round counter
   div_cache (N,N)    cached Eq.2 divergence matrix of the CURRENT
                      repository — the delta path scatters u×N / N×u strips

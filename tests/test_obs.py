@@ -180,7 +180,7 @@ def test_fire_args(sync, fire):
     (_, _, _, upd), = _named(spans, "repro.div_update")
     assert upd == {"rows": 3, "bucket": 4}
     (_, _, _, sel), = _named(spans, "repro.select")
-    assert sel == {"pool": 8, "bucket": 8}
+    assert sel == {"pool": 8, "bucket": 8, "form": "onehot"}
     paths = {a["path"] for *_, a in _named(spans, "repro.build_graph")}
     assert paths == {"delta"}
     paths = {a["path"] for *_, a in _named(sync[0], "repro.build_graph")}

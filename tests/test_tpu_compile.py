@@ -115,6 +115,37 @@ def test_k_sparse_neighbor_mean_compiles_at_server_size(one_chip, n,
     assert ("tpu_custom_call" in text) == resident
 
 
+def _entry(text: str):
+    """The instructions of an optimized HLO module's entry computation:
+    the values the program materializes (fused ones live elsewhere)."""
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY"))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return lines[start + 1:end]
+
+
+def _selection_args(n, b, div_sharding, sharding):
+    return [jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=div_sharding),
+            jax.ShapeDtypeStruct((b,), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=sharding)]
+
+
+def test_pool_selection_reads_div_in_place(one_chip):
+    """The server's neighbour selection at N=16384 (bucket 64, k=8) reads
+    the pool columns of ``div`` as stored: no (N,N) float32 value but the
+    parameter (no transpose, no similarity matrix), and temp under
+    64 MiB."""
+    from repro.core import graph
+    n, b, k = 16384, 64, 8
+    compiled = graph._select_pool_div.lower(
+        *_selection_args(n, b, one_chip, one_chip), k=k).compile()
+    square = [l for l in _entry(compiled.as_text())
+              if f"f32[{n},{n}]" in l.split(" = ", 1)[-1].split(" ", 1)[0]
+              and " parameter(" not in l]
+    assert square == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def test_nemotron_h_cohort_step_fits_one_chip(one_chip):
     """The ``nemotron-h`` cohort step at its published widths (one client:
     16 local and 240 reference series of 3000 samples, Adam state,
@@ -181,6 +212,21 @@ def test_sharded_rebuild_compiles_on_four_chips(four_chips):
                                          sharding=whole)).compile().as_text()
     assert "tpu_custom_call" in text
     assert collective_violations("divergence_matrix[v5e:2x2]", text) == []
+
+
+@pytest.mark.parametrize("n", (32, 16384))
+def test_pool_selection_keeps_row_split_on_four_chips(four_chips, n):
+    """The sharded rebuild hands the selection a row-split ``div``: each
+    chip selects for its own rows, with no collective (no chip gathers
+    the (N,N) matrix)."""
+    from repro.core import graph
+    from repro.sharding import CLIENT_AXIS
+    rows = NamedSharding(four_chips, PartitionSpec(CLIENT_AXIS, None))
+    whole = NamedSharding(four_chips, PartitionSpec())
+    b = 16 if n == 32 else 64
+    text = graph._select_pool_div.lower(
+        *_selection_args(n, b, rows, whole), k=8).compile().as_text()
+    assert collective_violations("select_pool_div[v5e:2x2]", text) == []
 
 
 @pytest.mark.parametrize("env_dir", (None, "elsewhere"))
