@@ -88,7 +88,7 @@ def bench_one(n: int, n_probe, seed: int = 0, verbose: bool = True,
 
     rng = np.random.default_rng(seed)
     protos = rng.normal(scale=2.0, size=(N_PROTO, R, C))
-    idx = NeighborIndex(n, R, C, k=K, n_probe=n_probe, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=K, n_probe=n_probe)
 
     t0 = time.perf_counter()
     for lo in range(0, n, GEN_CHUNK):

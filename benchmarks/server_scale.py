@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 
 import jax
@@ -48,11 +49,12 @@ def _time(fn, reps=None):
     return best
 
 
-def bench_one(n: int, r: int, c: int, uploads: int, backend: str,
-              seed: int = 0, verbose: bool = True) -> dict:
+def bench_one(n: int, r: int, c: int, uploads: int, seed: int = 0,
+              verbose: bool = True) -> dict:
     from repro.core import init_server, upload_messengers
     from repro.core.policies import as_policy
     from repro.core.protocols import sqmd
+    from repro.kernels import ops
 
     key = jax.random.key(seed)
     logp = jax.nn.log_softmax(
@@ -61,10 +63,10 @@ def bench_one(n: int, r: int, c: int, uploads: int, backend: str,
     state = upload_messengers(init_server(n, r, c), logp,
                               jnp.ones((n,), bool))
     pol = as_policy(sqmd(q=min(64, n), k=min(8, n - 1)))
-    quality = pol.grade(state, labels, backend=backend)
+    quality = pol.grade(state, labels)
 
     # one full rebuild seeds the cache (and is the timing baseline)
-    full_graph = pol.build_graph(state, quality, backend=backend)
+    full_graph = pol.build_graph(state, quality)
     state = pol.update_state(state, quality, full_graph)
 
     # u freshly-uploaded rows: new messengers merged into the repository
@@ -75,9 +77,8 @@ def bench_one(n: int, r: int, c: int, uploads: int, backend: str,
     state = upload_messengers(state, fresh, jnp.asarray(mask))
 
     # correctness gate before any timing: delta scatter == full rebuild
-    delta_graph = pol.build_graph_delta(state, quality, mask,
-                                        backend=backend)
-    oracle = pol.build_graph(state, quality, backend=backend)
+    delta_graph = pol.build_graph_delta(state, quality, mask)
+    oracle = pol.build_graph(state, quality)
     err = float(jnp.max(jnp.abs(delta_graph.divergence - oracle.divergence)))
     scale = float(jnp.max(jnp.abs(oracle.divergence)))
     if not err <= 1e-4 * max(scale, 1.0):
@@ -89,19 +90,17 @@ def bench_one(n: int, r: int, c: int, uploads: int, backend: str,
 
     # (a) the divergence matrix itself: full O(N²·R·C) rebuild vs the
     #     O(u·N·R·C) strip-scatter — the delta path vs full rebuild
-    t_full = _time(lambda: divergence_matrix(state.repo_logp,
-                                             backend=backend))
+    t_full = _time(lambda: divergence_matrix(state.repo_logp))
     t_delta = _time(lambda: update_divergence_cache(
-        state.div_cache, state.repo_logp, mask, backend=backend))
+        state.div_cache, state.repo_logp, mask))
     # (b) the whole graph build (divergence + Def.4/5 pool selection) —
     #     what one server trigger actually costs end to end
-    t_full_g = _time(lambda: pol.build_graph(state, quality,
-                                             backend=backend).edge_weights)
+    t_full_g = _time(lambda: pol.build_graph(state, quality).edge_weights)
     t_delta_g = _time(lambda: pol.build_graph_delta(
-        state, quality, mask, backend=backend).edge_weights)
+        state, quality, mask).edge_weights)
     row = {
         "n_clients": n, "ref_size": r, "n_classes": c, "uploads": uploads,
-        "backend": backend,
+        "backend": ops.default_backend(),
         "full_rebuild_s": t_full, "delta_update_s": t_delta,
         "delta_speedup": t_full / t_delta,
         "graph_full_s": t_full_g, "graph_delta_s": t_delta_g,
@@ -133,6 +132,9 @@ def main() -> None:
                     default="jnp")
     ap.add_argument("--out", default=OUT)
     args = ap.parse_args()
+    # the process-level kernel setting, set before the first kernel call
+    from repro.kernels.backend import ENV_VAR
+    os.environ[ENV_VAR] = args.backend
 
     sizes = tuple(args.n) if args.n else DEFAULT_N
     print(f"== Server graph scaling: full O(N^2 R C) rebuild vs "
@@ -140,7 +142,7 @@ def main() -> None:
     rows = []
     for n in sizes:
         rows.append(bench_one(n, args.ref_size, args.classes,
-                              min(args.uploads, n), args.backend))
+                              min(args.uploads, n)))
         jax.clear_caches()
     with open(args.out, "w") as f:
         json.dump(rows, f, indent=2)

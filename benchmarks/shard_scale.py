@@ -114,8 +114,7 @@ def bench_child(sizes, n_dev: int, ref_size: int, classes: int,
             apply_fn, opt, params, opt_state, batch_d["x"], batch_d["y"],
             ref_x, targets, trainable, 0.8, True)[2])
         t_up = _time(lambda: upload(apply_fn, params, ref_x))
-        t_graph = _time(lambda: divergence_matrix(logp, backend="jnp",
-                                                  mesh=mesh))
+        t_graph = _time(lambda: divergence_matrix(logp, mesh=mesh))
         row = {"n_clients": n, "devices": n_dev,
                "ref_size": ref_size, "n_classes": classes, "batch": batch,
                "step_s": t_step, "upload_s": t_up, "graph_build_s": t_graph,

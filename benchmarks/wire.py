@@ -59,13 +59,13 @@ def _time(fn, reps: int = 5) -> float:
 
 
 def _graph_neighbors(div, n: int, q: int, k: int):
-    from repro.core.graph import select_neighbors_from_div
+    from repro.core.graph import select_neighbors
     cand = jnp.ones((n,), bool)
     if q < n:
         # the quality pool of a real round; rank by random-but-fixed
         # grades so every codec sees the same pool
         cand = cand.at[jnp.arange(n) >= q].set(False)
-    return np.asarray(select_neighbors_from_div(div, cand, k).neighbors)
+    return np.asarray(select_neighbors(div, cand, k).neighbors)
 
 
 def bench_shape(n: int, r: int, c: int, k: int, backend: str,

@@ -9,7 +9,7 @@ milliseconds and zero array memory.
 
 One deliberate divergence from the PR 6 fixtures: the graph entries
 (``sqmd.build_graph`` / ``sqmd.build_graph_delta``) stage the candidate
-POOL concretely, exactly as the runtime does. ``select_neighbors_from_div``
+POOL concretely, exactly as the runtime does. ``graph.select_neighbors``
 needs concrete candidates to take its (N,Q) pool path and falls back to
 the dense O(N²) top-k under a tracer — tracing the policy hook naively
 would mis-attribute a Θ(N²) selection to the delta path and the
@@ -135,7 +135,7 @@ def _grade(d):
     from repro.kernels import ops
 
     def fn(repo_logp, labels):
-        return ops.soft_ce(repo_logp, labels, backend="jnp")
+        return ops.soft_ce(repo_logp, labels)
 
     return fn, (_f32(d["n"], d["r"], d["c"]), _i32(d["r"]))
 
@@ -162,7 +162,7 @@ def _build_graph(d):
     k = d["k"]
 
     def fn(repo_logp):
-        div = similarity.divergence_matrix(repo_logp, backend="jnp")
+        div = similarity.divergence_matrix(repo_logp)
         return graph_mod._select_pool_div(div, pool, pool_valid, k)
 
     return fn, (_f32(d["n"], d["r"], d["c"]),)
@@ -177,8 +177,7 @@ def _build_graph_delta(d):
     up[:d["u"]] = True
 
     def fn(div_cache, repo_logp):
-        div = similarity.update_divergence_cache(div_cache, repo_logp, up,
-                                                 backend="jnp")
+        div = similarity.update_divergence_cache(div_cache, repo_logp, up)
         return graph_mod._select_pool_div(div, pool, pool_valid, k)
 
     return fn, (_f32(n, n), _f32(n, d["r"], d["c"]))
@@ -188,7 +187,7 @@ def _divergence_matrix(d):
     from repro.core import similarity
 
     def fn(repo_logp):
-        return similarity.divergence_matrix(repo_logp, backend="jnp")
+        return similarity.divergence_matrix(repo_logp)
 
     return fn, (_f32(d["n"], d["r"], d["c"]),)
 
@@ -198,7 +197,7 @@ def _int8_dequant_kl(d):
     n, r, c = d["n"], d["r"], d["c"]
 
     def fn(q, scale, zp):
-        return ops.int8_pairwise_kl(q, scale, zp, backend="jnp")
+        return ops.int8_pairwise_kl(q, scale, zp)
 
     return fn, (jax.ShapeDtypeStruct((n, r, c), jnp.uint8),
                 _f32(n, r), _f32(n, r))
@@ -227,7 +226,7 @@ def _centroid_assign(d):
         # upload-vs-centroid KL strip — NeighborIndex._centroid_div
         recon = (q.astype(jnp.float32) * scale[..., None]
                  - lse[..., None])
-        return ops.pairwise_kl_pair(recon, centroids, backend="jnp")
+        return ops.pairwise_kl_pair(recon, centroids)
 
     return fn, (jax.ShapeDtypeStruct((u, r, c), jnp.uint8),
                 _f32(u, r), _f32(u, r), _f32(ncent, r, c))
@@ -242,12 +241,10 @@ def _ivf_search(d):
         # assignment strip + the forward/reverse candidate strips off the
         # int8 wire form — one NeighborIndex.update search round
         recon = (qu.astype(jnp.float32) * su[..., None] - lu[..., None])
-        d_cent = ops.pairwise_kl_pair(recon, centroids, backend="jnp")
+        d_cent = ops.pairwise_kl_pair(recon, centroids)
         zu = jnp.zeros_like(su)
-        fwd = ops.int8_pairwise_kl_pair(qu, su, zu, qc, sc, zc,
-                                        backend="jnp")
-        rev = ops.int8_pairwise_kl_pair(qc, sc, zc, qu, su, zu,
-                                        backend="jnp")
+        fwd = ops.int8_pairwise_kl_pair(qu, su, zu, qc, sc, zc)
+        rev = ops.int8_pairwise_kl_pair(qc, sc, zc, qu, su, zu)
         return d_cent, fwd, rev
 
     return fn, (jax.ShapeDtypeStruct((u, r, c), jnp.uint8),

@@ -96,8 +96,7 @@ def _probe_server():
     # a warm divergence cache so the delta path has something to scatter
     # into (matches the engine: the cache tracks the repository)
     from repro.core import similarity
-    st = st._replace(div_cache=similarity.divergence_matrix(
-        st.repo_logp, backend="jnp"))
+    st = st._replace(div_cache=similarity.divergence_matrix(st.repo_logp))
     labels = jax.random.randint(jax.random.key(12), (REF,), 0, CLASSES)
     return st, labels
 
@@ -141,28 +140,23 @@ def build_entries(ctx) -> Dict[str, TracedEntry]:
                                                codec=wire.Int8()),
         params, ref_x, codec_boundary=True)
 
-    # --- server round pieces (policy hooks, backend="jnp" oracle) --------
+    # --- server round pieces (policy hooks; the host platform's default
+    # kernel path is the jnp oracle) --------------------------------------
     st, labels = _probe_server()
     pol = _sqmd_policy()
-    add("sqmd.grade",
-        lambda s, y: pol.grade(s, y, backend="jnp"), st, labels)
-    add("sqmd.build_graph",
-        lambda s, q: pol.build_graph(s, q, backend="jnp"),
+    add("sqmd.grade", pol.grade, st, labels)
+    add("sqmd.build_graph", pol.build_graph,
         st, jnp.ones((N_CLIENTS,), jnp.float32))
     up_mask = np.zeros(N_CLIENTS, bool)
     up_mask[:2] = True
     add("sqmd.build_graph_delta",
-        lambda s, q: pol.build_graph_delta(s, q, up_mask, backend="jnp"),
+        lambda s, q: pol.build_graph_delta(s, q, up_mask),
         st, jnp.ones((N_CLIENTS,), jnp.float32))
-    graph = pol.build_graph(st, jnp.ones((N_CLIENTS,), jnp.float32),
-                            backend="jnp")
-    add("sqmd.emit_targets",
-        lambda s, g: pol.emit_targets(s, g, backend="jnp"), st, graph)
+    graph = pol.build_graph(st, jnp.ones((N_CLIENTS,), jnp.float32))
+    add("sqmd.emit_targets", pol.emit_targets, st, graph)
 
     # --- similarity paths -------------------------------------------------
-    add("divergence_matrix",
-        lambda lp: similarity.divergence_matrix(lp, backend="jnp"),
-        st.repo_logp)
+    add("divergence_matrix", similarity.divergence_matrix, st.repo_logp)
 
     # --- wire codecs (the sanctioned precision boundary) ------------------
     probe_logp = st.repo_logp

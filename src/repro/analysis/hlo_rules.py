@@ -132,14 +132,13 @@ def jit_cache_bucketing(ctx: AnalysisContext) -> Iterable[Violation]:
     n, r, c = 16, 6, fixtures.CLASSES
     logp = jax.nn.log_softmax(
         jax.random.normal(jax.random.key(21), (n, r, c)) * 2.0, axis=-1)
-    cache = similarity.divergence_matrix(logp, backend="jnp")
+    cache = similarity.divergence_matrix(logp)
 
     def replay() -> None:
         for u in _REPLAY_UPLOADS:
             mask = np.zeros(n, bool)
             mask[:u] = True
-            similarity.update_divergence_cache(cache, logp, mask,
-                                               backend="jnp")
+            similarity.update_divergence_cache(cache, logp, mask)
 
     yield from recompile_violations(
         "update_divergence_cache[jnp]", similarity._delta_update, replay,

@@ -129,9 +129,10 @@ def restore_federation(ckpt_dir: str, fed, step: Optional[int] = None,
     step = step if step is not None else latest_step(ckpt_dir)
     tree = restore_pytree(os.path.join(ckpt_dir, f"step_{step}.msgpack"))
     server = dict(tree["server"])
-    # the collaboration graph's (N,N) selection matrix was state before
-    # graphs went K-sparse; each fire rebuilds the graph, so drop it
+    # the graph's (N,N) selection matrix and similarity were state before
+    # graphs went K-sparse; each fire rebuilds the graph, so drop them
     server.pop("weights", None)
+    server.pop("sim", None)
     if "div_cache" not in server:
         # pre-delta-path checkpoint: rebuild the divergence cache from the
         # restored repository so incremental graph updates stay exact

@@ -8,8 +8,9 @@ driver hardwired together:
   * a client-availability ``Schedule`` (always-on, staged joins, dropout,
     stragglers, ...),
   * a ``FederationConfig`` (rounds, batch size, local steps, eval cadence,
-    kernel backend) — the kernel ``backend`` is threaded from this single
-    engine-owned setting into every server-side kernel call.
+    wire codecs, devices, selection). The server's kernels take their
+    path from ``$REPRO_KERNEL_BACKEND``, else the platform default
+    (``repro.kernels.backend``).
 
 Round callbacks observe eval-time metrics (``cb(engine, rnd, metrics)``)
 so benchmarks/dashboards hook in without subclassing.
@@ -143,7 +144,6 @@ class FederationConfig:
     batch_size: int = 32
     local_steps: int = 1
     eval_every: int = 10
-    backend: Optional[str] = None   # kernel backend for ALL server math
     delta_graph: bool = False       # incremental O(u·N) server graph
     # updates from the div_cache (policies that support it); off by
     # default — the full rebuild is the bit-exact oracle
@@ -310,7 +310,6 @@ class FederationEngine:
                                      mesh=self.mesh)
         self.bus = ServerBus(federation, self.policy,
                              trigger="every-upload",
-                             backend=self.config.backend,
                              delta=self.config.delta_graph,
                              mesh=self.mesh,
                              selection=self.config.selection)
@@ -470,7 +469,6 @@ class AsyncFederationEngine:
                                      mesh=self.mesh)
         self.bus = ServerBus(federation, self.policy,
                              trigger=as_trigger(trigger),
-                             backend=self.config.backend,
                              delta=self.config.delta_graph,
                              mesh=self.mesh,
                              selection=self.config.selection)

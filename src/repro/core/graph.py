@@ -7,10 +7,11 @@ SQMD's graph is carried K-sparse: ``neighbors`` (N,K) and ``edge_weights``
 (N,K), 1/count on each realized edge and 0 on unrealized slots, which is
 all Eq. 5 reads (``ops.neighbor_mean``). Graphs that are dense by nature
 (FedMD, D-Dist) carry the row-stochastic (N,N) ``weights`` instead;
-``selection_matrix`` gives either as the dense W off the hot path. SQMD's
-graph carries no (N,N) similarity either: its selection reads only the Q
-pool columns of the divergence, and ``similarity_of`` derives the whole C
-from ``divergence`` for readers off the hot path.
+``selection_matrix`` gives either as the dense W off the hot path. No
+graph carries the (N,N) similarity C: SQMD's selection computes Def. 4 on
+the Q pool columns of the divergence alone, and ``similarity_matrix``
+(``core/similarity.py``) gives the whole C of a ``divergence`` off the
+hot path.
 """
 from __future__ import annotations
 
@@ -29,8 +30,6 @@ class CollaborationGraph(NamedTuple):
     neighbors: jnp.ndarray       # (N, K) int32 neighbor indices
     weights: Optional[jnp.ndarray]  # (N, N) fp32 row-stochastic selection
     # matrix of a dense graph; None on K-sparse graphs
-    similarity: Optional[jnp.ndarray]  # (N, N) fp32 c_nm (the C matrix
-    # of Def. 5); None where it derives from ``divergence`` (SQMD)
     candidates: jnp.ndarray      # (N,) bool — the Q pool
     divergence: Optional[jnp.ndarray] = None  # (N,N) fp32 Eq.2 matrix this
     # graph was built from; policies that compute it surface it here so
@@ -39,12 +38,11 @@ class CollaborationGraph(NamedTuple):
     # each neighbor slot (0 on unrealized ones): set on K-sparse graphs
 
 
-def k_sparse(neighbors, edge_weights, similarity, candidates,
+def k_sparse(neighbors, edge_weights, candidates,
              divergence=None) -> CollaborationGraph:
     """A graph carried as its (N,K) neighbors and edge weights alone."""
     return CollaborationGraph(neighbors=neighbors, weights=None,
-                              similarity=similarity, candidates=candidates,
-                              divergence=divergence,
+                              candidates=candidates, divergence=divergence,
                               edge_weights=edge_weights)
 
 
@@ -67,34 +65,6 @@ def selection_matrix(g: CollaborationGraph) -> jnp.ndarray:
                           g.neighbors.shape[0])
 
 
-def similarity_of(g: CollaborationGraph) -> jnp.ndarray:
-    """The (N,N) Def. 4 similarity C of any graph, for readers off the hot
-    path (stats, tests, policies that keep it): ``similarity`` where the
-    graph carries it, else derived from its ``divergence``."""
-    if g.similarity is not None:
-        return g.similarity
-    from repro.core.similarity import similarity_matrix
-    return similarity_matrix(g.divergence)
-
-
-def _pool_topk(sub: jnp.ndarray, pool: jnp.ndarray, pool_valid: jnp.ndarray,
-               k: int):
-    """(N,B) pool-column scores -> the top-k selection: padded slots and
-    self-edges are unrealizable."""
-    rowidx = jnp.arange(sub.shape[0], dtype=pool.dtype)[:, None]
-    sub = jnp.where(pool_valid[None, :] & (pool[None, :] != rowidx),
-                    sub, -BIG)
-    return _topk_weights(sub, pool, k)
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _select_pool(similarity: jnp.ndarray, pool: jnp.ndarray,
-                 pool_valid: jnp.ndarray, k: int):
-    """Top-k over the candidate POOL columns only: O(N·Q·log k) instead of
-    O(N²·log k) — at 10k clients the pool is what bounds the cost."""
-    return _pool_topk(similarity[:, pool], pool, pool_valid, k)
-
-
 def _pool_columns(div: jnp.ndarray, pool: jnp.ndarray) -> jnp.ndarray:
     """The (N,B) pool columns ``div[:, pool]``, as ``div`` times the (B,N)
     one-hot of ``pool`` at HIGHEST precision. The matmul reads ``div`` in
@@ -113,13 +83,18 @@ def _pool_columns(div: jnp.ndarray, pool: jnp.ndarray) -> jnp.ndarray:
 def _select_pool_div(div: jnp.ndarray, pool: jnp.ndarray,
                      pool_valid: jnp.ndarray, k: int):
     """Def. 4 + the pool top-k from the (N,N) divergence matrix, on its B
-    pool columns alone: no (N,N) value is written, copied or transposed.
-    The scores are ``similarity_matrix(div)[:, pool]`` bit for bit (the
-    same elementwise ops; the pool mask covers the zeroed diagonal), in
-    pool order, so ``top_k`` breaks ties as over the whole matrix."""
+    pool columns alone: O(N·B·log k) instead of O(N²·log k), and no
+    (N,N) value is written, copied or transposed. The scores are
+    ``similarity_matrix(div)[:, pool]`` bit for bit (the same elementwise
+    ops; the pool mask covers the zeroed diagonal), in pool order, so
+    ``top_k`` breaks ties as over the whole matrix."""
     from repro.core.similarity import EPS
-    cols = _pool_columns(div, pool)
-    return _pool_topk(1.0 / jnp.maximum(cols, EPS), pool, pool_valid, k)
+    sub = 1.0 / jnp.maximum(_pool_columns(div, pool), EPS)
+    # padded slots and self-edges are unrealizable
+    rowidx = jnp.arange(sub.shape[0], dtype=pool.dtype)[:, None]
+    sub = jnp.where(pool_valid[None, :] & (pool[None, :] != rowidx),
+                    sub, -BIG)
+    return _topk_weights(sub, pool, k)
 
 
 def _topk_weights(sub: jnp.ndarray, pool: jnp.ndarray, k: int):
@@ -162,60 +137,40 @@ def _select_dense(similarity: jnp.ndarray, candidates: jnp.ndarray, k: int):
     return _topk_weights(scores, jnp.arange(n, dtype=jnp.int32), k)
 
 
-def select_neighbors(similarity: jnp.ndarray, candidates: jnp.ndarray,
+def select_neighbors(divergence: jnp.ndarray, candidates,
                      k: int) -> CollaborationGraph:
-    """Top-K most-similar candidates per client (directed edges n -> m).
+    """Top-K most-similar candidates per client (directed edges n -> m),
+    by the Def. 4 similarity of the (N,N) Eq. 2 ``divergence`` matrix.
 
     Clients outside Q still get K neighbors (paper: 'any client, regardless
     of its quality, is assigned K neighbors'). A client never selects
     itself. If fewer than K candidates exist, the row's edge weights are
     renormalized over the realized edges (unrealized slots weigh 0).
 
-    Only the Q candidate columns are ever eligible, so the top-k runs over
-    the (N, Q) pool sub-matrix, not all N² scores. The pool index set is
-    padded to a power-of-two bucket (padded slots scored -BIG) so the
-    jitted kernel compiles once per bucket, not once per pool size. The
-    pool extraction needs concrete values; under an outer jit trace the
-    dense O(N²) path keeps the function traceable."""
-    n = similarity.shape[0]
-    k = min(k, n - 1)
-    if isinstance(candidates, jax.core.Tracer):
-        return k_sparse(*_select_dense(similarity, candidates, k),
-                        similarity, candidates)
-    bucket = _pool_bucket(candidates, k)
-    if bucket is None:
-        return k_sparse(*_empty(n, k), similarity, candidates)
-    pool, valid = bucket
-    with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
-        nbrs, vals = _select_pool(similarity, jnp.asarray(pool),
-                                  jnp.asarray(valid), k)
-    return k_sparse(nbrs, vals, similarity, candidates)
-
-
-def select_neighbors_from_div(divergence: jnp.ndarray, candidates,
-                              k: int) -> CollaborationGraph:
-    """``select_neighbors`` on the Def. 4 similarity of the (N,N)
-    divergence matrix, computed on the pool columns alone — the hot path
-    for SQMD server rounds at large N. ``candidates`` may be a host mask,
-    read before the divergence was queued (a device mask is read here).
-    The graph carries ``divergence`` and no ``similarity``
-    (``similarity_of`` derives it)."""
+    Only the Q candidate columns are ever eligible, so Def. 4 and the
+    top-k run over the (N, Q) pool columns, not all N² scores. The pool
+    index set is padded to a power-of-two bucket (padded slots scored
+    -BIG) so the jitted selection compiles once per bucket, not once per
+    pool size. ``candidates`` may be a host mask, read before the
+    divergence was queued (a device mask is read here). The pool
+    extraction needs concrete values; under an outer jit trace the dense
+    O(N²) path keeps the function traceable. The graph carries
+    ``divergence``."""
     n = divergence.shape[0]
     k = min(k, n - 1)
     if isinstance(candidates, jax.core.Tracer):
         from repro.core.similarity import similarity_matrix
         sim = similarity_matrix(divergence)
-        return k_sparse(*_select_dense(sim, candidates, k), None,
-                        candidates, divergence)
+        return k_sparse(*_select_dense(sim, candidates, k), candidates,
+                        divergence)
     bucket = _pool_bucket(candidates, k)
     if bucket is None:
-        return k_sparse(*_empty(n, k), None, candidates, divergence)
+        return k_sparse(*_empty(n, k), candidates, divergence)
     pool, valid = bucket
-    with span("repro.select", pool=int(valid.sum()), bucket=valid.size,
-              form="onehot"):
+    with span("repro.select", pool=int(valid.sum()), bucket=valid.size):
         nbrs, vals = _select_pool_div(divergence, jnp.asarray(pool),
                                       jnp.asarray(valid), k)
-    return k_sparse(nbrs, vals, None, candidates, divergence)
+    return k_sparse(nbrs, vals, candidates, divergence)
 
 
 def fedmd_graph(active: jnp.ndarray) -> CollaborationGraph:
@@ -226,8 +181,7 @@ def fedmd_graph(active: jnp.ndarray) -> CollaborationGraph:
     w = jnp.tile(a[None, :], (n, 1))
     w = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1.0)
     nbrs = jnp.tile(jnp.arange(n, dtype=jnp.int32)[None, :], (n, 1))
-    return CollaborationGraph(neighbors=nbrs, weights=w,
-                              similarity=w, candidates=active)
+    return CollaborationGraph(neighbors=nbrs, weights=w, candidates=active)
 
 
 def ddist_graph(key, n: int, k: int, active: Optional[jnp.ndarray] = None
@@ -259,9 +213,7 @@ def ddist_graph(key, n: int, k: int, active: Optional[jnp.ndarray] = None
     rows = jnp.repeat(jnp.arange(n), k)
     w = w.at[rows, nbrs.reshape(-1)].add(valid.reshape(-1).astype(jnp.float32))
     w = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1.0)
-    sim = jnp.zeros((n, n), jnp.float32)
-    return CollaborationGraph(neighbors=nbrs, weights=w, similarity=sim,
-                              candidates=active)
+    return CollaborationGraph(neighbors=nbrs, weights=w, candidates=active)
 
 
 def graph_stats(g: CollaborationGraph) -> dict:

@@ -12,7 +12,7 @@ three hooks and never inspect the protocol name. New strategies drop in as
 
     @register_policy("my-policy")
     class MyPolicy(ServerPolicy):
-        def build_graph(self, state, quality, *, backend=None): ...
+        def build_graph(self, state, quality): ...
 
 and become constructible from ``Protocol("my-policy")``, the engine, and
 the launch CLI without touching the core loop.
@@ -97,14 +97,13 @@ class ServerPolicy(abc.ABC):
     optionally ``grade`` / ``emit_targets`` / ``setup``.
 
     Policies are lightweight config holders — all array math flows through
-    the three hooks so the engine can thread one kernel ``backend`` setting
-    through every call.
+    the three hooks. Their kernels dispatch by ``ops``' own process-level
+    setting (``$REPRO_KERNEL_BACKEND``, else the platform default), so no
+    hook takes a backend argument.
     """
 
     name: str = "?"                 # bound by @register_policy
     uses_reference: bool = True     # False => no messengers, no server round
-    computes_similarity: bool = False  # True => graph.similarity, where
-    # the graph carries one, -> state.sim
     # Client device mesh (repro.sharding.make_client_mesh), attached by the
     # ServerBus when the engine runs device-sharded: policies whose graph
     # build scales with the population (SQMD's O(N²·R·C) divergence) shard
@@ -148,28 +147,23 @@ class ServerPolicy(abc.ABC):
         raise ValueError(f"policy {self.name!r} takes no static graph")
 
     # -- the three stages of a server round --------------------------------
-    def grade(self, state, ref_labels: jnp.ndarray, *,
-              backend: Optional[str] = None) -> jnp.ndarray:
+    def grade(self, state, ref_labels: jnp.ndarray) -> jnp.ndarray:
         """(N,) Eq.1 quality grades of the repository messengers."""
-        return quality_mod.quality_scores(state.repo_logp, ref_labels,
-                                          backend=backend)
+        return quality_mod.quality_scores(state.repo_logp, ref_labels)
 
     @abc.abstractmethod
-    def build_graph(self, state, quality: jnp.ndarray, *,
-                    backend: Optional[str] = None):
+    def build_graph(self, state, quality: jnp.ndarray):
         """CollaborationGraph for this round (the policy's whole point)."""
 
-    def build_graph_delta(self, state, quality: jnp.ndarray, uploaded, *,
-                          backend: Optional[str] = None):
+    def build_graph_delta(self, state, quality: jnp.ndarray, uploaded):
         """Incremental variant: ``uploaded`` is the (N,) bool mask of every
         repository row that changed since the last policy round. Policies
         whose round cost scales with the population (sqmd's O(N²·R·C)
         divergence matrix) override this to pay only O(u·N); the default
         ignores the mask and rebuilds — always correct, never required."""
-        return self.build_graph(state, quality, backend=backend)
+        return self.build_graph(state, quality)
 
-    def emit_targets(self, state, graph, *,
-                     backend: Optional[str] = None) -> jnp.ndarray:
+    def emit_targets(self, state, graph) -> jnp.ndarray:
         """(N,R,C) fp32 probability targets: the K^n neighbor mean.
 
         The runtime wire-codes this output with the downlink codec
@@ -180,9 +174,8 @@ class ServerPolicy(abc.ABC):
         probs = jnp.exp(state.repo_logp)
         if graph.edge_weights is not None:
             return ops.neighbor_mean(graph.neighbors, graph.edge_weights,
-                                     probs, backend=backend)
-        return ops.neighbor_mean_dense(graph.weights, probs,
-                                       backend=backend)
+                                     probs)
+        return ops.neighbor_mean_dense(graph.weights, probs)
 
     def receivers(self, state, graph) -> jnp.ndarray:
         """(N,) bool — which clients a K^n downlink payload is sent to
@@ -194,16 +187,12 @@ class ServerPolicy(abc.ABC):
 
     # -- state fold-in -----------------------------------------------------
     def update_state(self, state, quality: jnp.ndarray, graph):
-        """Fold this round's results into the ServerState. Policies that do
-        not compute similarity, and graphs that carry none (SQMD's, whose
-        similarity derives from its divergence: ``graph.similarity_of``),
-        keep the previous ``sim`` matrix; a graph carrying the divergence
-        it was built from refreshes ``div_cache`` (both the full rebuild
-        and the delta scatter produce it, so the cache always matches the
-        current repository)."""
-        sim = (graph.similarity if self.computes_similarity
-               and graph.similarity is not None else state.sim)
+        """Fold this round's results into the ServerState: the grades, the
+        round counter, and — where the graph carries the divergence it
+        was built from — ``div_cache`` (both the full rebuild and the
+        delta scatter produce it, so the cache always matches the current
+        repository)."""
         div = (graph.divergence if graph.divergence is not None
                else state.div_cache)
-        return state._replace(quality=quality, sim=sim, div_cache=div,
+        return state._replace(quality=quality, div_cache=div,
                               round=state.round + 1)

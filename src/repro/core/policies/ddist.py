@@ -28,8 +28,7 @@ class DDistPolicy(ServerPolicy):
     def attach_static_weights(self, weights: jnp.ndarray) -> None:
         self.static_weights = weights
 
-    def build_graph(self, state, quality: jnp.ndarray, *,
-                    backend: Optional[str] = None):
+    def build_graph(self, state, quality: jnp.ndarray):
         if self.static_weights is None:
             raise ValueError("ddist needs its static graph: call "
                              "policy.setup(key, n) or pass static_weights")
@@ -38,7 +37,7 @@ class DDistPolicy(ServerPolicy):
         n = w.shape[0]
         return graph_mod.CollaborationGraph(
             neighbors=jnp.zeros((n, 0), jnp.int32),  # static; not re-derived
-            weights=w, similarity=state.sim, candidates=state.active)
+            weights=w, candidates=state.active)
 
     def receivers(self, state, graph) -> jnp.ndarray:
         """A client whose static edges all point at never-joined peers

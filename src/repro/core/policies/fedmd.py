@@ -2,8 +2,6 @@
 average messenger — the Q = K = N degenerate case of SQMD."""
 from __future__ import annotations
 
-from typing import Optional
-
 import jax.numpy as jnp
 
 from repro.core import graph as graph_mod
@@ -14,8 +12,7 @@ from repro.core.policies.base import ServerPolicy, register_policy
 class FedMDPolicy(ServerPolicy):
     """Complete graph over active clients, uniform weights."""
 
-    def build_graph(self, state, quality: jnp.ndarray, *,
-                    backend: Optional[str] = None):
+    def build_graph(self, state, quality: jnp.ndarray):
         # already O(N) per round: the base build_graph_delta fallback
         # (ignore the uploaded mask, rebuild) IS FedMD's delta path
         return graph_mod.fedmd_graph(state.active)

@@ -1,8 +1,6 @@
 """I-SGD baseline: isolated local SGD — no collaboration, zero targets."""
 from __future__ import annotations
 
-from typing import Optional
-
 import jax.numpy as jnp
 
 from repro.core import graph as graph_mod
@@ -17,12 +15,11 @@ class ISGDPolicy(ServerPolicy):
 
     uses_reference = False
 
-    def build_graph(self, state, quality: jnp.ndarray, *,
-                    backend: Optional[str] = None):
+    def build_graph(self, state, quality: jnp.ndarray):
         n = state.active.shape[0]
         return graph_mod.k_sparse(jnp.zeros((n, 0), jnp.int32),
                                   jnp.zeros((n, 0), jnp.float32),
-                                  state.sim, state.active)
+                                  state.active)
 
     def receivers(self, state, graph) -> jnp.ndarray:
         """No collaboration, no downlink: zero wire bytes charged."""

@@ -8,8 +8,6 @@ K neighbors.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -18,13 +16,13 @@ from repro.kernels import ops
 BIG = jnp.float32(1e30)
 
 
-def quality_scores(messengers_logp: jnp.ndarray, ref_labels: jnp.ndarray,
-                   backend: Optional[str] = None) -> jnp.ndarray:
+def quality_scores(messengers_logp: jnp.ndarray,
+                   ref_labels: jnp.ndarray) -> jnp.ndarray:
     """g (N,) — Eq.1 summed CE of each messenger vs the server's labels.
 
     Messengers are log-probs; soft_ce works on raw scores and log-probs
     alike (logsumexp(logp) = 0 exactly, so CE = -logp[y])."""
-    return ops.soft_ce(messengers_logp, ref_labels, backend=backend)
+    return ops.soft_ce(messengers_logp, ref_labels)
 
 
 def candidate_mask(quality: jnp.ndarray, active: jnp.ndarray,

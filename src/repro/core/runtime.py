@@ -430,14 +430,13 @@ class ServerBus:
 
     def __init__(self, federation, policy, trigger: Union[None, str,
                                                           Trigger] = None,
-                 backend: Optional[str] = None, delta: bool = False,
+                 delta: bool = False,
                  uplink: Union[None, str, wire.Codec] = None,
                  downlink: Union[None, str, wire.Codec] = None,
                  mesh=None, selection: Optional[str] = None):
         self.fed = federation
         self.policy = policy
         self.trigger = as_trigger(trigger)
-        self.backend = backend
         self.delta = bool(delta)
         self.mesh = mesh
         if mesh is not None:
@@ -530,8 +529,7 @@ class ServerBus:
                   rows=int(self.fresh_since_fire.sum())):
             uploaded = self.fresh_since_fire.copy() if self.delta else None
             fed.server, targets, self.last_graph = policy_round(
-                fed.server, self.policy, fed.ref_y, backend=self.backend,
-                uploaded=uploaded)
+                fed.server, self.policy, fed.ref_y, uploaded=uploaded)
             with span("repro.downlink"):
                 payload = self.downlink.encode(targets, domain="prob")
                 decoded = wire.decode(payload)

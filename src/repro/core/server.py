@@ -4,9 +4,6 @@ State (a pytree — jit-able end to end):
   repo_logp (N,R,C)  messenger repository S (stale rows allowed: asynchrony)
   active    (N,)     participation mask (clients that have ever joined)
   quality   (N,)     latest Eq.1 grades
-  sim       (N,N)    latest similarity matrix C (Def. 5) of a policy whose
-                     graph carries one; SQMD's derives from div_cache
-                     (``graph.similarity_of``) and leaves it as it was
   round     ()       round counter
   div_cache (N,N)    cached Eq.2 divergence matrix of the CURRENT
                      repository — the delta path scatters u×N / N×u strips
@@ -17,7 +14,8 @@ repository, re-grades, rebuilds the dynamic graph per the protocol, and
 returns the per-client distillation targets (the K^n payloads). The
 round's collaboration graph is not state: it is rebuilt every round and
 kept by its caller (``ServerBus.last_graph``); SQMD's is K-sparse, so no
-(N,N) selection matrix exists on the server.
+(N,N) selection matrix exists on the server, and no (N,N) similarity:
+``div_cache`` is the only (N,N) array the server holds.
 """
 from __future__ import annotations
 
@@ -36,7 +34,6 @@ class ServerState(NamedTuple):
     repo_logp: jnp.ndarray
     active: jnp.ndarray
     quality: jnp.ndarray
-    sim: jnp.ndarray
     round: jnp.ndarray
     div_cache: jnp.ndarray
 
@@ -50,7 +47,6 @@ def init_server(n_clients: int, ref_size: int, n_classes: int) -> ServerState:
         repo_logp=uniform,
         active=jnp.zeros((n_clients,), bool),
         quality=jnp.full((n_clients,), quality_mod.BIG),
-        sim=jnp.zeros((n_clients, n_clients), jnp.float32),
         round=jnp.zeros((), jnp.int32),
         # the all-uniform repository has KL(p||p) = 0 everywhere, so the
         # zero matrix IS the exact divergence of the initial repository
@@ -125,7 +121,6 @@ def staleness_summary(last_upload_t: np.ndarray, active: np.ndarray,
 
 
 def policy_round(state: ServerState, policy, ref_labels: jnp.ndarray,
-                 backend: Optional[str] = None,
                  uploaded: Optional[np.ndarray] = None):
     """Lines 7–10, policy-agnostic: grade -> build graph -> emit targets.
 
@@ -139,26 +134,24 @@ def policy_round(state: ServerState, policy, ref_labels: jnp.ndarray,
     instead of the O(N²) full rebuild. ``uploaded=None`` (the default, and
     the legacy ``server_round`` contract) always rebuilds from scratch."""
     with span("repro.grade"):
-        g = policy.grade(state, ref_labels, backend=backend)
+        g = policy.grade(state, ref_labels)
     if uploaded is None:
         with span("repro.build_graph", path="full"):
-            graph = policy.build_graph(state, g, backend=backend)
+            graph = policy.build_graph(state, g)
     else:
         path = "ivf" if policy.selection == "ivf" else "delta"
         with span("repro.build_graph", path=path):
-            graph = policy.build_graph_delta(state, g, uploaded,
-                                             backend=backend)
+            graph = policy.build_graph_delta(state, g, uploaded)
     path = "dense" if graph.edge_weights is None else "k-sparse"
     with span("repro.emit_targets", path=path):
-        targets = policy.emit_targets(state, graph, backend=backend)
+        targets = policy.emit_targets(state, graph)
     return policy.update_state(state, g, graph), targets, graph
 
 
 def server_round(state: ServerState, protocol: Union[Protocol, "ServerPolicy",
                                                      str],
                  ref_labels: jnp.ndarray,
-                 static_weights: Optional[jnp.ndarray] = None,
-                 backend: Optional[str] = None
+                 static_weights: Optional[jnp.ndarray] = None
                  ) -> Tuple[ServerState, jnp.ndarray]:
     """Lines 7–10: one server round under any registered policy.
 
@@ -168,5 +161,5 @@ def server_round(state: ServerState, protocol: Union[Protocol, "ServerPolicy",
     pre-``setup`` DDistPolicy instance)."""
     from repro.core.policies import as_policy
     pol = as_policy(protocol, static_weights=static_weights)
-    new, targets, _ = policy_round(state, pol, ref_labels, backend=backend)
+    new, targets, _ = policy_round(state, pol, ref_labels)
     return new, targets

@@ -34,7 +34,6 @@ EPS = 1e-8
 
 
 def divergence_matrix(messengers_logp: jnp.ndarray,
-                      backend: Optional[str] = None,
                       mesh=None) -> jnp.ndarray:
     """(N,R,C) log-messengers -> (N,N) fp32, D[n,m] = mean_j KL(n || m).
 
@@ -47,8 +46,8 @@ def divergence_matrix(messengers_logp: jnp.ndarray,
     divide the mesh are padded with a repeated last row and sliced
     back."""
     if mesh is not None and _mesh_devices(mesh) > 1:
-        return _divergence_sharded(messengers_logp, mesh, backend)
-    return ops.pairwise_kl(messengers_logp, backend=backend)
+        return _divergence_sharded(messengers_logp, mesh)
+    return ops.pairwise_kl(messengers_logp)
 
 
 def _mesh_devices(mesh) -> int:
@@ -67,21 +66,21 @@ _PRETRANSPOSE_ROWS = 1024
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_strip_fn(mesh, backend: Optional[str]):
-    """shard_map'd row-strip rebuild, cached per (mesh, backend) so each
-    repository shape compiles once. Both layouts keep the replicated
-    operand un-reduced per shard — zero collectives (the PR 6 HLO pin)."""
+def _sharded_strip_fn(mesh, path: str):
+    """shard_map'd row-strip rebuild, cached per (mesh, resolved kernel
+    ``path``) so each repository shape compiles once. Both layouts keep
+    the replicated operand un-reduced per shard — zero collectives (the
+    PR 6 HLO pin)."""
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding import CLIENT_AXIS
 
     n_dev = int(mesh.shape.get(CLIENT_AXIS, 1))
-    resolved = backend or ops.default_backend()
 
     def strips(block, full):
         # block: this device's rows; full: the whole repository
         # (replicated) — the PR 3 rectangular strip kernel per shard
-        return ops.pairwise_kl_pair(block, full, backend=backend)
+        return ops.pairwise_kl_pair(block, full, backend=path)
 
     def strips_pre_t(la_blk, lt_full):
         # la_blk (rows, R*C) this device's flattened rows; lt_full
@@ -93,7 +92,7 @@ def _sharded_strip_fn(mesh, backend: Optional[str]):
 
     def rebuild(lp_padded, lp_full):
         rows = lp_padded.shape[0] // n_dev
-        if resolved != "jnp" or rows >= _PRETRANSPOSE_ROWS:
+        if path != "jnp" or rows >= _PRETRANSPOSE_ROWS:
             # a pallas_call's output carries no varying-manual-axes type,
             # so the kernel branch cannot be vma-checked
             return jax.shard_map(
@@ -113,8 +112,7 @@ def _sharded_strip_fn(mesh, backend: Optional[str]):
     return jax.jit(rebuild)
 
 
-def _divergence_sharded(messengers_logp: jnp.ndarray, mesh,
-                        backend: Optional[str]) -> jnp.ndarray:
+def _divergence_sharded(messengers_logp: jnp.ndarray, mesh) -> jnp.ndarray:
     n = messengers_logp.shape[0]
     n_dev = _mesh_devices(mesh)
     pad = (-n) % n_dev
@@ -122,7 +120,7 @@ def _divergence_sharded(messengers_logp: jnp.ndarray, mesh,
     if pad:
         lp = jnp.concatenate(
             [lp, jnp.broadcast_to(lp[-1:], (pad,) + lp.shape[1:])])
-    d = _sharded_strip_fn(mesh, backend)(lp, messengers_logp)
+    d = _sharded_strip_fn(mesh, ops.default_backend())(lp, messengers_logp)
     return d[:n] if pad else d
 
 
@@ -161,8 +159,7 @@ def _delta_update(cache: jnp.ndarray, lp: jnp.ndarray, rows: jnp.ndarray,
 
 
 def update_divergence_cache(cache: jnp.ndarray, messengers_logp: jnp.ndarray,
-                            uploaded, backend: Optional[str] = None
-                            ) -> jnp.ndarray:
+                            uploaded) -> jnp.ndarray:
     """Scatter the divergence strips of freshly-uploaded rows into the
     cached (N,N) matrix.
 
@@ -181,20 +178,17 @@ def update_divergence_cache(cache: jnp.ndarray, messengers_logp: jnp.ndarray,
     if rows.size == 0:
         return cache
     if rows.size >= messengers_logp.shape[0]:
-        return divergence_matrix(messengers_logp, backend=backend)
+        return divergence_matrix(messengers_logp)
     bucket = _bucket_rows(rows)
     with span("repro.div_update", rows=rows.size, bucket=bucket.size):
         rows = jnp.asarray(bucket)
-        backend = backend or ops.default_backend()
-        if backend == "jnp":
+        if ops.default_backend() == "jnp":
             n, r, c = messengers_logp.shape
             lp = messengers_logp.astype(jnp.float32).reshape(n, r * c)
             return _delta_update(cache, lp, rows, r)
         fresh = messengers_logp[rows]
-        row_strip = ops.pairwise_kl_pair(fresh, messengers_logp,
-                                         backend=backend)       # (u, N)
-        col_strip = ops.pairwise_kl_pair(messengers_logp, fresh,
-                                         backend=backend)       # (N, u)
+        row_strip = ops.pairwise_kl_pair(fresh, messengers_logp)  # (u, N)
+        col_strip = ops.pairwise_kl_pair(messengers_logp, fresh)  # (N, u)
         return _scatter_strips(cache, rows, row_strip, col_strip)
 
 
@@ -275,8 +269,7 @@ class NeighborIndex:
     def __init__(self, capacity: int, ref_size: int, n_classes: int,
                  k: int, n_probe: Optional[int] = None,
                  n_centroids: Optional[int] = None,
-                 list_margin: int = 2, backend: Optional[str] = None,
-                 seed: int = 0):
+                 list_margin: int = 2, seed: int = 0):
         if capacity < 1 or ref_size < 1 or n_classes < 2:
             raise ValueError(f"bad index dims: capacity={capacity}, "
                              f"ref_size={ref_size}, n_classes={n_classes}")
@@ -290,7 +283,6 @@ class NeighborIndex:
         self.list_len = list_margin * k
         self.n_probe = n_probe          # None -> derived from ncent at fit
         self._n_centroids = n_centroids  # None -> isqrt(|active|) at fit
-        self.backend = backend
         self.seed = seed
         n, L = capacity, self.list_len
         self._codes = np.zeros((n, ref_size, n_classes), np.uint8)
@@ -378,7 +370,7 @@ class NeighborIndex:
         assignment/probing metric — same metric as the lists hold)."""
         return host_read(ops.pairwise_kl_pair(
             jnp.asarray(self._recon_logp(rows)),
-            jnp.asarray(self._centroids), backend=self.backend),
+            jnp.asarray(self._centroids)),
             "ivf.centroid_div")
 
     def _effective_probe(self) -> int:
@@ -397,8 +389,8 @@ class NeighborIndex:
             jnp.asarray(self._codes[rows_a]),
             jnp.asarray(self._scale[rows_a]), jnp.asarray(zp_a),
             jnp.asarray(self._codes[rows_b]),
-            jnp.asarray(self._scale[rows_b]), jnp.asarray(zp_b),
-            backend=self.backend), "ivf.strip")
+            jnp.asarray(self._scale[rows_b]), jnp.asarray(zp_b)),
+            "ivf.strip")
 
     def _search(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """rows (u,) -> (candidates (m,), fwd strip (u,m)).

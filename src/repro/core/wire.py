@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
-from typing import Dict, Optional, Sequence, Tuple, Type, Union
+from typing import Dict, Sequence, Tuple, Type, Union
 
 import jax
 import jax.numpy as jnp
@@ -298,8 +298,7 @@ class Int8(Codec):
             return jax.nn.log_softmax(deq, axis=-1)
         return _renorm_probs(deq)
 
-    def pairwise_kl(self, payload: Payload,
-                    backend: Optional[str] = None) -> jnp.ndarray:
+    def pairwise_kl(self, payload: Payload) -> jnp.ndarray:
         """Eq.2 divergence matrix straight off the wire form: the fused
         dequant->KL kernel never materializes the dense fp32 (N, R, C)
         decode (``kernels/dequant_kl.py``)."""
@@ -311,7 +310,7 @@ class Int8(Codec):
                              f"got shape {payload.shape}")
         return ops.int8_pairwise_kl(payload.arrays["q"],
                                     payload.arrays["scale"],
-                                    payload.arrays["zp"], backend=backend)
+                                    payload.arrays["zp"])
 
 
 @register_codec("topk")

@@ -11,9 +11,11 @@ independently (and therefore inconsistently — see PR 6's lint rule
     ``pallas_call`` wrapper runs compiled or under the Pallas interpreter
     when the caller passes ``interpret=None``.
 
-Both honor the ``REPRO_KERNEL_BACKEND`` environment variable so a whole
-process (CI lane, benchmark, federate run) can be pinned to one path
-without threading a flag through every call site:
+Both honor the ``REPRO_KERNEL_BACKEND`` environment variable, the one
+process-level setting, so a whole process (CI lane, benchmark, federate
+run) is pinned to one path; nothing above ``ops`` takes a backend
+argument. It is read at every call, so a change takes effect at the next
+kernel call:
 
   * ``REPRO_KERNEL_BACKEND=pallas``    -> backend "pallas", interpret False
   * ``REPRO_KERNEL_BACKEND=interpret`` -> backend "interpret", interpret True
@@ -30,6 +32,7 @@ below them to avoid an import cycle.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional
 
@@ -37,8 +40,6 @@ import jax
 
 VALID_BACKENDS = ("pallas", "interpret", "jnp")
 ENV_VAR = "REPRO_KERNEL_BACKEND"
-
-_DEFAULT_BACKEND: Optional[str] = None
 
 
 def _env_backend() -> Optional[str]:
@@ -52,27 +53,18 @@ def _env_backend() -> Optional[str]:
     return env
 
 
+@functools.cache
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
 def default_backend() -> str:
-    """Dispatch path used when ``backend=None``: the ``set_default_backend``
-    override, else ``$REPRO_KERNEL_BACKEND``, else the platform default."""
-    global _DEFAULT_BACKEND
-    if _DEFAULT_BACKEND is None:
-        env = _env_backend()
-        if env is not None:
-            _DEFAULT_BACKEND = env
-        else:
-            platform = jax.devices()[0].platform
-            _DEFAULT_BACKEND = "pallas" if platform == "tpu" else "jnp"
-    return _DEFAULT_BACKEND
-
-
-def set_default_backend(name: str) -> None:
-    global _DEFAULT_BACKEND
-    if name not in VALID_BACKENDS:
-        # ValueError (not assert) so the guard survives python -O
-        raise ValueError(f"unknown backend {name!r}; expected 'pallas', "
-                         f"'interpret', or 'jnp'")
-    _DEFAULT_BACKEND = name
+    """Dispatch path used when ``backend=None``: ``$REPRO_KERNEL_BACKEND``
+    where it is set, else the platform default."""
+    env = _env_backend()
+    if env is not None:
+        return env
+    return "pallas" if _on_tpu() else "jnp"
 
 
 def default_interpret() -> bool:
@@ -82,7 +74,7 @@ def default_interpret() -> bool:
     env = _env_backend()
     if env is not None:
         return env != "pallas"
-    return jax.devices()[0].platform != "tpu"
+    return not _on_tpu()
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

@@ -5,7 +5,8 @@
   * "interpret"  — same kernel body, Python interpreter (CPU validation),
   * "jnp"        — the pure-jnp oracle from ref.py.
 
-The default (``backend=None``) is "pallas" on a TPU and "jnp" elsewhere;
+The default (``backend=None``) is ``$REPRO_KERNEL_BACKEND`` where it is
+set, else "pallas" on a TPU and "jnp" elsewhere (``backend.py``);
 the CPU paths exist for tests, and on the chip nothing gives way to them.
 The numerical contract is identical, up to the TPU's bf16 matmul passes.
 """
@@ -27,7 +28,6 @@ from repro.kernels.backend import (  # noqa: F401  (public re-exports)
     default_backend,
     default_interpret,
     resolve_interpret,
-    set_default_backend,
 )
 
 

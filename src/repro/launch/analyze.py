@@ -12,7 +12,9 @@ Usage (from the repo root):
 Exits 1 if any rule reports a non-baselined violation OR crashes — a
 broken auditor must fail the gate, not silently pass it. The 8-device
 host platform is forced before jax imports so the sharded HLO audits run
-on plain CPU CI.
+on plain CPU CI; the host platform itself is forced too (unless
+``JAX_PLATFORMS`` names one), so every audited server entry traces the
+``jnp`` kernel path, the host's default.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ _DEVICE_FLAG = "--xla_force_host_platform_device_count=8"
 
 def _force_host_devices() -> None:
     """Must run BEFORE jax is imported anywhere in this process."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = f"{flags} {_DEVICE_FLAG}".strip()

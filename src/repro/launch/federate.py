@@ -25,6 +25,13 @@ A profiler trace of the run, with the program's repro.* spans (README,
 
   PYTHONPATH=src python -m repro.launch.federate --rounds 5 --profile runs/prof
 
+The server's kernels take their path ("pallas", "interpret" or "jnp")
+from the process-level REPRO_KERNEL_BACKEND, else the platform default
+("pallas" on a TPU, "jnp" elsewhere):
+
+  REPRO_KERNEL_BACKEND=interpret PYTHONPATH=src \
+      python -m repro.launch.federate --rounds 2
+
 Multi-device client sharding (cohort steps + server divergence rows shard
 over a 1-D client mesh; fake host devices for CPU testing):
 
@@ -107,7 +114,6 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--local-steps", type=int, default=1)
     ap.add_argument("--eval-every", type=int, default=5)
-    ap.add_argument("--backend", choices=("pallas", "interpret", "jnp"))
     ap.add_argument("--devices", type=int,
                     help="shard the client axis over this many devices "
                          "(cohort steps + server divergence rows); on CPU "
@@ -208,7 +214,6 @@ def main() -> None:
     config = FederationConfig(rounds=args.rounds, batch_size=args.batch,
                               local_steps=args.local_steps,
                               eval_every=args.eval_every,
-                              backend=args.backend,
                               delta_graph=args.delta,
                               uplink=args.uplink, downlink=args.downlink,
                               devices=args.devices,

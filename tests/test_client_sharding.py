@@ -155,13 +155,10 @@ def test_devices_one_matches_legacy_path(setup_small):
 
 # --- mesh parity (CI sharded lane: 8 fake host devices) -------------------
 
-# The n_dev=1 oracle trajectory, captured (and pinned) in
+# The n_dev=1 oracle trajectory, pinned in
 # tests/test_runtime.py::test_sync_parity_pinned on exactly the
 # pad_like(30, 30, 24) fixture below. The n_dev=8 run must reproduce it.
-PINNED_MEAN_ACC = [0.7023809626698494, 0.7500000095793179,
-                   0.7976190575531551]
-PINNED_VAL_ACC = [0.7619047707745007, 0.8095238187483379,
-                  0.8452381044626236]
+from test_runtime import PINNED_MEAN_ACC, PINNED_VAL_ACC  # noqa: E402
 
 
 @needs_mesh
@@ -220,7 +217,7 @@ def test_sharded_divergence_matches_oracle(n):
     logp = jax.nn.log_softmax(
         jax.random.normal(jax.random.key(0), (n, 20, 5)) * 2, -1)
     oracle = np.asarray(ops.pairwise_kl(logp, backend="jnp"))
-    d = np.asarray(divergence_matrix(logp, backend="jnp", mesh=mesh))
+    d = np.asarray(divergence_matrix(logp, mesh=mesh))
     assert d.shape == (n, n)
     np.testing.assert_allclose(d, oracle, rtol=0, atol=1e-6)
 
@@ -240,9 +237,9 @@ def test_sharded_policy_graph_matches_oracle():
     pol1 = as_policy(sqmd(q=8, k=4))
     pol8 = as_policy(sqmd(q=8, k=4))
     pol8.mesh = make_client_mesh(8)
-    quality = pol1.grade(state, labels, backend="jnp")
-    g1 = pol1.build_graph(state, quality, backend="jnp")
-    g8 = pol8.build_graph(state, quality, backend="jnp")
+    quality = pol1.grade(state, labels)
+    g1 = pol1.build_graph(state, quality)
+    g8 = pol8.build_graph(state, quality)
     np.testing.assert_array_equal(np.asarray(g1.neighbors),
                                   np.asarray(g8.neighbors))
     np.testing.assert_allclose(np.asarray(g8.divergence),

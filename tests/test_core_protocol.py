@@ -7,7 +7,7 @@ import pytest
 from repro.core import (ServerState, as_policy, candidate_mask, ddist_graph,
                         fedmd_graph, init_server, policy_round,
                         quality_scores, select_neighbors, selection_matrix,
-                        server_round, similarity_matrix, divergence_matrix,
+                        server_round, divergence_matrix,
                         upload_messengers)
 from repro.core.protocols import ddist, fedmd, isgd, sqmd
 
@@ -61,22 +61,23 @@ def test_server_round_all_inactive_no_nan_downstream():
     n, r, c = 5, 10, 3
     labels = jax.random.randint(jax.random.key(0), (r,), 0, c)
     st = init_server(n, r, c)          # nobody has joined: active all-False
-    st2, targets, g = policy_round(st, as_policy(sqmd(q=3, k=2)), labels,
-                                   backend="jnp")
+    st2, targets, g = policy_round(st, as_policy(sqmd(q=3, k=2)), labels)
     assert np.isfinite(np.asarray(targets)).all()
     np.testing.assert_allclose(np.asarray(targets), 0.0)
     np.testing.assert_allclose(np.asarray(selection_matrix(g)), 0.0)
-    assert np.isfinite(np.asarray(st2.sim)).all()
+    assert np.isfinite(np.asarray(st2.div_cache)).all()
 
 
 def test_server_state_holds_no_selection_matrix():
     """The graph is rebuilt every fire and kept by its caller: the state
-    holds the two (N,N) arrays Eq. 2 and Def. 4 need, and no W."""
-    assert "weights" not in ServerState._fields
+    holds the one (N,N) array Eq. 2 needs, and no W and no similarity C
+    (Def. 4 is computed on the pool columns of ``div_cache``)."""
+    assert ServerState._fields == ("repo_logp", "active", "quality",
+                                   "round", "div_cache")
     st = init_server(6, 4, 3)
     square = [f for f in ServerState._fields
               if getattr(st, f).shape == (6, 6)]
-    assert sorted(square) == ["div_cache", "sim"]
+    assert square == ["div_cache"]
 
 
 def test_quality_ranks_better_model_lower():
@@ -104,8 +105,8 @@ def test_similarity_recovers_planted_clusters():
         reps.append(jax.nn.log_softmax(b[0] * 1.0 + 0.05 *
                                        jax.random.normal(jax.random.key(20 + i), (r, c)), -1))
     logp = jnp.stack(reps)
-    sim = similarity_matrix(divergence_matrix(logp, backend="jnp"))
-    g = select_neighbors(sim, jnp.ones((2 * per,), bool), k=3)
+    g = select_neighbors(divergence_matrix(logp), jnp.ones((2 * per,), bool),
+                         k=3)
     nbrs = np.asarray(g.neighbors)
     for i in range(2 * per):
         group = i // per
@@ -114,8 +115,7 @@ def test_similarity_recovers_planted_clusters():
 
 def test_select_neighbors_never_self_and_row_stochastic():
     logp = _logp(9, 20, 3, seed=5)
-    sim = similarity_matrix(divergence_matrix(logp, backend="jnp"))
-    g = select_neighbors(sim, jnp.ones((9,), bool), k=4)
+    g = select_neighbors(divergence_matrix(logp), jnp.ones((9,), bool), k=4)
     w = np.asarray(selection_matrix(g))
     assert np.allclose(np.diag(w), 0.0)
     np.testing.assert_allclose(w.sum(1), 1.0, atol=1e-6)
@@ -124,9 +124,8 @@ def test_select_neighbors_never_self_and_row_stochastic():
 
 def test_select_neighbors_respects_candidates():
     logp = _logp(8, 20, 3, seed=6)
-    sim = similarity_matrix(divergence_matrix(logp, backend="jnp"))
     cand = jnp.asarray([True, True, True, False, False, False, False, True])
-    g = select_neighbors(sim, cand, k=3)
+    g = select_neighbors(divergence_matrix(logp), cand, k=3)
     w = np.asarray(selection_matrix(g))
     # only candidate columns may carry weight
     assert np.allclose(w[:, ~np.asarray(cand)], 0.0)
@@ -157,7 +156,7 @@ def test_server_round_targets_shape_and_validity(proto):
     labels = jax.random.randint(jax.random.key(8), (r,), 0, c)
     st = init_server(n, r, c)
     st = upload_messengers(st, _logp(n, r, c, seed=9), jnp.ones((n,), bool))
-    st2, targets = server_round(st, proto, labels, backend="jnp")
+    st2, targets = server_round(st, proto, labels)
     assert targets.shape == (n, r, c)
     assert int(st2.round) == 1
     if proto.name != "isgd":
@@ -179,8 +178,7 @@ def test_async_newcomer_excluded_from_candidates_but_served():
     logp = jnp.concatenate([good, newbie])
     st = init_server(n, r, c)
     st = upload_messengers(st, logp, jnp.ones((n,), bool))
-    st2, targets, g = policy_round(st, as_policy(sqmd(q=4, k=2)), labels,
-                                   backend="jnp")
+    st2, targets, g = policy_round(st, as_policy(sqmd(q=4, k=2)), labels)
     w = np.asarray(selection_matrix(g))
     assert np.allclose(w[:, -1], 0.0), "newcomer poisoned the graph"
     assert w[-1].sum() > 0.99, "newcomer did not receive neighbors"

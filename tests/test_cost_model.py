@@ -166,7 +166,7 @@ def _dense_rebuild_delta_scaling():
     from repro.core import similarity
 
     def mutant(cache, repo_logp):
-        div = similarity.divergence_matrix(repo_logp, backend="jnp")
+        div = similarity.divergence_matrix(repo_logp)
         return cache.at[:, :].set(div)
 
     axis_vals = (256, 512, 1024, 2048)

@@ -62,14 +62,13 @@ def test_select_neighbors_traceable_under_jit():
     """The pool fast path needs concrete candidates; under an outer jit
     the dense fallback keeps select_neighbors traceable with identical
     results."""
-    from repro.core import (select_neighbors, selection_matrix,
-                            similarity_matrix)
+    from repro.core import select_neighbors, selection_matrix
     lp = _logp(8, 10, 3, seed=3)
-    sim = similarity_matrix(divergence_matrix(lp, backend="jnp"))
+    div = divergence_matrix(lp)
     cand = jnp.asarray([True] * 6 + [False] * 2)
-    eager = select_neighbors(sim, cand, 3)
-    jitted = jax.jit(lambda s, c: selection_matrix(
-        select_neighbors(s, c, 3)))(sim, cand)
+    eager = select_neighbors(div, cand, 3)
+    jitted = jax.jit(lambda d, c: selection_matrix(
+        select_neighbors(d, c, 3)))(div, cand)
     np.testing.assert_allclose(np.asarray(jitted),
                                np.asarray(selection_matrix(eager)),
                                atol=1e-6)
@@ -100,9 +99,8 @@ def test_cache_scatter_equals_rebuild_after_upload_sequence():
     for i, mask in enumerate(masks):
         st = upload_messengers(st, _logp(n, r, c, seed=20 + i),
                                jnp.asarray(mask))
-        cache = update_divergence_cache(cache, st.repo_logp, mask,
-                                        backend="jnp")
-    oracle = divergence_matrix(st.repo_logp, backend="jnp")
+        cache = update_divergence_cache(cache, st.repo_logp, mask)
+    oracle = divergence_matrix(st.repo_logp)
     np.testing.assert_allclose(np.asarray(cache), np.asarray(oracle),
                                atol=1e-5)
 
@@ -114,9 +112,8 @@ def test_cache_never_uploaded_rows_stay_exact():
     st = init_server(n, r, c)
     mask = np.arange(n) < 2                 # only clients 0,1 ever upload
     st = upload_messengers(st, _logp(n, r, c, seed=31), jnp.asarray(mask))
-    cache = update_divergence_cache(st.div_cache, st.repo_logp, mask,
-                                    backend="jnp")
-    oracle = divergence_matrix(st.repo_logp, backend="jnp")
+    cache = update_divergence_cache(st.div_cache, st.repo_logp, mask)
+    oracle = divergence_matrix(st.repo_logp)
     np.testing.assert_allclose(np.asarray(cache), np.asarray(oracle),
                                atol=1e-5)
     # uniform-vs-uniform pairs are exactly zero KL
@@ -129,16 +126,16 @@ def test_policy_round_delta_matches_full_rebuild():
     pol = as_policy(sqmd(q=5, k=3))
     st = upload_messengers(init_server(n, r, c), _logp(n, r, c, seed=40),
                            jnp.ones(n, bool))
-    st, _, g = policy_round(st, pol, labels, backend="jnp")
+    st, _, g = policy_round(st, pol, labels)
     np.testing.assert_allclose(np.asarray(st.div_cache),
                                np.asarray(g.divergence))
     # one fresh upload, then delta vs full on identical state
     mask = np.zeros(n, bool)
     mask[4] = True
     st = upload_messengers(st, _logp(n, r, c, seed=41), jnp.asarray(mask))
-    st_d, tgt_d, g_d = policy_round(st, pol, labels, backend="jnp",
+    st_d, tgt_d, g_d = policy_round(st, pol, labels,
                                     uploaded=mask)
-    st_f, tgt_f, g_f = policy_round(st, pol, labels, backend="jnp")
+    st_f, tgt_f, g_f = policy_round(st, pol, labels)
     np.testing.assert_allclose(np.asarray(g_d.divergence),
                                np.asarray(g_f.divergence), atol=1e-5)
     np.testing.assert_allclose(np.asarray(selection_matrix(g_d)),
@@ -159,9 +156,9 @@ def test_policy_round_mask_is_optional_for_any_policy():
                            jnp.ones(n, bool))
     pol = as_policy("fedmd")
     mask = np.arange(n) < 2
-    _, t_delta, _ = policy_round(st, pol, labels, backend="jnp",
+    _, t_delta, _ = policy_round(st, pol, labels,
                                  uploaded=mask)
-    _, t_full, _ = policy_round(st, pol, labels, backend="jnp")
+    _, t_full, _ = policy_round(st, pol, labels)
     np.testing.assert_allclose(np.asarray(t_delta), np.asarray(t_full),
                                atol=1e-7)
 
@@ -194,15 +191,13 @@ def test_sqmd_graphs_are_k_sparse(build):
         st = upload_messengers(st, _logp(n, r, c, seed=80),
                                jnp.ones(n, bool))
     if build == "jit":
-        from repro.core import select_neighbors, similarity_matrix
-        sim = similarity_matrix(divergence_matrix(st.repo_logp,
-                                                  backend="jnp"))
+        from repro.core import select_neighbors
+        div = divergence_matrix(st.repo_logp)
         cand = jnp.arange(n) < 5
-        g = jax.jit(lambda s, m: select_neighbors(s, m, k))(sim, cand)
+        g = jax.jit(lambda d, m: select_neighbors(d, m, k))(div, cand)
     else:
         mask = np.arange(n) < 2 if build == "delta" else None
-        _, _, g = policy_round(st, pol, labels, backend="jnp",
-                               uploaded=mask)
+        _, _, g = policy_round(st, pol, labels, uploaded=mask)
     _assert_k_sparse(g, n, k)
     if build == "empty":
         np.testing.assert_array_equal(np.asarray(g.edge_weights), 0.0)
@@ -214,13 +209,13 @@ def test_delta_and_full_rebuild_give_equal_k_sparse_targets():
     pol = as_policy(sqmd(q=6, k=3))
     st = upload_messengers(init_server(n, r, c), _logp(n, r, c, seed=81),
                            jnp.ones(n, bool))
-    st, _, _ = policy_round(st, pol, labels, backend="jnp")
+    st, _, _ = policy_round(st, pol, labels)
     mask = np.zeros(n, bool)
     mask[[1, 7]] = True
     st = upload_messengers(st, _logp(n, r, c, seed=82), jnp.asarray(mask))
-    _, tgt_d, g_d = policy_round(st, pol, labels, backend="jnp",
+    _, tgt_d, g_d = policy_round(st, pol, labels,
                                  uploaded=mask)
-    _, tgt_f, g_f = policy_round(st, pol, labels, backend="jnp")
+    _, tgt_f, g_f = policy_round(st, pol, labels)
     np.testing.assert_array_equal(np.asarray(g_d.neighbors),
                                   np.asarray(g_f.neighbors))
     np.testing.assert_array_equal(np.asarray(g_d.edge_weights),
@@ -253,14 +248,14 @@ def test_server_bus_delta_keeps_cache_exact_across_fires():
     fed = _tiny_fed(n=n)
     from repro.core import EveryKUploads
     bus = ServerBus(fed, as_policy(sqmd(q=n, k=2)),
-                    trigger=EveryKUploads(k=2), backend="jnp", delta=True)
+                    trigger=EveryKUploads(k=2), delta=True)
     rng = np.random.default_rng(7)
     for step in range(6):
         mask = rng.random(n) < 0.5
         msg = _logp(n, 8, 3, seed=60 + step)
         fired = bus.deliver(float(step), msg, mask)
         if fired:
-            oracle = divergence_matrix(fed.server.repo_logp, backend="jnp")
+            oracle = divergence_matrix(fed.server.repo_logp)
             np.testing.assert_allclose(np.asarray(fed.server.div_cache),
                                        np.asarray(oracle), atol=1e-5)
     assert bus.n_triggers >= 1
@@ -284,7 +279,7 @@ def test_engine_delta_graph_end_to_end():
         schedule=StagedJoin(join), seed=7)
     hist = engine.fit(splits)
     assert np.isfinite(hist.mean_acc).all()
-    oracle = divergence_matrix(engine.server.repo_logp, backend="jnp")
+    oracle = divergence_matrix(engine.server.repo_logp)
     np.testing.assert_allclose(np.asarray(engine.server.div_cache),
                                np.asarray(oracle), atol=1e-4)
 
@@ -310,9 +305,11 @@ def test_checkpoint_restores_legacy_server_without_div_cache(tmp_path):
         np.asarray(ref.pairwise_kl_ref(fed2.server.repo_logp)), atol=1e-6)
 
 
-def test_checkpoint_restores_legacy_server_with_weights(tmp_path):
-    """Checkpoints written while the selection matrix was server state
-    carry a ``weights`` key: restore drops it and the bus fires on."""
+@pytest.mark.parametrize("legacy", ("weights", "sim"))
+def test_checkpoint_restores_legacy_server_with_weights(tmp_path, legacy):
+    """Checkpoints written while the selection matrix (``weights``) or the
+    similarity C (``sim``) was server state carry that (N,N) key: restore
+    drops it and the bus fires on."""
     from repro.checkpoint.io import restore_pytree, save_pytree
     from repro.checkpoint import restore_federation, save_federation
     n, r, c = 5, 8, 3
@@ -322,13 +319,12 @@ def test_checkpoint_restores_legacy_server_with_weights(tmp_path):
     save_federation(str(tmp_path), fed, step=1)
     path = str(tmp_path / "step_1.msgpack")
     tree = restore_pytree(path)
-    tree["server"]["weights"] = np.full((n, n), 0.5, np.float32)
+    tree["server"][legacy] = np.full((n, n), 0.5, np.float32)
     save_pytree(path, tree)
     fed2 = _tiny_fed()
     assert restore_federation(str(tmp_path), fed2) == 1
-    assert "weights" not in fed2.server._fields
-    bus = ServerBus(fed2, as_policy(sqmd(q=n, k=2)), backend="jnp",
-                    delta=True)
+    assert legacy not in fed2.server._fields
+    bus = ServerBus(fed2, as_policy(sqmd(q=n, k=2)), delta=True)
     assert bus.deliver(1.0, _logp(n, r, c, seed=72), np.arange(n) < 2)
     _assert_k_sparse(bus.last_graph, n, 2)
     assert np.isfinite(np.asarray(fed2.targets)).all()

@@ -20,8 +20,7 @@ import pytest
 ROOT = Path(__file__).resolve().parent.parent
 
 ARGS = ["--rounds", "2", "--batch", "4", "--eval-every", "1",
-        "--samples-per-client", "12", "--ref-size", "12",
-        "--backend", "jnp", "--seed", "0"]
+        "--samples-per-client", "12", "--ref-size", "12", "--seed", "0"]
 
 # wall_s is timing; devices/schedule describe the config, not the result
 _COMPARED = ("final_acc", "selected_acc", "macro_precision",
@@ -32,6 +31,7 @@ _COMPARED = ("final_acc", "selected_acc", "macro_precision",
 def _run_federate(devices: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
+    env["REPRO_KERNEL_BACKEND"] = "jnp"
     if devices > 1:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             f" --xla_force_host_platform_device_count="

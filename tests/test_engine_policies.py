@@ -55,7 +55,7 @@ def test_registry_rejects_duplicates():
     with pytest.raises(ValueError, match="already registered"):
         @register_policy("sqmd")
         class Clone(ServerPolicy):  # pragma: no cover - never registered
-            def build_graph(self, state, quality, *, backend=None):
+            def build_graph(self, state, quality):
                 raise NotImplementedError
 
 
@@ -134,8 +134,8 @@ def _uploaded_server(n=6, r=12, c=3, seed=0):
 
 def test_server_round_accepts_policy_instance_and_name():
     st, labels = _uploaded_server()
-    by_name = server_round(st, "fedmd", labels, backend="jnp")
-    by_inst = server_round(st, as_policy(fedmd()), labels, backend="jnp")
+    by_name = server_round(st, "fedmd", labels)
+    by_inst = server_round(st, as_policy(fedmd()), labels)
     np.testing.assert_allclose(np.asarray(by_name[1]),
                                np.asarray(by_inst[1]), atol=1e-7)
 
@@ -145,9 +145,8 @@ def test_server_round_ignores_static_weights_for_graphless_policies():
     st, labels = _uploaded_server()
     n = st.active.shape[0]
     w = jnp.ones((n, n)) / n
-    plain = server_round(st, fedmd(), labels, backend="jnp")
-    with_w = server_round(st, fedmd(), labels, static_weights=w,
-                          backend="jnp")
+    plain = server_round(st, fedmd(), labels)
+    with_w = server_round(st, fedmd(), labels, static_weights=w)
     np.testing.assert_allclose(np.asarray(plain[1]), np.asarray(with_w[1]),
                                atol=1e-7)
 
@@ -167,7 +166,7 @@ def toy_policy():
             w = w * state.active[:, None]          # only members receive
             return CollaborationGraph(
                 neighbors=jnp.tile(best[None, None], (n, 1)).astype(jnp.int32),
-                weights=w, similarity=state.sim, candidates=state.active)
+                weights=w, candidates=state.active)
 
     yield ToyBestPolicy
     unregister_policy("toy-best")
@@ -177,8 +176,7 @@ def test_toy_policy_end_to_end(setup, toy_policy):
     """Acceptance: a new policy runs through server_round AND the engine
     without touching core/server.py or core/engine.py."""
     st, labels = _uploaded_server()
-    st2, targets = server_round(st, Protocol("toy-best"), labels,
-                                backend="jnp")
+    st2, targets = server_round(st, Protocol("toy-best"), labels)
     best = int(np.argmin(np.asarray(st2.quality)))
     # every client's target row equals the best client's messenger
     best_msgr = np.asarray(jnp.exp(st.repo_logp[best]))
@@ -213,15 +211,17 @@ def test_legacy_federation_module_is_gone():
         import repro.core.federation  # noqa: F401
 
 
-def test_engine_backend_threading(setup):
-    """One engine-owned backend setting reaches the server kernels."""
+def test_engine_backend_threading(setup, monkeypatch):
+    """The process-level kernel setting reaches the server kernels: an
+    engine under the Pallas interpreter trains as one on the jnp oracle."""
+    from repro.kernels.backend import ENV_VAR
     ds, splits, zoo, assignment = setup
     accs = []
     for backend in ("jnp", "interpret"):
+        monkeypatch.setenv(ENV_VAR, backend)
         engine = FederationEngine.build(
             ds, splits, zoo, assignment, sqmd(q=8, k=4),
-            config=FederationConfig(rounds=2, batch_size=8, eval_every=1,
-                                    backend=backend),
+            config=FederationConfig(rounds=2, batch_size=8, eval_every=1),
             seed=3)
         accs.append(engine.fit(splits).mean_acc)
     np.testing.assert_allclose(accs[0], accs[1], atol=1e-4)

@@ -3,7 +3,8 @@
 SQMD's server selects each client's K neighbours from the Q pool columns
 of the divergence matrix. The oracle is the whole-matrix form:
 ``similarity_matrix(div)`` (Def. 4, zero diagonal), then the top-k over
-its pool columns (``_oracle``, and ``select_neighbors`` on the graph).
+its pool columns (``_oracle``; ``_dense_select`` adds the pool from a
+candidate mask, as ``select_neighbors`` takes it).
 The two must agree bit for bit, on neighbours and on edge weights,
 including ties, pools smaller than k, padded pool slots and divergences
 at or under the EPS floor.
@@ -35,6 +36,17 @@ def _oracle(div, pool, valid, k):
     real = (vals > -BIG / 2).astype(jnp.float32)
     return (pool[at].astype(jnp.int32),
             real / jnp.maximum(real.sum(axis=1, keepdims=True), 1.0))
+
+
+def _dense_select(div, cand: np.ndarray, k: int):
+    """``_oracle`` from a candidate mask: the pool padded to its bucket,
+    and an empty pool's K unrealized slots of weight 0."""
+    n = div.shape[0]
+    k = min(k, n - 1)
+    bucket = graph._pool_bucket(np.asarray(cand, bool), k)
+    if bucket is None:
+        return np.zeros((n, k), np.int32), np.zeros((n, k), np.float32)
+    return _oracle(div, *(jnp.asarray(a) for a in bucket), k)
 
 
 def _div(n: int, values: str, seed: int) -> jnp.ndarray:
@@ -105,18 +117,14 @@ def test_sqmd_graph_carries_no_similarity(pool, values):
     div = _div(n, values, seed=7)
     cand = (np.zeros(n, bool) if pool == "empty"
             else _candidates(n, pool, seed=7))
-    g = graph.select_neighbors_from_div(div, jnp.asarray(cand), K)
-    want = graph.select_neighbors(similarity_matrix(div), jnp.asarray(cand),
-                                  K)
-    assert g.similarity is None and g.divergence is div
+    g = graph.select_neighbors(div, jnp.asarray(cand), K)
+    want = _dense_select(div, cand, K)
+    assert "similarity" not in graph.CollaborationGraph._fields
+    assert g.divergence is div
     np.testing.assert_array_equal(np.asarray(g.neighbors),
-                                  np.asarray(want.neighbors))
+                                  np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(g.edge_weights),
-                                  np.asarray(want.edge_weights))
-    np.testing.assert_array_equal(np.asarray(graph.similarity_of(g)),
-                                  np.asarray(similarity_matrix(div)))
-    # a graph that carries its similarity hands back that one
-    assert graph.similarity_of(want) is want.similarity
+                                  np.asarray(want[1]))
 
 
 def _logp(n, r, c, seed):
@@ -128,26 +136,24 @@ def _logp(n, r, c, seed):
 def test_policy_round_selects_as_dense_formula(path):
     """Through ``policy_round``: a full rebuild, then (for ``delta``) a
     delta update of 3 uploaded rows. Each graph's neighbours and edge
-    weights are the dense formula's over its own divergence; the state's
-    ``sim`` is left as it was and ``div_cache`` is the graph's."""
+    weights are the dense formula's over its own divergence, and the
+    state's one (N,N) array, ``div_cache``, is the graph's."""
     n, r, c, q = 32, 12, 3, 12
     labels = jax.random.randint(jax.random.key(0), (r,), 0, c)
     policy = as_policy(sqmd(q=q, k=K))
     st = init_server(n, r, c)
     st = upload_messengers(st, _logp(n, r, c, seed=1), jnp.ones(n, bool))
-    st, _, g = policy_round(st, policy, labels, backend="jnp")
+    st, _, g = policy_round(st, policy, labels)
     if path == "delta":
         up = np.zeros(n, bool)
         up[[2, 9, 30]] = True
         st = upload_messengers(st, _logp(n, r, c, seed=2), jnp.asarray(up))
-        st, _, g = policy_round(st, policy, labels, backend="jnp",
-                                uploaded=up)
-    want = graph.select_neighbors(similarity_matrix(g.divergence),
-                                  g.candidates, K)
-    assert g.similarity is None
+        st, _, g = policy_round(st, policy, labels, uploaded=up)
+    want = _dense_select(g.divergence, g.candidates, K)
     np.testing.assert_array_equal(np.asarray(g.neighbors),
-                                  np.asarray(want.neighbors))
+                                  np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(g.edge_weights),
-                                  np.asarray(want.edge_weights))
+                                  np.asarray(want[1]))
     assert st.div_cache is g.divergence
-    np.testing.assert_array_equal(np.asarray(st.sim), np.zeros((n, n)))
+    assert [f for f in st._fields
+            if getattr(st, f).shape == (n, n)] == ["div_cache"]

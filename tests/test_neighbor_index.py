@@ -60,7 +60,7 @@ def _assert_matches_oracle(idx, logp, active, cand, k):
 def test_probe_all_matches_oracle_after_uploads():
     rng = np.random.default_rng(0)
     n, k = 48, 4
-    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL)
     logp = np.zeros((n, R, C), np.float32)
     active = np.zeros(n, bool)
     for _ in range(8):
@@ -77,7 +77,7 @@ def test_reupload_changes_lists_exactly():
     OTHER row's list (the reverse-merge + degraded-rebuild path)."""
     rng = np.random.default_rng(1)
     n, k = 24, 3
-    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL)
     logp = _rand_logp(rng, n)
     active = np.ones(n, bool)
     idx.update(np.arange(n), logp)
@@ -92,7 +92,7 @@ def test_reupload_changes_lists_exactly():
 def test_deactivation_never_selected_and_lists_repair():
     rng = np.random.default_rng(2)
     n, k = 32, 4
-    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL)
     logp = _rand_logp(rng, n)
     active = np.ones(n, bool)
     idx.update(np.arange(n), logp)
@@ -107,7 +107,7 @@ def test_deactivation_never_selected_and_lists_repair():
 def test_candidate_mask_restricts_selection():
     rng = np.random.default_rng(3)
     n, k = 20, 3
-    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL)
     idx.update(np.arange(n), _rand_logp(rng, n))
     cand = np.zeros(n, bool)
     cand[: n // 2] = True
@@ -121,7 +121,7 @@ def test_ghost_rows_never_selected():
     """Rows never ingested (no wire form) must not appear in any list."""
     rng = np.random.default_rng(4)
     n, k = 30, 4
-    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL)
     real = np.arange(0, n, 2)          # odd rows are ghosts
     idx.update(real, _rand_logp(rng, real.size))
     nbrs, _ = idx.select(np.ones(n, bool), k)
@@ -133,7 +133,7 @@ def test_partial_probe_structural_invariants():
     contain self / inactive / non-candidate entries."""
     rng = np.random.default_rng(5)
     n, k = 64, 4
-    idx = NeighborIndex(n, R, C, k=k, n_probe=1, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=k, n_probe=1)
     active = np.zeros(n, bool)
     for _ in range(6):
         rows = rng.choice(n, size=8, replace=False)
@@ -156,7 +156,7 @@ def test_update_dedups_unsorted_rows():
     last write for a duplicated id wins, like upload_messengers)."""
     rng = np.random.default_rng(6)
     n = 12
-    idx = NeighborIndex(n, R, C, k=2, n_probe=PROBE_ALL, backend="jnp")
+    idx = NeighborIndex(n, R, C, k=2, n_probe=PROBE_ALL)
     lp = _rand_logp(rng, 4)
     idx.update(np.array([7, 3, 7, 1]), lp)
     np.testing.assert_allclose(idx._recon_logp(np.array([3]))[0],
@@ -174,7 +174,7 @@ def test_validation_errors():
         NeighborIndex(0, R, C, k=2)
     with pytest.raises(ValueError):
         NeighborIndex(8, R, C, k=0)
-    idx = NeighborIndex(8, R, C, k=2, backend="jnp")
+    idx = NeighborIndex(8, R, C, k=2)
     with pytest.raises(ValueError):
         idx.update(np.array([8]), _rand_logp(np.random.default_rng(0), 1))
     with pytest.raises(ValueError):
@@ -195,8 +195,9 @@ def test_config_rejects_ivf_without_delta():
 
 def test_policy_ivf_graph_shape_and_edges():
     """The SQMD ivf branch emits a well-formed CollaborationGraph: row-
-    stochastic weights on realized edges, sparse similarity, candidates
-    respected, dense div_cache untouched."""
+    stochastic weights on realized edges, candidates respected, (N,K) and
+    (N,) arrays alone, dense div_cache untouched — and the server state
+    holds no (N,N) array but that div_cache."""
     from repro.core import init_server, upload_messengers
     from repro.core.policies import as_policy
 
@@ -208,10 +209,19 @@ def test_policy_ivf_graph_shape_and_edges():
     pol = as_policy("sqmd")
     pol.selection = "ivf"
     pol._ivf = NeighborIndex(n, r, c, k=pol.protocol.k,
-                             n_probe=PROBE_ALL, backend="jnp")
-    quality = pol.grade(state, jnp.zeros((r,), jnp.int32), backend="jnp")
+                             n_probe=PROBE_ALL)
+    quality = pol.grade(state, jnp.zeros((r,), jnp.int32))
     uploaded = np.ones(n, bool)
-    g = pol.build_graph_delta(state, quality, uploaded, backend="jnp")
+    g = pol.build_graph_delta(state, quality, uploaded)
+    k = pol.protocol.k
+    shapes = {f: np.shape(v) for f, v in g._asdict().items()
+              if v is not None}
+    assert shapes == {"neighbors": (n, k), "edge_weights": (n, k),
+                      "candidates": (n,)}
+    state2 = pol.update_state(state, quality, g)
+    assert state2.div_cache is state.div_cache
+    assert [f for f in state2._fields
+            if np.shape(getattr(state2, f)) == (n, n)] == ["div_cache"]
     w = np.asarray(selection_matrix(g))
     assert w.shape == (n, n)
     sums = w.sum(axis=1)
@@ -221,8 +231,7 @@ def test_policy_ivf_graph_shape_and_edges():
     cand = np.asarray(g.candidates)
     assert (w[:, ~cand] == 0).all()
     with pytest.raises(TypeError):
-        pol.build_graph_delta(state, quality, uploaded.astype(np.int32),
-                              backend="jnp")
+        pol.build_graph_delta(state, quality, uploaded.astype(np.int32))
 
 
 def test_engine_ivf_end_to_end_matches_exact_graph_edges():
@@ -242,14 +251,13 @@ def test_engine_ivf_end_to_end_matches_exact_graph_edges():
     exact = as_policy(proto)
     ivf = as_policy(proto)
     ivf.selection = "ivf"
-    ivf._ivf = NeighborIndex(n, r, c, k=k, n_probe=PROBE_ALL,
-                             backend="jnp")
+    ivf._ivf = NeighborIndex(n, r, c, k=k, n_probe=PROBE_ALL)
     labels = jnp.zeros((r,), jnp.int32)
-    quality = exact.grade(state, labels, backend="jnp")
+    quality = exact.grade(state, labels)
     uploaded = np.ones(n, bool)
 
-    g_exact = exact.build_graph(state, quality, backend="jnp")
-    g_ivf = ivf.build_graph_delta(state, quality, uploaded, backend="jnp")
+    g_exact = exact.build_graph(state, quality)
+    g_ivf = ivf.build_graph_delta(state, quality, uploaded)
     # compare edge sets per row; int8 round-trip shifts divergences a
     # little, so compare against the oracle computed off the wire form
     div = _oracle_divergence(np.asarray(logp), n)
@@ -286,8 +294,7 @@ if _HAS_HYPOTHESIS:
         rng = np.random.default_rng(seed)
         n = int(rng.integers(6, 40))
         k = int(rng.integers(1, 5))
-        idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL,
-                            backend="jnp")
+        idx = NeighborIndex(n, R, C, k=k, n_probe=PROBE_ALL)
         logp = np.zeros((n, R, C), np.float32)
         active = np.zeros(n, bool)
         for _ in range(steps):
@@ -313,7 +320,7 @@ if _HAS_HYPOTHESIS:
         rng = np.random.default_rng(seed)
         n = int(rng.integers(8, 48))
         probe = int(rng.integers(1, 4))
-        idx = NeighborIndex(n, R, C, k=3, n_probe=probe, backend="jnp")
+        idx = NeighborIndex(n, R, C, k=3, n_probe=probe)
         active = np.zeros(n, bool)
         for _ in range(4):
             u = int(rng.integers(1, max(2, n // 4)))

@@ -43,9 +43,8 @@ def test_div_cache_scatter_matches_full_rebuild(dims, seed, steps):
                               (n, r, c)) * 3
         state = upload_messengers(state, jax.nn.log_softmax(z, -1),
                                   jnp.asarray(mask))
-        cache = update_divergence_cache(cache, state.repo_logp, mask,
-                                        backend="jnp")
-    full = np.asarray(divergence_matrix(state.repo_logp, backend="jnp"))
+        cache = update_divergence_cache(cache, state.repo_logp, mask)
+    full = np.asarray(divergence_matrix(state.repo_logp))
     np.testing.assert_allclose(np.asarray(cache), full, atol=1e-4,
                                rtol=1e-4)
     # rows nobody uploaded keep the exact zero-KL uniform block
@@ -140,9 +139,9 @@ def test_topk_neighbors_are_most_similar(n, k, seed):
     k = min(k, n - 1)
     z = jax.random.normal(jax.random.key(seed), (n, 10, 4)) * 2
     logp = jax.nn.log_softmax(z, -1)
-    sim = similarity_matrix(divergence_matrix(logp, backend="jnp"))
-    g = select_neighbors(sim, jnp.ones((n,), bool), k)
-    s = np.asarray(sim)
+    div = divergence_matrix(logp)
+    g = select_neighbors(div, jnp.ones((n,), bool), k)
+    s = np.asarray(similarity_matrix(div))
     for i in range(n):
         chosen = set(np.asarray(g.neighbors[i]).tolist())
         others = [j for j in range(n) if j != i and j not in chosen]

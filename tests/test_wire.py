@@ -102,15 +102,15 @@ def test_lossy_decode_is_normalized(name):
 def test_int8_roundtrip_preserves_neighbor_selection():
     """The acceptance fidelity claim at unit scale: the SQMD graph built
     from int8-decoded messengers picks (nearly) the oracle's neighbors."""
-    from repro.core.graph import select_neighbors_from_div
+    from repro.core.graph import select_neighbors
     n, r, c, k = 24, 40, 32, 4
     logp = _messengers(n, r, c, seed=7)
     cand = jnp.ones((n,), bool)
     div0 = ops.pairwise_kl(logp, backend="jnp")
-    g0 = select_neighbors_from_div(div0, cand, k)
+    g0 = select_neighbors(div0, cand, k)
     dec = decode(encode("int8", logp))
     div1 = ops.pairwise_kl(dec, backend="jnp")
-    g1 = select_neighbors_from_div(div1, cand, k)
+    g1 = select_neighbors(div1, cand, k)
     a, b = np.asarray(g0.neighbors), np.asarray(g1.neighbors)
     overlap = np.mean([len(set(a[i]) & set(b[i])) / k for i in range(n)])
     assert overlap >= 0.9
@@ -137,7 +137,7 @@ def test_int8_pairwise_kl_matches_oracle(shape):
 
 def test_int8_payload_pairwise_kl_helper():
     payload = encode("int8", _messengers(6, 10, 4, seed=2))
-    d = Int8().pairwise_kl(payload, backend="jnp")
+    d = Int8().pairwise_kl(payload)
     assert d.shape == (6, 6)
     assert np.allclose(np.diag(np.asarray(d)), 0.0, atol=1e-4)
     with pytest.raises(ValueError, match="log-domain"):
@@ -220,8 +220,7 @@ def test_bus_meters_superseded_uploads(setup):
                      protocol=sqmd(q=n, k=2), ref_x=jnp.zeros((r, 4)),
                      ref_y=jnp.asarray(np.arange(r) % c),
                      optimizer=sgd(0.1), n_clients=n)
-    bus = ServerBus(fed, as_policy(sqmd(q=4, k=2)), trigger="every-upload",
-                    backend="jnp")
+    bus = ServerBus(fed, as_policy(sqmd(q=4, k=2)), trigger="every-upload")
     only2 = np.zeros(4, bool)
     only2[2] = True
     msg = jax.nn.log_softmax(
@@ -261,12 +260,13 @@ def test_checkpoint_stores_codec_names(tmp_path, setup):
 # --- the federate CLI (previously zero coverage) --------------------------
 
 def test_federate_cli_event_clock_with_lossy_wire(monkeypatch, capsys):
+    from repro.kernels.backend import ENV_VAR
     from repro.launch import federate
+    monkeypatch.setenv(ENV_VAR, "jnp")
     monkeypatch.setattr("sys.argv", [
         "federate", "--rounds", "2", "--batch", "4", "--eval-every", "1",
         "--samples-per-client", "12", "--ref-size", "9",
-        "--clock", "event", "--uplink", "int8", "--downlink", "topk",
-        "--backend", "jnp"])
+        "--clock", "event", "--uplink", "int8", "--downlink", "topk"])
     federate.main()
     out = capsys.readouterr().out
     summary = json.loads(out[out.index("{"):])

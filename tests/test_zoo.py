@@ -357,12 +357,13 @@ def test_mixed_federation_sharded_matches_single_device(setup_small):
 
 def test_federate_cli_accepts_zoo_and_assignment(monkeypatch, capsys):
     import json
+    from repro.kernels.backend import ENV_VAR
     from repro.launch import federate
+    monkeypatch.setenv(ENV_VAR, "jnp")
     monkeypatch.setattr("sys.argv", [
         "federate", "--rounds", "1", "--batch", "4", "--eval-every", "1",
         "--samples-per-client", "12", "--ref-size", "9",
-        "--zoo", "mlp-s,rglru", "--assignment", "mlp-s:0.75,rglru:0.25",
-        "--backend", "jnp"])
+        "--zoo", "mlp-s,rglru", "--assignment", "mlp-s:0.75,rglru:0.25"])
     federate.main()
     out = capsys.readouterr().out
     summary = json.loads(out[out.index("{"):])
